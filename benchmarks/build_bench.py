@@ -42,6 +42,7 @@ try:
     import bench_json                      # script: python benchmarks/...
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
+from repro import compile_cache
 from repro.core import solver, timeslot, topology, traffic
 
 OBJECTIVES = ("energy", "time")
@@ -197,6 +198,7 @@ def main(argv=None) -> int:
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     backends = bench_json.parse_backends(ap, args.backends)
     for b in backends:
         solver._check_backend(b)

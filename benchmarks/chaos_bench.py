@@ -39,6 +39,7 @@ try:
     import bench_json                      # script: python benchmarks/...
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
+from repro import compile_cache
 from repro.core import arrivals, solver, topology, traffic
 from repro.core import chaos as chaosmod
 
@@ -156,6 +157,7 @@ def main(argv=None) -> int:
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.chaos not in chaosmod.PRESETS:
         ap.error(f"unknown chaos preset {args.chaos!r}; "
                  f"have {sorted(chaosmod.PRESETS)}")

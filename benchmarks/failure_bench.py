@@ -44,6 +44,7 @@ try:
     import bench_json                      # script: python benchmarks/...
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
+from repro import compile_cache
 from repro.core import failures, solver, timeslot, topology, traffic
 
 
@@ -159,6 +160,7 @@ def main(argv=None) -> int:
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     scale = (args.n_map, args.n_reduce, args.total_gbits)
     presets = args.failures.split(",")
     backends = bench_json.parse_backends(ap, args.backends)

@@ -47,7 +47,7 @@ try:
     import bench_json                      # script: python benchmarks/...
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
-from repro import search
+from repro import compile_cache, search
 from repro.core import solver, timeslot, topology, traffic
 
 
@@ -163,6 +163,7 @@ def main(argv=None) -> int:
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
     agg_loop = agg_batch = 0.0

@@ -33,6 +33,7 @@ try:
     import bench_json                      # script: python benchmarks/...
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
+from repro import compile_cache
 from repro.core import policies, solver, topology, traffic, verify
 from repro.core.timeslot import ScheduleProblem, suggest_n_slots
 
@@ -110,6 +111,7 @@ def main(argv=None) -> int:
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
     best = 0.0

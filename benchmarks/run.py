@@ -118,7 +118,8 @@ def bench_kernels(full: bool):
         out.block_until_ready()
         dt = (time.time() - t0) / n
         print(f"kernels/flash_attn_S{S},{dt*1e6:.0f},"
-              f"interpret=True;ref_validated=tests/test_kernels.py")
+              f"platform={jax.default_backend()};"
+              f"ref_validated=tests/test_kernels.py")
     a = jax.nn.sigmoid(jax.random.normal(key, (4, 1024, 512)))
     b = jax.random.normal(key, (4, 1024, 512))
     h, _ = ops.rglru(a, b)
@@ -126,7 +127,8 @@ def bench_kernels(full: bool):
     t0 = time.time()
     h, _ = ops.rglru(a, b)
     h.block_until_ready()
-    print(f"kernels/rglru_1024x512,{(time.time()-t0)*1e6:.0f},interpret=True")
+    print(f"kernels/rglru_1024x512,{(time.time()-t0)*1e6:.0f},"
+          f"platform={jax.default_backend()}")
 
 
 def bench_roofline(full: bool):
@@ -151,6 +153,8 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale sizes (slow)")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     names = list(BENCHES) if args.only == "all" else args.only.split(",")
     print("name,us_per_call,derived")
     for n in names:

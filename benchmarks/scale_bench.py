@@ -144,6 +144,9 @@ def main(argv=None) -> int:
             + f" --xla_force_host_platform_device_count={n_dev}").strip()
         os.execv(sys.executable, [sys.executable] + sys.argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     try:
         import bench_json
     except ImportError:

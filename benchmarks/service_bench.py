@@ -39,7 +39,7 @@ try:
     import bench_json                      # script: python benchmarks/...
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
-from repro import service
+from repro import compile_cache, service
 from repro.core import arrivals, solver, topology, traffic
 
 
@@ -143,6 +143,7 @@ def main(argv=None) -> int:
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
     agg: dict[str, tuple[float, float, float]] = {}

@@ -181,10 +181,12 @@ _pdhg_resume = functools.partial(jax.jit, static_argnames=(
 # unchanged.  backend="pallas" re-packs the operator into the blocked-ELL
 # layout of repro.kernels.pdhg_spmv and runs whole iteration bursts as one
 # fused Pallas kernel (K^T.y gather, prox/clip, K.x, dual ascent, terminal
-# residuals) — validated on CPU via interpret=True, lowering to Mosaic on
-# TPU.  Same math, same freeze semantics; only the SpMV reduction order
-# differs, so results agree to fp tolerance, not bitwise (see
-# docs/SOLVER.md "Backends" and docs/KERNELS.md).
+# residuals) — validated on CPU in interpret mode.  On one TPU, Mosaic
+# refuses the burst's flat gather ("Only 2D gather is supported"), so
+# this backend runs on the chip only row-sharded (shards > 1, plain jnp
+# inside shard_map).  Same math, same freeze semantics; only the SpMV
+# reduction order differs, so results agree to fp tolerance, not bitwise
+# (see docs/SOLVER.md "Backends" and docs/KERNELS.md).
 
 BACKENDS = ("xla", "pallas")
 PRECISIONS = ("fp32", "bf16")
@@ -735,9 +737,9 @@ class DispatchStats:
     A dispatch's compiled executable is keyed by its *post-bucketing*
     static shape (padded n/m_eq/m/nnz, instance count, chunk schedule,
     backend) — `shape_hits` counts dispatches that landed on a shape
-    this process has dispatched before (the jitted kernel, and with
-    `--jax-cache` the persistent XLA cache, can reuse the compiled
-    executable), `shape_misses` counts first-seen shapes.  The
+    this process has dispatched before (the jitted kernel, and the
+    persistent compilation cache of repro.compile_cache, can reuse the
+    compiled executable), `shape_misses` counts first-seen shapes.  The
     multi-tenant scheduler service reads deltas of these counters to
     report its bucket-hit ratio; read via `dispatch_stats()`, clear via
     `reset_dispatch_stats()`."""

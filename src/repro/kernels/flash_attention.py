@@ -74,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          softcap: float = 0.0, bq: int = 512, bk: int = 512,
-                         scale: float | None = None, interpret: bool = True):
+                         scale: float | None = None, interpret: bool):
     """q: (BH, S, hd); k, v: (BH_kv, T, hd) with BH = BH_kv * group.
     Returns (BH, S, hd)."""
     BH, S, hd = q.shape

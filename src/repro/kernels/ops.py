@@ -1,8 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode; on TPU they lower
-to Mosaic.  The wrappers handle GQA layout, head_dim padding to the
-128-lane MXU width, and block-size selection.
+This module alone decides whether a kernel runs in interpret mode: off
+a TPU it always does (the kernel body executes as traced jax ops), on a
+TPU never.  There is no fallback: a kernel that Mosaic refuses raises.
+The single-device PDHG burst is one such kernel today; Mosaic rejects
+its `jnp.take` gather ("Only 2D gather is supported", see
+docs/KERNELS.md).  The wrappers handle GQA layout, head_dim padding to
+the 128-lane MXU width, and block-size selection.
 """
 from __future__ import annotations
 
@@ -16,17 +20,15 @@ from . import pdhg_spmv as ps
 from . import rglru_scan as rs
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode everywhere but on a TPU (read at trace time)."""
+    return jax.default_backend() != "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "softcap"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    softcap: float = 0.0, interpret: bool | None = None):
+                    softcap: float = 0.0):
     """q: (B,S,H,hd); k,v: (B,T,Hkv,hd) -> (B,S,H,hd)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     B, S, H, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     # pad head_dim to the 128-lane width
@@ -42,18 +44,16 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     # the dots, so only the softmax scale constant must be corrected)
     out = fa.flash_attention_bhsd(
         qb, kb, vb, causal=causal, window=int(window or 0),
-        softcap=softcap, interpret=interpret, scale=1.0 / (hd ** 0.5),
+        softcap=softcap, interpret=_interpret(), scale=1.0 / (hd ** 0.5),
         bq=min(512, S), bk=min(512, T))
     out = out.reshape(B, H, S, hdp).transpose(0, 2, 1, 3)
     return out[..., :hd]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def rglru(a, b, h0=None, *, interpret: bool | None = None):
+@jax.jit
+def rglru(a, b, h0=None):
     """Linear recurrence h_t = a*h + b.  a, b: (B,S,R)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    return rs.rglru_scan(a, b, h0, interpret=interpret)
+    return rs.rglru_scan(a, b, h0, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------
@@ -61,11 +61,11 @@ def rglru(a, b, h0=None, *, interpret: bool | None = None):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("row_meta", "col_meta", "iters",
-                                             "interpret", "precision"))
+                                             "precision"))
 def pdhg_burst(c, tau, xmax, q, sig, ub, keep_n, keep_m,
                row_idx, row_val, col_idx, col_val, x0, y0, *,
                row_meta: tuple, col_meta: tuple, iters: int,
-               interpret: bool | None = None, precision: str = "fp32"):
+               precision: str = "fp32"):
     """One fused `iters`-iteration PDHG burst (kernels.pdhg_spmv).
 
     Arrays are storage-padded (x side n_pad, y side m_pad); returns
@@ -75,23 +75,19 @@ def pdhg_burst(c, tau, xmax, q, sig, ub, keep_n, keep_m,
     `precision="bf16"` stores the iterates in bfloat16 between
     iterations (fp32 arithmetic and residuals — see pdhg_update_burst);
     the default "fp32" trace is unchanged."""
-    if interpret is None:
-        interpret = not _on_tpu()
     return ps.pdhg_burst(c, tau, xmax, q, sig, ub, keep_n, keep_m,
                          row_idx, row_val, col_idx, col_val, x0, y0,
                          row_meta=row_meta, col_meta=col_meta, iters=iters,
-                         interpret=interpret, precision=precision)
+                         interpret=_interpret(), precision=precision)
 
 
 @functools.partial(jax.jit, static_argnames=("row_meta", "col_meta",
                                              "num_inst", "chunk",
-                                             "max_chunks", "interpret",
-                                             "precision"))
+                                             "max_chunks", "precision"))
 def pdhg_adaptive(c, tau, xmax, q, sig, ub, row_idx, row_val, col_idx,
                   col_val, x0, y0, tols, inst_n, inst_m, *,
                   num_inst: int, row_meta: tuple, col_meta: tuple,
-                  chunk: int, max_chunks: int,
-                  interpret: bool | None = None, precision: str = "fp32"):
+                  chunk: int, max_chunks: int, precision: str = "fp32"):
     """Adaptive PDHG over a block-stacked instance batch, Pallas bursts.
 
     The exact semantics of core.solver._pdhg_run_adaptive — `chunk`-
@@ -103,8 +99,7 @@ def pdhg_adaptive(c, tau, xmax, q, sig, ub, row_idx, row_val, col_idx,
     `inst_n`/`inst_m` map storage coordinates to instance ids, with
     padded slots mapped to the dump segment `num_inst`.  Returns
     (x, y, per-instance residuals, per-instance chunks used)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret()
 
     def burst(x, y, frozen):
         frozen_ext = jnp.concatenate(
@@ -147,8 +142,6 @@ def _sharded_burst_fn(mesh, axis: str, row_meta: tuple, col_meta: tuple,
     reuse one compiled executable instead of re-tracing per call."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime.collectives import shard_map
-
     rep, shd = P(), P(axis)
 
     def inner(c, tau, xmax, q, sig, ub, keep_n, keep_m,
@@ -158,11 +151,11 @@ def _sharded_burst_fn(mesh, axis: str, row_meta: tuple, col_meta: tuple,
             row_idx, row_val, col_idx, col_val, row_meta=row_meta,
             col_meta=col_meta, iters=iters, axis=axis, precision=precision)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(rep, rep, rep, shd, shd, shd, rep, shd,
                   shd, shd, shd, shd, rep, shd),
-        out_specs=(rep, shd, shd), check_rep=False)
+        out_specs=(rep, shd, shd), check_vma=False)
     return jax.jit(fn)
 
 

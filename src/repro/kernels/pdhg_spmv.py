@@ -27,9 +27,11 @@ column index.
 
 Both directions ship with a pure-jnp oracle (`kernels.ref.ell_spmv` /
 `ref.pdhg_ell_burst_ref`) and are validated on CPU via `interpret=True`
-(tests/test_pdhg_kernels.py); on TPU the kernel lowers to Mosaic, where
-`align` should be raised to the 128-lane width (see docs/KERNELS.md for
-the layout/padding rules).
+(tests/test_pdhg_kernels.py).  On a TPU, Mosaic refuses the fused burst:
+the flat `jnp.take` in `spmv_blocks` raises "Only 2D gather is
+supported" (tests/test_tpu_compile.py).  The row-sharded body
+(`pdhg_update_burst_sharded`) is plain jnp under shard_map and compiles
+(see docs/KERNELS.md for the layout/padding rules and the VMEM limit).
 
 Trajectory contract: the kernel computes exactly the update of
 `core.solver._pdhg_ops` — same preconditioners, same prox, same freeze
@@ -333,7 +335,7 @@ def _burst_kernel(c_ref, tau_ref, xmax_ref, q_ref, sig_ref, ub_ref,
 def pdhg_burst(c, tau, xmax, q, sig, ub, keep_n, keep_m,
                row_idx, row_val, col_idx, col_val, x0, y0, *,
                row_meta: tuple, col_meta: tuple, iters: int,
-               interpret: bool = True, precision: str = "fp32"):
+               interpret: bool, precision: str = "fp32"):
     """Run one fused PDHG burst; returns (x, y, worst).
 
     All vectors are storage-padded: x-side arrays have length n_pad,

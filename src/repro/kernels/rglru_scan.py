@@ -30,7 +30,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, *, seq: int):
     hlast_ref[0] = h
 
 
-def rglru_scan(a, b, h0=None, *, bf: int = 128, interpret: bool = True):
+def rglru_scan(a, b, h0=None, *, bf: int = 128, interpret: bool):
     """a, b: (B, S, R) float32; h0: (B, R) initial state (zeros default).
     Returns (h (B,S,R), h_last (B,R))."""
     B, S, R = a.shape

@@ -17,20 +17,10 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.fabric import SlotPlan
-
-try:                                    # jax >= 0.6 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The version-portable shard_map: every in-tree consumer (the sharded
-# PDHG driver in kernels.ops, make_scheduled_grad_sync below) goes
-# through this name so the jax.shard_map vs jax.experimental.shard_map
-# split is resolved in exactly one place.
-shard_map = _shard_map
 
 PyTree = Any
 

@@ -17,7 +17,7 @@ import argparse
 import pathlib
 import time
 
-from repro import search
+from repro import compile_cache, search
 from repro.core import arrivals, failures, solver, topology, traffic
 from repro.core import chaos as chaosmod
 from repro.core import policies as policy_zoo
@@ -175,7 +175,8 @@ def main(argv=None) -> int:
     ap.add_argument("--backend", default="xla", choices=solver.BACKENDS,
                     help="PDHG lowering: xla (COO scatters, default) or "
                          "pallas (fused blocked-ELL kernel bursts; "
-                         "interpret mode on CPU)")
+                         "interpret mode on CPU; on a TPU only with "
+                         "--mesh > 1, see docs/KERNELS.md)")
     ap.add_argument("--mesh", type=int, default=1,
                     help="row-partition every PDHG dispatch across this "
                          "many devices (pallas only; on CPU requires "
@@ -194,12 +195,6 @@ def main(argv=None) -> int:
                     help="print a build/solve/report wall-time split per "
                          "grid cell (with structure-cache hit/miss "
                          "deltas from core.solver.build_cache_stats)")
-    ap.add_argument("--jax-cache", default="",
-                    help="opt-in persistent JAX compilation cache "
-                         "directory: compiled PDHG executables survive "
-                         "across sweep processes (pairs with the solver's "
-                         "shape bucketing, which keeps the set of "
-                         "distinct shapes small)")
     ap.add_argument("--service", type=int, default=0, metavar="N",
                     help="smoke-run the multi-tenant scheduler service "
                          "(repro.service) with N tenants cycling through "
@@ -213,18 +208,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="results/sweep",
                     help="output directory for results.csv / results.md")
     args = ap.parse_args(argv)
-
-    if args.jax_cache:
-        import jax
-        try:
-            jax.config.update("jax_compilation_cache_dir", args.jax_cache)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0)
-        except AttributeError:        # older jax without the knobs
-            print(f"warning: this jax build does not support the "
-                  f"persistent compilation cache; --jax-cache ignored")
+    compile_cache.enable()
 
     if args.service:
         return _run_service_smoke(args)

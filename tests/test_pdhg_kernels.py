@@ -142,7 +142,7 @@ def test_pdhg_burst_matches_ref_oracle(m, n, m_eq, frozen):
     rng = np.random.default_rng(m + n)
     op, args = _burst_args(rng, m, n, 8 * m, m_eq, frozen_frac=frozen)
     kw = dict(row_meta=op.rows.meta, col_meta=op.cols.meta, iters=60)
-    xk, yk, wk = ops.pdhg_burst(*args, **kw, interpret=True)
+    xk, yk, wk = ops.pdhg_burst(*args, **kw)
     xr, yr, wr = ref.pdhg_ell_burst_ref(*args, **kw)
     # same traced ops either side; only XLA fusion decisions may differ
     # between the two compiled programs, so agreement is ~1 ulp
@@ -180,8 +180,7 @@ def test_pdhg_burst_tracks_xla_kernel():
     x_pl, y_pl, _ = ops.pdhg_burst(
         *vecs, jnp.zeros(op.n_pad, bool), jnp.zeros(op.m_pad, bool), *ell,
         jnp.zeros(op.n_pad), jnp.zeros(op.m_pad),
-        row_meta=op.rows.meta, col_meta=op.cols.meta, iters=200,
-        interpret=True)
+        row_meta=op.rows.meta, col_meta=op.cols.meta, iters=200)
     scale = float(jnp.abs(x_xla).max())
     np.testing.assert_allclose(np.asarray(x_pl)[:lp.n], np.asarray(x_xla),
                                atol=2e-4 * max(scale, 1.0))

@@ -1,5 +1,5 @@
 """Unit tests for the runtime helpers the sharded PDHG path leans on:
-repro.runtime.collectives (version-portable shard_map, bucketize,
+repro.runtime.collectives (shard_map, bucketize,
 scheduled_psum via make_scheduled_grad_sync, plan_axis_names) and
 repro.runtime.sharding (solver_mesh, Strategy spec derivation).
 
@@ -25,7 +25,7 @@ def test_shard_map_alias_is_callable_on_one_device_mesh():
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("shard",))
     fn = rc.shard_map(lambda x: jax.lax.psum(x, "shard"), mesh=mesh,
                       in_specs=P("shard"), out_specs=P("shard"),
-                      check_rep=False)
+                      check_vma=False)
     out = fn(jnp.arange(4.0))
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0))
 

@@ -1,0 +1,167 @@
+"""Compile rehearsals: the main path's device programs, compiled by the
+TPU compiler at fat-tree-k16 size for a described v5e that is not
+attached.
+
+Nothing runs here, so these tests say nothing about results or times.
+They catch what interpret mode and the CPU backend forgive: a kernel
+Mosaic refuses, a program that does not fit the chip, a sharded program
+without its collective.  The topology is described inside a fixture,
+never at import time (only one process may hold the TPU library, and
+pytest-xdist workers import every test file); keep every such compile in
+this one file.
+
+The LP is the fat-tree-k16 instance of benchmarks/scale_bench.py:
+1,024 servers, 20 mappers x 12 reducers, 120 Gbit, seed 0.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.core import solver, timeslot, topology, traffic
+from repro.kernels import ops as kops
+from repro.kernels import pdhg_spmv
+
+V5E_HBM_BYTES = 16e9
+ITERS = 1500
+
+
+@pytest.fixture(scope="module")
+def tpu_topology():
+    """A described v5e:2x2, with the persistent compilation cache off
+    (entries compiled for a chip that is absent cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(tpu_topology):
+    return SingleDeviceSharding(tpu_topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def lp_k16():
+    """The fat-tree-k16 routing LP (min-energy), built once."""
+    topo = topology.build("fat-tree", k=16)
+    pat = traffic.pattern("uniform", n_map=20, n_reduce=12,
+                          total_gbits=120.0)
+    cf = traffic.generate(topo, pat, seed=0)
+    p = timeslot.ScheduleProblem(
+        topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf), path_slack=0)
+    lp, _ = solver.build_routing_lp(p, "energy")
+    assert len(lp.val) > 100_000
+    return lp
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _lp_specs(lp, sharding):
+    """(c, row, col, val, b, h, xmax) as the solver uploads them."""
+    f32, i32 = jnp.float32, jnp.int32
+    nnz = len(lp.val)
+    return [_spec((lp.n,), f32, sharding), _spec((nnz,), i32, sharding),
+            _spec((nnz,), i32, sharding), _spec((nnz,), f32, sharding),
+            _spec((lp.m_eq,), f32, sharding),
+            _spec((lp.m - lp.m_eq,), f32, sharding),
+            _spec((lp.n,), f32, sharding)]
+
+
+def _normalized(lp):
+    """(c / max|c|, xmax with infinities clamped) as solve_lp packs them."""
+    cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
+    return lp.c / cscale, np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
+
+
+def _fits_one_chip(compiled):
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES, ma
+
+
+def test_pdhg_resume_compiles_for_one_v5e(one_chip, lp_k16):
+    """solve_fast's default path: the resumable COO-scatter PDHG."""
+    lp = lp_k16
+    f32 = jnp.float32
+    compiled = solver._pdhg_resume.lower(
+        *_lp_specs(lp, one_chip), _spec((lp.n,), f32, one_chip),
+        _spec((lp.m,), f32, one_chip), lp.m, lp.n, lp.m_eq,
+        ITERS).compile()
+    _fits_one_chip(compiled)
+
+
+def test_pdhg_run_adaptive_compiles_for_one_v5e(one_chip, lp_k16):
+    """The batched path (sweep, run_online, run_service): the fused
+    adaptive loop over the shape-bucketed stacked LP."""
+    gp, _ = solver._pad_for_buckets(solver.block_stack([lp_k16]).lp)
+    f32, i32 = jnp.float32, jnp.int32
+    chunk = 500
+    compiled = solver._pdhg_run_adaptive.lower(
+        *_lp_specs(gp, one_chip), _spec((gp.n,), f32, one_chip),
+        _spec((gp.m,), f32, one_chip), _spec((1,), f32, one_chip),
+        _spec((gp.n,), i32, one_chip), _spec((gp.m,), i32, one_chip),
+        1, gp.m, gp.n, gp.m_eq, chunk, 4 * ITERS // chunk).compile()
+    _fits_one_chip(compiled)
+
+
+def test_sharded_burst_compiles_for_v5e_2x2(tpu_topology, lp_k16):
+    """The row-sharded burst (`--mesh 4`, solve_fast(shards=4)) on a
+    four-chip mesh: one all-reduce of K^T.y per iteration."""
+    lp = lp_k16
+    c, xmax = _normalized(lp)
+    op, vecs, ell = solver._pack_pallas_sharded(
+        c, lp.row, lp.col, lp.val, lp.b, lp.h, xmax, lp.m_eq, shards=4)
+    mesh = Mesh(np.asarray(tpu_topology.devices[:4]), ("shard",))
+    args = [*vecs, jnp.zeros(op.n_pad, bool), jnp.zeros(op.m_pad, bool),
+            *ell, jnp.zeros(op.n_pad), jnp.zeros(op.m_pad)]
+    # x-side arrays (c, tau, xmax, keep_n, x0) are replicated, the rest
+    # split by rows — kernels.ops._sharded_burst_fn's in_specs
+    replicated = {0, 1, 2, 6, 12}
+    specs = [_spec(a.shape, a.dtype,
+                   NamedSharding(mesh, P() if i in replicated
+                                 else P("shard")))
+             for i, a in enumerate(args)]
+    fn = kops._sharded_burst_fn(mesh, "shard", op.row_meta, op.col_meta,
+                                ITERS, "fp32")
+    compiled = fn.lower(*specs).compile()
+    assert "all-reduce" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason="Mosaic refuses the blocked-ELL gather in "
+           "kernels/pdhg_spmv.spmv_blocks (jnp.take over a flat vector): "
+           "'NotImplementedError: Only 2D gather is supported'")
+def test_single_device_pallas_burst_compiles_for_one_v5e(one_chip, lp_k16):
+    """`backend="pallas"` on one chip: the fused whole-array burst."""
+    lp = lp_k16
+    c, xmax = _normalized(lp)
+    op, vecs, ell = solver._pack_pallas(c, lp.row, lp.col, lp.val, lp.b,
+                                        lp.h, xmax, lp.m_eq)
+    args = [*vecs, jnp.zeros(op.n_pad, bool), jnp.zeros(op.m_pad, bool),
+            *ell, jnp.zeros(op.n_pad), jnp.zeros(op.m_pad)]
+    burst = jax.jit(functools.partial(
+        pdhg_spmv.pdhg_burst, row_meta=op.rows.meta, col_meta=op.cols.meta,
+        iters=ITERS, interpret=False))
+    burst.lower(*[_spec(a.shape, a.dtype, one_chip) for a in args]).compile()
