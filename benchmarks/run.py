@@ -6,7 +6,6 @@ perf tables.  Prints ``name,us_per_call,derived`` CSV.
   gap         fast-path vs oracle optimality/time table
   fabric      co-flow collective plans vs naive single-axis
   kernels     Pallas kernel wall-times (interpret mode -> call overhead)
-  roofline    per-(arch x shape) roofline terms from the dry-run artifacts
 
 Default sizes are reduced for CI; ``--full`` runs paper-scale (10x6
 tasks, 1-120 Gbit, exact Table I cell).
@@ -131,18 +130,12 @@ def bench_kernels(full: bool):
           f"platform={jax.default_backend()}")
 
 
-def bench_roofline(full: bool):
-    from . import roofline
-    roofline.main()
-
-
 BENCHES = {
     "paper": bench_paper,
     "baselines": bench_baselines,
     "gap": bench_gap,
     "fabric": bench_fabric,
     "kernels": bench_kernels,
-    "roofline": bench_roofline,
 }
 
 

@@ -48,13 +48,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
-import time
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from .timeslot import Metrics, ScheduleProblem, evaluate
 
 Array = jax.Array
@@ -123,10 +123,12 @@ def _pdhg_ops(c, row, col, val, b, h, m, n, m_eq):
     sig = 1.0 / jnp.maximum(row_sum, 1e-12)
 
     def Kx(x):
-        return jnp.zeros(m).at[row].add(val * x[col])
+        with jax.named_scope("pdhg/Kx"):
+            return jnp.zeros(m).at[row].add(val * x[col])
 
     def KTy(y):
-        return jnp.zeros(n).at[col].add(val * y[row])
+        with jax.named_scope("pdhg/KTy"):
+            return jnp.zeros(n).at[col].add(val * y[row])
 
     ub_mask = jnp.arange(m) >= m_eq
     return q, tau, sig, Kx, KTy, ub_mask
@@ -243,10 +245,8 @@ def _ell_operator_cached(row, col, val, m, n):
                            digest_size=16).digest())
     plan = _ELL_PLAN_CACHE.get(key)
     if plan is None:
-        t0 = time.perf_counter()
         plan = pdhg_spmv.ell_plan(row, col, m, n)
         BUILD_STATS.ell_misses += 1
-        BUILD_STATS.ell_s += time.perf_counter() - t0
         if len(_ELL_PLAN_CACHE) >= _ELL_PLAN_CACHE_MAX:
             _ELL_PLAN_CACHE.pop(next(iter(_ELL_PLAN_CACHE)))
         _ELL_PLAN_CACHE[key] = plan
@@ -434,10 +434,11 @@ def _pdhg_run_adaptive(c, row, col, val, b, h, xmax, x0, y0, tols,
                                               m, n, m_eq)
 
     def residuals(x):
-        r = Kx(x) - q
-        worst = jnp.where(ub_mask, jnp.maximum(r, 0.0), jnp.abs(r))
-        return jax.ops.segment_max(worst, inst_m,
-                                   num_segments=num_inst + 1)[:num_inst]
+        with jax.named_scope("pdhg/residual"):
+            r = Kx(x) - q
+            worst = jnp.where(ub_mask, jnp.maximum(r, 0.0), jnp.abs(r))
+            return jax.ops.segment_max(worst, inst_m,
+                                       num_segments=num_inst + 1)[:num_inst]
 
     def burst(x, y, frozen):
         frozen_ext = jnp.concatenate([frozen, jnp.ones((1,), bool)])
@@ -700,11 +701,8 @@ class BuildCacheStats:
 
     structure_hits: int = 0
     structure_misses: int = 0
-    structure_s: float = 0.0      # seconds spent building structures
-    fill_s: float = 0.0           # seconds refreshing value arrays
     ell_hits: int = 0
     ell_misses: int = 0
-    ell_s: float = 0.0            # seconds building blocked-ELL plans
 
     def snapshot(self) -> "BuildCacheStats":
         return dataclasses.replace(self)
@@ -742,11 +740,22 @@ class DispatchStats:
     compiled executable), `shape_misses` counts first-seen shapes.  The
     multi-tenant scheduler service reads deltas of these counters to
     report its bucket-hit ratio; read via `dispatch_stats()`, clear via
-    `reset_dispatch_stats()`."""
+    `reset_dispatch_stats()`.
+
+    The two work counters are in nonzero-iterations, one pass over one
+    nonzero of K in one PDHG iteration: `nnz_iters_run` is what the
+    dispatches ran on the device (the longest instance's iterations
+    times the dispatched, bucketed nnz: frozen instances, padding and
+    the dump segment included), `nnz_iters_useful` what moved an
+    unconverged instance (each instance's iterations times its own
+    nnz).  Their ratio is the share of PDHG's scatter work that was
+    not wasted."""
 
     dispatches: int = 0
     shape_hits: int = 0
     shape_misses: int = 0
+    nnz_iters_run: int = 0
+    nnz_iters_useful: int = 0
 
     def snapshot(self) -> "DispatchStats":
         return dataclasses.replace(self)
@@ -776,6 +785,15 @@ def _note_dispatch(shape: tuple) -> None:
     else:
         DISPATCH_STATS.shape_misses += 1
         _DISPATCH_SHAPES.add(shape)
+
+
+def _note_work(used: np.ndarray, nnz_run: int, nnz: list[int]) -> None:
+    """Record one dispatch's PDHG work (see DispatchStats): `used` holds
+    each instance's iterations, `nnz_run` the dispatched nnz and `nnz`
+    each instance's own."""
+    DISPATCH_STATS.nnz_iters_run += int(used.max(initial=0)) * nnz_run
+    DISPATCH_STATS.nnz_iters_useful += sum(int(u) * k
+                                           for u, k in zip(used, nnz))
 
 
 def _structure_key(p: ScheduleProblem, objective: str) -> tuple:
@@ -961,6 +979,7 @@ def _fill_lp(st: ProblemStructure, p: ScheduleProblem) -> StructuredLP:
                         b=b, h=h, xmax=xmax)
 
 
+@trace.spanned("lp.build")
 def build_routing_lp(p: ScheduleProblem, objective: str, *,
                      cache: bool = True
                      ) -> tuple[StructuredLP, RoutingIndex]:
@@ -980,20 +999,15 @@ def build_routing_lp(p: ScheduleProblem, objective: str, *,
     key = _structure_key(p, objective) if cache else None
     st = _STRUCTURE_CACHE.get(key) if cache else None
     if st is None:
-        t0 = time.perf_counter()
         st = _build_structure(p, objective)
         BUILD_STATS.structure_misses += 1
-        BUILD_STATS.structure_s += time.perf_counter() - t0
         if cache:
             if len(_STRUCTURE_CACHE) >= _STRUCTURE_CACHE_MAX:
                 _STRUCTURE_CACHE.pop(next(iter(_STRUCTURE_CACHE)))
             _STRUCTURE_CACHE[key] = st
     else:
         BUILD_STATS.structure_hits += 1
-    t0 = time.perf_counter()
-    lp = _fill_lp(st, p)
-    BUILD_STATS.fill_s += time.perf_counter() - t0
-    return lp, st.idx
+    return _fill_lp(st, p), st.idx
 
 
 def _build_routing_lp_loops(p: ScheduleProblem, objective: str
@@ -1205,6 +1219,7 @@ def _route_search(p: ScheduleProblem, out_edges, src: int, dst: int,
     return None
 
 
+@trace.spanned("pack.decompose")
 def path_decompose(p: ScheduleProblem, idx: RoutingIndex,
                    vol: np.ndarray) -> list[FlowPath]:
     """Decompose per-flow (edge, wavelength) volumes into src->dst paths.
@@ -1281,6 +1296,7 @@ def path_decompose(p: ScheduleProblem, idx: RoutingIndex,
 # Temporal packing (fractional routing -> discrete slots)
 # ---------------------------------------------------------------------------
 
+@trace.spanned("pack.slots")
 def temporal_pack(p: ScheduleProblem, idx: RoutingIndex,
                   x_route: np.ndarray, *,
                   paths: list[FlowPath] | None = None) -> np.ndarray:
@@ -1459,6 +1475,7 @@ def _assemble_fast_result(p: ScheduleProblem, lp: StructuredLP,
                                         1e-12))
 
 
+@trace.batched
 def solve_fast(p: ScheduleProblem, objective: str = "energy", *,
                iters: int = 4000, tol: float | None = None,
                backend: str = "xla", shards: int = 1,
@@ -1722,11 +1739,14 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                                          1.0)
                          for lp in lps])
 
-    def _run_pallas(g: StructuredLP, bs: BlockStackedLP, x0, y0,
-                    sub: list[int], budget: int):
-        """The stacked dispatch lowered through the Pallas kernels: pack
-        the stacked LP into blocked-ELL once per dispatch shape, then run
-        the fused adaptive loop (or one fixed burst) via repro.kernels."""
+    def _stage_pallas(g: StructuredLP, bs: BlockStackedLP, x0, y0,
+                      sub: list[int], budget: int):
+        """Stage the stacked dispatch for the Pallas kernels: pack the
+        stacked LP into blocked-ELL once per dispatch shape and upload
+        it.  Returns a function that runs the fused adaptive loop (or
+        one fixed burst) via repro.kernels and brings (x, y, iterations
+        per instance) to the host, and the entries one SpMV direction
+        stores (the mean of the two directions' padded tables)."""
         from repro.kernels import ops as kops
 
         if shards > 1:
@@ -1742,12 +1762,16 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                             op.n_pad, op.m_pad, len(sub)))
             x0p = jnp.pad(x0.astype(jnp.float32), (0, op.n_pad - g.n))
             y0p = jnp.pad(y0.astype(jnp.float32), (0, op.m_pad - g.m))
-            x, y, _ = kops.pdhg_burst_sharded(
-                mesh, *vecs, jnp.zeros(op.n_pad, bool),
-                jnp.zeros(op.m_pad, bool), *ell, x0p, y0p,
-                row_meta=op.row_meta, col_meta=op.col_meta, iters=budget,
-                precision=precision)
-            return x, y, np.full(len(sub), budget)
+
+            def launch():
+                x, y, _ = kops.pdhg_burst_sharded(
+                    mesh, *vecs, jnp.zeros(op.n_pad, bool),
+                    jnp.zeros(op.m_pad, bool), *ell, x0p, y0p,
+                    row_meta=op.row_meta, col_meta=op.col_meta,
+                    iters=budget, precision=precision)
+                return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
+                        np.full(len(sub), budget))
+            return launch, (op.row_idx.size + op.col_idx.size) // 2
 
         op, vecs, ell = _pack_pallas(g.c, g.row, g.col, g.val, g.b, g.h,
                                      g.xmax, g.m_eq)
@@ -1765,97 +1789,123 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
             inst_m[:g.m] = np.concatenate(
                 [np.repeat(np.arange(len(sub)), np.diff(bs.eq_off)),
                  np.repeat(np.arange(len(sub)), np.diff(bs.ub_off))])
-            x, y, _, used_chunks = kops.pdhg_adaptive(
-                *vecs, *ell, x0p, y0p, jnp.asarray(all_tols[sub]),
-                jnp.asarray(inst_n), jnp.asarray(inst_m),
-                num_inst=len(sub), row_meta=op.rows.meta,
-                col_meta=op.cols.meta, chunk=chunk,
-                max_chunks=budget // chunk, precision=precision)
-            used = np.asarray(used_chunks) * chunk
+            tols_d = jnp.asarray(all_tols[sub])
+            inst_n_d, inst_m_d = jnp.asarray(inst_n), jnp.asarray(inst_m)
+
+            def launch():
+                x, y, _, used_chunks = kops.pdhg_adaptive(
+                    *vecs, *ell, x0p, y0p, tols_d, inst_n_d, inst_m_d,
+                    num_inst=len(sub), row_meta=op.rows.meta,
+                    col_meta=op.cols.meta, chunk=chunk,
+                    max_chunks=budget // chunk, precision=precision)
+                return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
+                        np.asarray(used_chunks) * chunk)
         else:
-            x, y, _ = kops.pdhg_burst(
-                *vecs, jnp.zeros(op.n_pad, bool), jnp.zeros(op.m_pad, bool),
-                *ell, x0p, y0p, row_meta=op.rows.meta,
-                col_meta=op.cols.meta, iters=budget, precision=precision)
-            used = np.full(len(sub), budget)
-        return x, y, used
+            def launch():
+                x, y, _ = kops.pdhg_burst(
+                    *vecs, jnp.zeros(op.n_pad, bool),
+                    jnp.zeros(op.m_pad, bool), *ell, x0p, y0p,
+                    row_meta=op.rows.meta, col_meta=op.cols.meta,
+                    iters=budget, precision=precision)
+                return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
+                        np.full(len(sub), budget))
+        return launch, (op.rows.idx.size + op.cols.idx.size) // 2
+
+    def _stage_xla(g: StructuredLP, bs: BlockStackedLP, x0, y0,
+                   sub: list[int], budget: int):
+        """Stage the stacked dispatch for the COO kernels: pad to shape
+        buckets and upload.  Returns a function that runs the jitted
+        PDHG and brings (x, y, iterations per instance) to the host,
+        unpadded, and the dispatched nnz."""
+        # shape bucketing: pad the stacked dims (and the instance
+        # count) up to bucket boundaries so the jitted kernels are
+        # compiled per bucket, not per exact shape — the padding is
+        # value-neutral (see _pad_for_buckets), so trajectories
+        # match the unbucketed dispatch
+        B_sub = len(sub)
+        gp, (n_t, meq_t, mub_t) = (
+            _pad_for_buckets(g) if bucket
+            else (g, (g.n, g.m_eq, g.m - g.m_eq)))
+        shift = gp.m_eq - meq_t
+        if gp.n != n_t:
+            x0 = jnp.concatenate([x0, jnp.zeros(gp.n - n_t)])
+        if gp.m != g.m:
+            y0 = jnp.concatenate([y0[:meq_t], jnp.zeros(shift),
+                                  y0[meq_t:],
+                                  jnp.zeros(gp.m - g.m - shift)])
+        args = (jnp.asarray(gp.c), jnp.asarray(gp.row),
+                jnp.asarray(gp.col), jnp.asarray(gp.val),
+                jnp.asarray(gp.b), jnp.asarray(gp.h),
+                jnp.asarray(gp.xmax))
+
+        def unpad(x, y):
+            y_arr = np.asarray(y)
+            return np.asarray(x)[:n_t], np.concatenate(
+                [y_arr[:meq_t], y_arr[gp.m_eq:gp.m_eq + mub_t]])
+
+        if adaptive:
+            # padded coords go to the dump segment num_b; fake
+            # instances (instance-count bucketing) have no rows and
+            # tol=inf, so they freeze at the first residual check
+            num_b = ((1 << max(B_sub - 1, 0).bit_length()) if bucket
+                     else B_sub)
+            inst_n = np.full(gp.n, num_b, np.int32)
+            inst_n[:n_t] = np.repeat(np.arange(B_sub), np.diff(bs.n_off))
+            inst_m = np.full(gp.m, num_b, np.int32)
+            inst_m[:meq_t] = np.repeat(np.arange(B_sub),
+                                       np.diff(bs.eq_off))
+            inst_m[gp.m_eq:gp.m_eq + mub_t] = np.repeat(
+                np.arange(B_sub), np.diff(bs.ub_off))
+            tols_sub = np.concatenate(
+                [all_tols[sub], np.full(num_b - B_sub, np.inf)])
+            _note_dispatch(("xla", True, chunk, budget, gp.n, gp.m,
+                            gp.m_eq, len(gp.val), num_b))
+            tols_d = jnp.asarray(tols_sub)
+            inst_n_d, inst_m_d = jnp.asarray(inst_n), jnp.asarray(inst_m)
+
+            def launch():
+                x, y, _, used_chunks = _pdhg_run_adaptive(
+                    *args, x0, y0, tols_d, inst_n_d, inst_m_d, num_b,
+                    gp.m, gp.n, gp.m_eq, chunk, budget // chunk)
+                used = np.asarray(used_chunks)[:B_sub] * chunk
+                return *unpad(x, y), used
+        else:
+            _note_dispatch(("xla", False, 0, budget, gp.n, gp.m,
+                            gp.m_eq, len(gp.val)))
+
+            def launch():
+                x, y, _, _ = _pdhg_resume(*args, x0, y0, gp.m, gp.n,
+                                          gp.m_eq, budget)
+                return *unpad(x, y), np.full(B_sub, budget)
+        return launch, len(gp.val)
 
     def _run(sub: list[int], states, budget: int):
         """One stacked dispatch over the instances in `sub`; returns
         (x, y, residuals, iterations) split per instance."""
-        bs = block_stack([lps[i] for i in sub])
-        g = bs.lp
-        if states is None:
-            x0, y0 = jnp.zeros(g.n), jnp.zeros(g.m)
-        else:
-            x0 = jnp.asarray(np.concatenate([states[i][0] for i in sub]))
-            y0 = jnp.asarray(np.concatenate(
-                [states[i][1][:lps[i].m_eq] for i in sub]
-                + [states[i][1][lps[i].m_eq:] for i in sub]))
-        if backend == "pallas":
-            x, y, used = _run_pallas(g, bs, x0, y0, sub, budget)
-            x_np, y_np = np.asarray(x)[:g.n], np.asarray(y)[:g.m]
-        else:
-            # shape bucketing: pad the stacked dims (and the instance
-            # count) up to bucket boundaries so the jitted kernels are
-            # compiled per bucket, not per exact shape — the padding is
-            # value-neutral (see _pad_for_buckets), so trajectories
-            # match the unbucketed dispatch
-            B_sub = len(sub)
-            gp, (n_t, meq_t, mub_t) = (
-                _pad_for_buckets(g) if bucket
-                else (g, (g.n, g.m_eq, g.m - g.m_eq)))
-            shift = gp.m_eq - meq_t
-            if gp.n != n_t:
-                x0 = jnp.concatenate([x0, jnp.zeros(gp.n - n_t)])
-            if gp.m != g.m:
-                y0 = jnp.concatenate([y0[:meq_t], jnp.zeros(shift),
-                                      y0[meq_t:],
-                                      jnp.zeros(gp.m - g.m - shift)])
-            args = (jnp.asarray(gp.c), jnp.asarray(gp.row),
-                    jnp.asarray(gp.col), jnp.asarray(gp.val),
-                    jnp.asarray(gp.b), jnp.asarray(gp.h),
-                    jnp.asarray(gp.xmax))
-            if adaptive:
-                # padded coords go to the dump segment num_b; fake
-                # instances (instance-count bucketing) have no rows and
-                # tol=inf, so they freeze at the first residual check
-                num_b = ((1 << max(B_sub - 1, 0).bit_length()) if bucket
-                         else B_sub)
-                inst_n = np.full(gp.n, num_b, np.int32)
-                inst_n[:n_t] = np.repeat(np.arange(B_sub), np.diff(bs.n_off))
-                inst_m = np.full(gp.m, num_b, np.int32)
-                inst_m[:meq_t] = np.repeat(np.arange(B_sub),
-                                           np.diff(bs.eq_off))
-                inst_m[gp.m_eq:gp.m_eq + mub_t] = np.repeat(
-                    np.arange(B_sub), np.diff(bs.ub_off))
-                tols_sub = np.concatenate(
-                    [all_tols[sub], np.full(num_b - B_sub, np.inf)])
-                _note_dispatch(("xla", True, chunk, budget, gp.n, gp.m,
-                                gp.m_eq, len(gp.val), num_b))
-                x, y, _, used_chunks = _pdhg_run_adaptive(
-                    *args, x0, y0, jnp.asarray(tols_sub),
-                    jnp.asarray(inst_n), jnp.asarray(inst_m), num_b,
-                    gp.m, gp.n, gp.m_eq, chunk, budget // chunk)
-                used = np.asarray(used_chunks)[:B_sub] * chunk
+        with trace.span("pdhg.stack"):
+            bs = block_stack([lps[i] for i in sub])
+            g = bs.lp
+            if states is None:
+                x0, y0 = jnp.zeros(g.n), jnp.zeros(g.m)
             else:
-                _note_dispatch(("xla", False, 0, budget, gp.n, gp.m,
-                                gp.m_eq, len(gp.val)))
-                x, y, _, _ = _pdhg_resume(*args, x0, y0, gp.m, gp.n,
-                                          gp.m_eq, budget)
-                used = np.full(B_sub, budget)
-            y_arr = np.asarray(y)
-            x_np = np.asarray(x)[:n_t]
-            y_np = np.concatenate([y_arr[:meq_t],
-                                   y_arr[gp.m_eq:gp.m_eq + mub_t]])
-        res = _per_instance_residuals(bs, x_np)
-        outs = {}
-        for j, i in enumerate(sub):
-            xi = x_np[bs.n_off[j]:bs.n_off[j + 1]]
-            yi = np.concatenate(
-                [y_np[bs.eq_off[j]:bs.eq_off[j + 1]],
-                 y_np[g.m_eq + bs.ub_off[j]:g.m_eq + bs.ub_off[j + 1]]])
-            outs[i] = (xi, yi, float(res[j]), int(used[j]))
+                x0 = jnp.asarray(np.concatenate([states[i][0] for i in sub]))
+                y0 = jnp.asarray(np.concatenate(
+                    [states[i][1][:lps[i].m_eq] for i in sub]
+                    + [states[i][1][lps[i].m_eq:] for i in sub]))
+            stage = _stage_pallas if backend == "pallas" else _stage_xla
+            launch, nnz = stage(g, bs, x0, y0, sub, budget)
+        with trace.span("pdhg.run"):
+            x_np, y_np, used = launch()
+        _note_work(used, nnz, [len(lps[i].val) for i in sub])
+        with trace.span("pdhg.unstack"):
+            res = _per_instance_residuals(bs, x_np)
+            outs = {}
+            for j, i in enumerate(sub):
+                xi = x_np[bs.n_off[j]:bs.n_off[j + 1]]
+                yi = np.concatenate(
+                    [y_np[bs.eq_off[j]:bs.eq_off[j + 1]],
+                     y_np[g.m_eq + bs.ub_off[j]:g.m_eq + bs.ub_off[j + 1]]])
+                outs[i] = (xi, yi, float(res[j]), int(used[j]))
         return outs
 
     # escalation ladder with re-stacking: each level runs only the
@@ -2159,6 +2209,7 @@ def resolve_incremental(p: ScheduleProblem, objective: str,
     return _assemble_fast_result(p, lp, idx, res)
 
 
+@trace.batched
 def solve_fast_warm(p: ScheduleProblem, objective: str = "energy", *,
                     warm: FastPathResult | None = None,
                     flow_map: np.ndarray | None = None,
@@ -2204,6 +2255,7 @@ def solve_fast_warm(p: ScheduleProblem, objective: str = "energy", *,
     return out
 
 
+@trace.batched
 def solve_fast_ensemble(problems: list[ScheduleProblem],
                         objective: str = "energy", *,
                         warm: list[FastPathResult] | None = None,
@@ -2245,6 +2297,7 @@ def solve_fast_ensemble(problems: list[ScheduleProblem],
             for p, (lp, idx), res in zip(problems, built, results)]
 
 
+@trace.batched
 def solve_fast_group(problems: list[ScheduleProblem],
                      objectives: list[str] | str = "energy", *,
                      warm: list[FastPathResult | None] | None = None,

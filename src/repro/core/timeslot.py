@@ -22,6 +22,7 @@ import dataclasses
 
 import numpy as np
 
+from .. import trace
 from .topology import KIND_SERVER, KIND_SWITCH, Topology
 from .traffic import CoflowSet
 
@@ -255,6 +256,7 @@ def prefix_energy(p: ScheduleProblem, x: np.ndarray, t_end: int) -> float:
     return _activity_energy(p, x[:, :, :, :t_end].sum(axis=0))[2]
 
 
+@trace.spanned("pack.evaluate")
 def evaluate(p: ScheduleProblem, x: np.ndarray) -> Metrics:
     """Exact accounting of a schedule tensor with the paper's equations.
 

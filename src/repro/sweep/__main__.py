@@ -192,9 +192,11 @@ def main(argv=None) -> int:
                          "(cheapest first; 0 disables)")
     ap.add_argument("--oracle-time-limit", type=float, default=60.0)
     ap.add_argument("--profile", action="store_true",
-                    help="print a build/solve/report wall-time split per "
-                         "grid cell (with structure-cache hit/miss "
-                         "deltas from core.solver.build_cache_stats)")
+                    help="record the program's spans (repro.trace) and "
+                         "print a wall-time split per grid cell: LP "
+                         "build, PDHG (stack/run/unstack), pack "
+                         "(decompose/slots/evaluate), with structure-"
+                         "cache hit/miss deltas")
     ap.add_argument("--service", type=int, default=0, metavar="N",
                     help="smoke-run the multi-tenant scheduler service "
                          "(repro.service) with N tenants cycling through "

@@ -27,13 +27,14 @@ by core.verify.check_schedule before it is recorded.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable
 
 import numpy as np
 
-from repro import search
+from repro import search, trace
 from repro.core import (arrivals, failures, oracle, solver, timeslot,
                         topology, traffic)
 from repro.core import chaos as chaosmod
@@ -98,9 +99,10 @@ class SweepSpec:
     path_slack: int | None = 2        # near-shortest route pruning; None = off
     oracle_check: int = 0             # instances to spot-check vs the MILP
     oracle_time_limit: float = 60.0
-    # print a build/solve wall-time split per grid cell (problem + LP
-    # assembly vs PDHG/packing), with structure-cache hit/miss deltas
-    # from core.solver.build_cache_stats()
+    # record the program's spans (repro.trace) and print a wall-time
+    # split per grid cell: LP build, PDHG host and device work, pack,
+    # with structure-cache hit/miss deltas from
+    # core.solver.build_cache_stats()
     profile: bool = False
 
     def validate(self) -> None:
@@ -220,18 +222,38 @@ class SweepRecord:
         return self.energy_j if self.objective == "energy" else self.completion_s
 
 
-def _profile_line(say, label: str, snap, wall_s: float) -> None:
-    """One --profile line: LP-assembly vs solve split for a finished cell
-    (`snap` is the build_cache_stats snapshot taken before the cell)."""
+def _profile_mark() -> tuple:
+    """Where a cell starts, for its --profile line: the number of spans
+    recorded so far and the build-cache counters."""
+    rec = trace.active()
+    return (len(rec.records) if rec else 0,
+            solver.build_cache_stats().snapshot())
+
+
+def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
+    """One --profile line for a finished cell, from the spans recorded
+    since `mark` (_profile_mark): LP build (`lp.build`), PDHG
+    (`pdhg.*`: stack, run, unstack), pack (`pack.*`: decompose, slots,
+    evaluate) and the rest of the cell's wall time."""
+    start, snap = mark
+    rec = trace.active()
+
+    def ms(prefix: str) -> float:
+        return rec.total(prefix, start) * 1e3
+
     d = solver.build_cache_stats()
-    build_s = ((d.structure_s + d.fill_s + d.ell_s)
-               - (snap.structure_s + snap.fill_s + snap.ell_s))
-    say(f"    profile {label}: build {build_s * 1e3:7.1f} ms "
+    build, pdhg, pack = ms("lp.build"), ms("pdhg."), ms("pack.")
+    say(f"    profile {label}: build {build:7.1f} ms "
         f"(structure {d.structure_hits - snap.structure_hits} hit"
         f"/{d.structure_misses - snap.structure_misses} miss, "
         f"ell {d.ell_hits - snap.ell_hits} hit"
         f"/{d.ell_misses - snap.ell_misses} miss) | "
-        f"solve {(wall_s - build_s) * 1e3:8.1f} ms | "
+        f"pdhg {pdhg:8.1f} ms (stack {ms('pdhg.stack'):.1f}, "
+        f"run {ms('pdhg.run'):.1f}, unstack {ms('pdhg.unstack'):.1f}) | "
+        f"pack {pack:8.1f} ms (decompose {ms('pack.decompose'):.1f}, "
+        f"slots {ms('pack.slots'):.1f}, "
+        f"evaluate {ms('pack.evaluate'):.1f}) | "
+        f"other {wall_s * 1e3 - build - pdhg - pack:8.1f} ms | "
         f"total {wall_s * 1e3:8.1f} ms")
 
 
@@ -543,9 +565,16 @@ def _record(topo_name, obj, pat_name, seed, p, r, per_inst_s, *,
 
 def run_sweep(spec: SweepSpec, *, log: Callable[[str], None] | None = None
               ) -> tuple[list[SweepRecord], list[timeslot.ScheduleProblem]]:
-    """Run the grid; returns (records, problems) with parallel indexing."""
+    """Run the grid; returns (records, problems) with parallel indexing.
+    With `spec.profile` the program's spans are recorded for the
+    per-cell profile lines."""
     spec.validate()
-    say = log or (lambda s: None)
+    with trace.recording() if spec.profile else contextlib.nullcontext():
+        return _run_grid(spec, log or (lambda s: None))
+
+
+def _run_grid(spec: SweepSpec, say: Callable[[str], None]
+              ) -> tuple[list[SweepRecord], list[timeslot.ScheduleProblem]]:
     records: list[SweepRecord] = []
     problems: list[timeslot.ScheduleProblem] = []
     for topo_name in spec.topos:
@@ -571,7 +600,7 @@ def run_sweep(spec: SweepSpec, *, log: Callable[[str], None] | None = None
                 # shallow copy: problems are objective-independent, but
                 # _solve_group may swap entries during its retry ladder
                 probs = list(base_probs)
-                snap = solver.build_cache_stats().snapshot()
+                snap = _profile_mark()
                 t_cell = time.perf_counter()
                 results, per_inst_s = _solve_group(probs, OBJECTIVES[obj], spec)
                 t_cell = time.perf_counter() - t_cell
@@ -593,7 +622,7 @@ def run_sweep(spec: SweepSpec, *, log: Callable[[str], None] | None = None
                 _policy_records(records, problems, spec, say, topo_name,
                                 obj, pat_name, probs, results, offered)
                 for fail_name in spec.failures:
-                    snap = solver.build_cache_stats().snapshot()
+                    snap = _profile_mark()
                     t_cell = time.perf_counter()
                     f_probs, f_results, f_s = _solve_failure_group(
                         probs, results, fail_name, OBJECTIVES[obj], spec)
@@ -625,7 +654,7 @@ def run_sweep(spec: SweepSpec, *, log: Callable[[str], None] | None = None
                                     failure=fail_name, ratios=ratios)
                 for fam in spec.arrivals:
                     fam_recs = []
-                    snap = solver.build_cache_stats().snapshot()
+                    snap = _profile_mark()
                     t_cell = time.perf_counter()
                     for seed in spec.seeds:
                         trace, res, wall = _solve_arrival_cell(
@@ -650,7 +679,7 @@ def run_sweep(spec: SweepSpec, *, log: Callable[[str], None] | None = None
                             snap, time.perf_counter() - t_cell)
                 for preset in spec.chaos:
                     cz_recs = []
-                    snap = solver.build_cache_stats().snapshot()
+                    snap = _profile_mark()
                     t_cell = time.perf_counter()
                     for seed in spec.seeds:
                         trace, res, wall = _solve_chaos_cell(

@@ -18,6 +18,8 @@ that make that refactor safe:
   3. shape bucketing is value-neutral: bucketed solves match unbucketed
      within the golden 1e-4 envelope (on CPU they are bitwise equal).
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -319,8 +321,23 @@ def test_sweep_profile_prints_build_solve_split():
     assert len(records) == 2
     prof = [ln for ln in lines if "profile" in ln]
     assert any("problem generation" in ln for ln in prof)
-    assert any("build" in ln and "solve" in ln and "structure" in ln
-               for ln in prof)
+    cell = [ln for ln in prof if "spine-leaf/uniform/min-energy:" in ln]
+    assert len(cell) == 1
+    # the split comes from the program's spans (repro.trace): build,
+    # PDHG host and device work, and the pack split three ways
+    ms = {k: float(v) for k, v in re.findall(r"(\w+) +([\d.]+) ms",
+                                            cell[0])}
+    assert set(ms) == {"build", "pdhg", "pack", "other", "total"}
+    parts = dict(re.findall(r"(\w+) ([\d.]+)[,)]", cell[0]))
+    assert set(parts) >= {"stack", "run", "unstack", "decompose", "slots",
+                          "evaluate"}
+    assert ms["build"] > 0 and ms["pdhg"] > 0 and ms["pack"] > 0
+    assert float(parts["run"]) > 0 and float(parts["slots"]) > 0
+    assert ms["build"] + ms["pdhg"] + ms["pack"] <= ms["total"] + 0.1
+    assert "structure" in cell[0]
+    # the recording ends with the sweep
+    from repro import trace
+    assert trace.active() is None
 
 
 def test_bench_trend_tool_modes():
