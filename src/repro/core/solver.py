@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import trace
+from . import tile_spmv
 from .timeslot import Metrics, ScheduleProblem, evaluate
 
 Array = jax.Array
@@ -102,12 +103,29 @@ class PDHGResult:
     y: np.ndarray | None = None
 
 
-def _pdhg_ops(c, row, col, val, b, h, m, n, m_eq):
+def _coo_pair(row, col, val, m, n):
+    """(Kx, KTy) as one scalar gather and scatter-add per nonzero."""
+    def Kx(x):
+        with jax.named_scope("pdhg/Kx"):
+            return jnp.zeros(m).at[row].add(val * x[col])
+
+    def KTy(y):
+        with jax.named_scope("pdhg/KTy"):
+            return jnp.zeros(n).at[col].add(val * y[row])
+
+    return Kx, KTy
+
+
+def _pdhg_ops(c, row, col, val, b, h, m, n, m_eq, tiles=None):
     """Shared PDHG machinery: stacked rhs q, diagonal preconditioners
     (tau_j = 1/sum_i |K_ij|, sig_i = 1/sum_j |K_ij|), the sparse operator
     pair (Kx, KTy), and the inequality-row mask.  Single source of truth
     for both the resumable kernel and the fused adaptive batch kernel —
     their trajectories must stay identical.
+
+    The operator pair is the COO scatter (`tiles` None) or, given the
+    stacked tile plan's arrays, the block-sparse tile operator of
+    core.tile_spmv; the update formulas are the same for both.
 
     The pallas backend mirrors these formulas: _pack_pallas
     (preconditioners/q/ub mask, numpy) and the shared update body
@@ -122,14 +140,8 @@ def _pdhg_ops(c, row, col, val, b, h, m, n, m_eq):
     tau = 1.0 / jnp.maximum(col_sum, 1e-12)
     sig = 1.0 / jnp.maximum(row_sum, 1e-12)
 
-    def Kx(x):
-        with jax.named_scope("pdhg/Kx"):
-            return jnp.zeros(m).at[row].add(val * x[col])
-
-    def KTy(y):
-        with jax.named_scope("pdhg/KTy"):
-            return jnp.zeros(n).at[col].add(val * y[row])
-
+    Kx, KTy = (_coo_pair(row, col, val, m, n) if tiles is None
+               else tile_spmv.operator_pair(tiles, val, m, n))
     ub_mask = jnp.arange(m) >= m_eq
     return q, tau, sig, Kx, KTy, ub_mask
 
@@ -253,6 +265,26 @@ def _ell_operator_cached(row, col, val, m, n):
     else:
         BUILD_STATS.ell_hits += 1
     return pdhg_spmv.ell_fill(plan, val)
+
+
+def _tile_plan_cached(lp: StructuredLP) -> tile_spmv.InstancePlan:
+    """One instance's tile plan (core.tile_spmv.instance_plan), cached
+    per sparsity pattern like _ell_operator_cached: keyed by a digest of
+    (row, col) and (m, n, m_eq); counters land in BUILD_STATS."""
+    key = (lp.m, lp.n, lp.m_eq, len(lp.val),
+           hashlib.blake2b(np.ascontiguousarray(lp.row).tobytes()
+                           + np.ascontiguousarray(lp.col).tobytes(),
+                           digest_size=16).digest())
+    plan = _TILE_PLAN_CACHE.get(key)
+    if plan is None:
+        plan = tile_spmv.instance_plan(lp.row, lp.col, lp.m, lp.n, lp.m_eq)
+        BUILD_STATS.tile_misses += 1
+        if len(_TILE_PLAN_CACHE) >= _TILE_PLAN_CACHE_MAX:
+            _TILE_PLAN_CACHE.pop(next(iter(_TILE_PLAN_CACHE)))
+        _TILE_PLAN_CACHE[key] = plan
+    else:
+        BUILD_STATS.tile_hits += 1
+    return plan
 
 
 def _pack_pallas(c, row, col, val, b, h, xmax, m_eq):
@@ -409,7 +441,7 @@ def _solve_lp_pallas_sharded(lp: StructuredLP, iters: int, tol: float,
     "num_inst", "m", "n", "m_eq", "chunk", "max_chunks"))
 def _pdhg_run_adaptive(c, row, col, val, b, h, xmax, x0, y0, tols,
                        inst_n, inst_m,
-                       num_inst, m, n, m_eq, chunk, max_chunks):
+                       num_inst, m, n, m_eq, chunk, max_chunks, tiles=None):
     """Fused adaptive PDHG over a block-stacked instance batch.
 
     Runs `chunk`-iteration bursts inside one jitted lax.while_loop,
@@ -429,9 +461,12 @@ def _pdhg_run_adaptive(c, row, col, val, b, h, xmax, x0, y0, tols,
     the residual vector — identical semantics to kernels.ops'
     pdhg_adaptive.
 
+    `tiles` (core.tile_spmv.StackedTiles.arrays) selects the tile
+    operator; its tables are filled once, before the loop.
+
     Returns (x, y, per-instance residuals, per-instance chunks used)."""
     q, tau, sig, Kx, KTy, ub_mask = _pdhg_ops(c, row, col, val, b, h,
-                                              m, n, m_eq)
+                                              m, n, m_eq, tiles)
 
     def residuals(x):
         with jax.named_scope("pdhg/residual"):
@@ -694,8 +729,8 @@ class ProblemStructure:
 
 @dataclasses.dataclass
 class BuildCacheStats:
-    """Counters for the problem-construction fast path (structure cache
-    + blocked-ELL plan cache).  Read via `build_cache_stats()`, cleared
+    """Counters for the problem-construction fast path (structure cache,
+    blocked-ELL and tile plan caches).  Read via `build_cache_stats()`, cleared
     via `reset_build_caches()`; `python -m repro.sweep --profile` prints
     per-cell deltas."""
 
@@ -703,6 +738,8 @@ class BuildCacheStats:
     structure_misses: int = 0
     ell_hits: int = 0
     ell_misses: int = 0
+    tile_hits: int = 0
+    tile_misses: int = 0
 
     def snapshot(self) -> "BuildCacheStats":
         return dataclasses.replace(self)
@@ -713,6 +750,9 @@ _STRUCTURE_CACHE: dict = {}
 _STRUCTURE_CACHE_MAX = 256
 _ELL_PLAN_CACHE: dict = {}
 _ELL_PLAN_CACHE_MAX = 256
+_TILE_PLAN_CACHE: dict = {}
+_TILE_PLAN_CACHE_MAX = 256
+_TILE_CAPACITY: dict = {}
 
 
 def build_cache_stats() -> BuildCacheStats:
@@ -721,9 +761,12 @@ def build_cache_stats() -> BuildCacheStats:
 
 
 def reset_build_caches() -> None:
-    """Drop the structure and ELL-plan caches and zero the counters."""
+    """Drop the structure, ELL-plan and tile-plan caches and zero the
+    counters."""
     _STRUCTURE_CACHE.clear()
     _ELL_PLAN_CACHE.clear()
+    _TILE_PLAN_CACHE.clear()
+    _TILE_CAPACITY.clear()
     for f in dataclasses.fields(BuildCacheStats):
         setattr(BUILD_STATS, f.name, f.default)
 
@@ -749,13 +792,21 @@ class DispatchStats:
     the dump segment included), `nnz_iters_useful` what moved an
     unconverged instance (each instance's iterations times its own
     nnz).  Their ratio is the share of PDHG's scatter work that was
-    not wasted."""
+    not wasted.
+
+    Dispatches that apply K as dense tiles (core.tile_spmv) count in
+    `tiled_dispatches`, and their tile entries in `tile_slots_run`: the
+    mean of the two directions' dispatched (bucketed) tile counts, times
+    1,024 entries a tile, times the longest instance's iterations.
+    `nnz_iters_useful / tile_slots_run` is then the tiles' fill."""
 
     dispatches: int = 0
     shape_hits: int = 0
     shape_misses: int = 0
     nnz_iters_run: int = 0
     nnz_iters_useful: int = 0
+    tiled_dispatches: int = 0
+    tile_slots_run: int = 0
 
     def snapshot(self) -> "DispatchStats":
         return dataclasses.replace(self)
@@ -787,13 +838,19 @@ def _note_dispatch(shape: tuple) -> None:
         _DISPATCH_SHAPES.add(shape)
 
 
-def _note_work(used: np.ndarray, nnz_run: int, nnz: list[int]) -> None:
+def _note_work(used: np.ndarray, nnz_run: int, nnz: list[int],
+               tile_slots: int = 0) -> None:
     """Record one dispatch's PDHG work (see DispatchStats): `used` holds
-    each instance's iterations, `nnz_run` the dispatched nnz and `nnz`
-    each instance's own."""
-    DISPATCH_STATS.nnz_iters_run += int(used.max(initial=0)) * nnz_run
+    each instance's iterations, `nnz_run` the dispatched nnz, `nnz`
+    each instance's own and `tile_slots` a direction's tile entries
+    (0 where K is applied as COO)."""
+    longest = int(used.max(initial=0))
+    DISPATCH_STATS.nnz_iters_run += longest * nnz_run
     DISPATCH_STATS.nnz_iters_useful += sum(int(u) * k
                                            for u, k in zip(used, nnz))
+    if tile_slots:
+        DISPATCH_STATS.tiled_dispatches += 1
+        DISPATCH_STATS.tile_slots_run += longest * tile_slots
 
 
 def _structure_key(p: ScheduleProblem, objective: str) -> tuple:
@@ -1678,6 +1735,59 @@ def _pad_for_buckets(g: StructuredLP) -> tuple[StructuredLP,
     ), (n_t, meq_t, mub_t)
 
 
+def _tile_layout(lps: list[StructuredLP], g: StructuredLP, bucket: bool
+                 ) -> tuple[StructuredLP, tile_spmv.StackedTiles]:
+    """Lay out the stack `g` of `lps` (block_stack) for the tile
+    operator: each instance's columns, equality rows and inequality rows
+    start on a tile boundary, then (with `bucket`) the totals, the nnz
+    and each direction's tile count are padded to shape buckets.  The
+    padding is value-neutral, as _pad_for_buckets' is.  So the shapes
+    depend on the instances and not on their order, and an instance's
+    tiles are the same alone or stacked."""
+    plans = [_tile_plan_cached(lp) for lp in lps]
+    dim = ((lambda d: _bucket(d, minimum=tile_spmv.LANES)) if bucket
+           else (lambda d: d))
+    n = dim(sum(p.n_a for p in plans))
+    m_eq = dim(sum(p.m_eq_a for p in plans))
+    m = m_eq + dim(sum(p.m_ub_a for p in plans))
+    nnz = _bucket(len(g.val)) if bucket else len(g.val)
+    tiles = (sum(p.kx.tiles for p in plans), sum(p.kty.tiles for p in plans))
+    if bucket:
+        tiles = _tile_capacity((n, m_eq, m, nnz), tiles)
+    st = tile_spmv.stack(plans, n, m_eq, m, nnz, *tiles)
+    c, xmax, q = np.zeros(n), np.zeros(n), np.zeros(m)
+    c[st.pos_n], xmax[st.pos_n] = g.c, g.xmax
+    q[st.pos_m] = np.concatenate([g.b, g.h])
+    pad = np.zeros(nnz - len(g.val), np.int64)
+    return StructuredLP(
+        c=c, row=np.concatenate([st.pos_m[g.row], pad]),
+        col=np.concatenate([st.pos_n[g.col], pad]),
+        val=np.concatenate([g.val, np.zeros(len(pad))]),
+        b=q[:m_eq], h=q[m_eq:], xmax=xmax), st
+
+
+def _tile_capacity(dims: tuple, tiles: tuple[int, int]) -> tuple[int, int]:
+    """Padded tile counts (K.x, K^T.y) for a dispatch of `dims` holding
+    `tiles`.  Draws of one LP shape differ in their tile counts by a few
+    per cent (a relabelled rack or pod moves nonzeros between tiles), so
+    a count is padded to the shape bucket of itself plus 1/16, and never
+    below the largest padded count given before for the same dims: the
+    draws then share one compiled program, as their other dims do."""
+    old = _TILE_CAPACITY.get(dims, (0, 0))
+    cap = tuple(c if t <= c else _bucket(t + t // 16)
+                for t, c in zip(tiles, old))
+    _TILE_CAPACITY[dims] = cap
+    return cap
+
+
+def _tiled_spmv() -> bool:
+    """Whether the XLA backend's adaptive dispatches apply K as dense
+    tiles (core.tile_spmv).  On a TPU every scalar gather and
+    scatter-add of the COO pair costs a fixed time, so they do; on a
+    CPU, whose scatter is cheap, the COO pair stays, bit for bit."""
+    return jax.default_backend() == "tpu"
+
+
 def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                    tol: float | None = None, max_restarts: int = 3,
                    adaptive: bool = True, chunk: int = 500,
@@ -1733,6 +1843,7 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
     backend="pallas" (see solve_lp)."""
     _check_backend(backend)
     _check_scale_opts(backend, shards, precision)
+    tiled = backend == "xla" and adaptive and _tiled_spmv()
     B = len(lps)
     all_tols = np.array([tol if tol is not None
                          else 1e-4 * max(float(np.abs(lp.b).max(initial=0.0)),
@@ -1771,7 +1882,7 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                     iters=budget, precision=precision)
                 return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
                         np.full(len(sub), budget))
-            return launch, (op.row_idx.size + op.col_idx.size) // 2
+            return launch, (op.row_idx.size + op.col_idx.size) // 2, 0
 
         op, vecs, ell = _pack_pallas(g.c, g.row, g.col, g.val, g.b, g.h,
                                      g.xmax, g.m_eq)
@@ -1809,39 +1920,45 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                     iters=budget, precision=precision)
                 return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
                         np.full(len(sub), budget))
-        return launch, (op.rows.idx.size + op.cols.idx.size) // 2
+        return launch, (op.rows.idx.size + op.cols.idx.size) // 2, 0
 
     def _stage_xla(g: StructuredLP, bs: BlockStackedLP, x0, y0,
                    sub: list[int], budget: int):
-        """Stage the stacked dispatch for the COO kernels: pad to shape
-        buckets and upload.  Returns a function that runs the jitted
-        PDHG and brings (x, y, iterations per instance) to the host,
-        unpadded, and the dispatched nnz."""
+        """Stage the stacked dispatch for the XLA kernels: lay it out
+        and upload.  Returns a function that runs the jitted PDHG and
+        brings (x, y, iterations per instance) to the host, in the
+        coordinates of `g`, the dispatched nnz, and a direction's tile
+        entries (0 for the COO operator)."""
         # shape bucketing: pad the stacked dims (and the instance
         # count) up to bucket boundaries so the jitted kernels are
         # compiled per bucket, not per exact shape — the padding is
         # value-neutral (see _pad_for_buckets), so trajectories
-        # match the unbucketed dispatch
+        # match the unbucketed dispatch.  `pos_n` / `pos_m` say where
+        # each column and row of `g` went.
         B_sub = len(sub)
-        gp, (n_t, meq_t, mub_t) = (
-            _pad_for_buckets(g) if bucket
-            else (g, (g.n, g.m_eq, g.m - g.m_eq)))
-        shift = gp.m_eq - meq_t
-        if gp.n != n_t:
-            x0 = jnp.concatenate([x0, jnp.zeros(gp.n - n_t)])
-        if gp.m != g.m:
-            y0 = jnp.concatenate([y0[:meq_t], jnp.zeros(shift),
-                                  y0[meq_t:],
-                                  jnp.zeros(gp.m - g.m - shift)])
+        if tiled:
+            gp, st = _tile_layout([lps[i] for i in sub], g, bucket)
+            pos_n, pos_m = st.pos_n, st.pos_m
+            tiles = tuple(jnp.asarray(a) for a in st.arrays())
+            t_kx, t_kty = st.kx.tiles, st.kty.tiles
+        else:
+            gp, (n_t, meq_t, mub_t) = (
+                _pad_for_buckets(g) if bucket
+                else (g, (g.n, g.m_eq, g.m - g.m_eq)))
+            pos_n = np.arange(n_t)
+            pos_m = np.concatenate([np.arange(meq_t),
+                                    gp.m_eq + np.arange(mub_t)])
+            tiles, t_kx, t_kty = None, 0, 0
+        x0p, y0p = np.zeros(gp.n), np.zeros(gp.m)
+        x0p[pos_n], y0p[pos_m] = x0, y0
+        x0p, y0p = jnp.asarray(x0p), jnp.asarray(y0p)
         args = (jnp.asarray(gp.c), jnp.asarray(gp.row),
                 jnp.asarray(gp.col), jnp.asarray(gp.val),
                 jnp.asarray(gp.b), jnp.asarray(gp.h),
                 jnp.asarray(gp.xmax))
 
         def unpad(x, y):
-            y_arr = np.asarray(y)
-            return np.asarray(x)[:n_t], np.concatenate(
-                [y_arr[:meq_t], y_arr[gp.m_eq:gp.m_eq + mub_t]])
+            return np.asarray(x)[pos_n], np.asarray(y)[pos_m]
 
         if adaptive:
             # padded coords go to the dump segment num_b; fake
@@ -1850,23 +1967,22 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
             num_b = ((1 << max(B_sub - 1, 0).bit_length()) if bucket
                      else B_sub)
             inst_n = np.full(gp.n, num_b, np.int32)
-            inst_n[:n_t] = np.repeat(np.arange(B_sub), np.diff(bs.n_off))
+            inst_n[pos_n] = np.repeat(np.arange(B_sub), np.diff(bs.n_off))
             inst_m = np.full(gp.m, num_b, np.int32)
-            inst_m[:meq_t] = np.repeat(np.arange(B_sub),
-                                       np.diff(bs.eq_off))
-            inst_m[gp.m_eq:gp.m_eq + mub_t] = np.repeat(
-                np.arange(B_sub), np.diff(bs.ub_off))
+            inst_m[pos_m] = np.concatenate(
+                [np.repeat(np.arange(B_sub), np.diff(bs.eq_off)),
+                 np.repeat(np.arange(B_sub), np.diff(bs.ub_off))])
             tols_sub = np.concatenate(
                 [all_tols[sub], np.full(num_b - B_sub, np.inf)])
             _note_dispatch(("xla", True, chunk, budget, gp.n, gp.m,
-                            gp.m_eq, len(gp.val), num_b))
+                            gp.m_eq, len(gp.val), num_b, t_kx, t_kty))
             tols_d = jnp.asarray(tols_sub)
             inst_n_d, inst_m_d = jnp.asarray(inst_n), jnp.asarray(inst_m)
 
             def launch():
                 x, y, _, used_chunks = _pdhg_run_adaptive(
-                    *args, x0, y0, tols_d, inst_n_d, inst_m_d, num_b,
-                    gp.m, gp.n, gp.m_eq, chunk, budget // chunk)
+                    *args, x0p, y0p, tols_d, inst_n_d, inst_m_d, num_b,
+                    gp.m, gp.n, gp.m_eq, chunk, budget // chunk, tiles)
                 used = np.asarray(used_chunks)[:B_sub] * chunk
                 return *unpad(x, y), used
         else:
@@ -1874,10 +1990,10 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                             gp.m_eq, len(gp.val)))
 
             def launch():
-                x, y, _, _ = _pdhg_resume(*args, x0, y0, gp.m, gp.n,
+                x, y, _, _ = _pdhg_resume(*args, x0p, y0p, gp.m, gp.n,
                                           gp.m_eq, budget)
                 return *unpad(x, y), np.full(B_sub, budget)
-        return launch, len(gp.val)
+        return launch, len(gp.val), (t_kx + t_kty) * tile_spmv.SLOTS // 2
 
     def _run(sub: list[int], states, budget: int):
         """One stacked dispatch over the instances in `sub`; returns
@@ -1886,17 +2002,17 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
             bs = block_stack([lps[i] for i in sub])
             g = bs.lp
             if states is None:
-                x0, y0 = jnp.zeros(g.n), jnp.zeros(g.m)
+                x0, y0 = np.zeros(g.n), np.zeros(g.m)
             else:
-                x0 = jnp.asarray(np.concatenate([states[i][0] for i in sub]))
-                y0 = jnp.asarray(np.concatenate(
+                x0 = np.concatenate([states[i][0] for i in sub])
+                y0 = np.concatenate(
                     [states[i][1][:lps[i].m_eq] for i in sub]
-                    + [states[i][1][lps[i].m_eq:] for i in sub]))
+                    + [states[i][1][lps[i].m_eq:] for i in sub])
             stage = _stage_pallas if backend == "pallas" else _stage_xla
-            launch, nnz = stage(g, bs, x0, y0, sub, budget)
+            launch, nnz, tile_slots = stage(g, bs, x0, y0, sub, budget)
         with trace.span("pdhg.run"):
             x_np, y_np, used = launch()
-        _note_work(used, nnz, [len(lps[i].val) for i in sub])
+        _note_work(used, nnz, [len(lps[i].val) for i in sub], tile_slots)
         with trace.span("pdhg.unstack"):
             res = _per_instance_residuals(bs, x_np)
             outs = {}

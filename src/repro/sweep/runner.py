@@ -224,32 +224,38 @@ class SweepRecord:
 
 def _profile_mark() -> tuple:
     """Where a cell starts, for its --profile line: the number of spans
-    recorded so far and the build-cache counters."""
+    recorded so far, the build-cache and the dispatch counters."""
     rec = trace.active()
     return (len(rec.records) if rec else 0,
-            solver.build_cache_stats().snapshot())
+            solver.build_cache_stats().snapshot(),
+            solver.dispatch_stats().snapshot())
 
 
 def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
     """One --profile line for a finished cell, from the spans recorded
     since `mark` (_profile_mark): LP build (`lp.build`), PDHG
     (`pdhg.*`: stack, run, unstack), pack (`pack.*`: decompose, slots,
-    evaluate) and the rest of the cell's wall time."""
-    start, snap = mark
+    evaluate) and the rest of the cell's wall time, with the build
+    caches' hits and how many PDHG dispatches applied K as tiles."""
+    start, snap, disp = mark
     rec = trace.active()
 
     def ms(prefix: str) -> float:
         return rec.total(prefix, start) * 1e3
 
-    d = solver.build_cache_stats()
+    d, t = solver.build_cache_stats(), solver.dispatch_stats()
     build, pdhg, pack = ms("lp.build"), ms("pdhg."), ms("pack.")
     say(f"    profile {label}: build {build:7.1f} ms "
         f"(structure {d.structure_hits - snap.structure_hits} hit"
         f"/{d.structure_misses - snap.structure_misses} miss, "
         f"ell {d.ell_hits - snap.ell_hits} hit"
-        f"/{d.ell_misses - snap.ell_misses} miss) | "
+        f"/{d.ell_misses - snap.ell_misses} miss, "
+        f"tiles {d.tile_hits - snap.tile_hits} hit"
+        f"/{d.tile_misses - snap.tile_misses} miss) | "
         f"pdhg {pdhg:8.1f} ms (stack {ms('pdhg.stack'):.1f}, "
-        f"run {ms('pdhg.run'):.1f}, unstack {ms('pdhg.unstack'):.1f}) | "
+        f"run {ms('pdhg.run'):.1f}, unstack {ms('pdhg.unstack'):.1f}, "
+        f"tiled {t.tiled_dispatches - disp.tiled_dispatches}"
+        f"/{t.dispatches - disp.dispatches} dispatches) | "
         f"pack {pack:8.1f} ms (decompose {ms('pack.decompose'):.1f}, "
         f"slots {ms('pack.slots'):.1f}, "
         f"evaluate {ms('pack.evaluate'):.1f}) | "

@@ -14,6 +14,7 @@ The LP is the fat-tree-k16 instance of benchmarks/scale_bench.py:
 1,024 servers, 20 mappers x 12 reducers, 120 Gbit, seed 0.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,18 +111,57 @@ def test_pdhg_resume_compiles_for_one_v5e(one_chip, lp_k16):
     _fits_one_chip(compiled)
 
 
-def test_pdhg_run_adaptive_compiles_for_one_v5e(one_chip, lp_k16):
-    """The batched path (sweep, run_online, run_service): the fused
-    adaptive loop over the shape-bucketed stacked LP."""
-    gp, _ = solver._pad_for_buckets(solver.block_stack([lp_k16]).lp)
+def _loop_scatter_updates(compiled) -> list[int]:
+    """Updates (leading dim of the update operand) of every scatter in
+    the optimized HLO whose op sits inside a while loop's body."""
+    text = compiled.as_text()
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"scatter\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)",
+                      line)
+        if m and "/while/body" in line:
+            out.append(int(shapes[m.group(1)].split(",")[0]))
+    return out
+
+
+def _compile_adaptive(gp, one_chip, tiles=None):
+    """_pdhg_run_adaptive for one instance `gp`, `tiles` the tile plan's
+    arrays or None for the COO operator."""
     f32, i32 = jnp.float32, jnp.int32
     chunk = 500
-    compiled = solver._pdhg_run_adaptive.lower(
+    tile_specs = None if tiles is None else tuple(
+        _spec(a.shape, i32, one_chip) for a in tiles)
+    return solver._pdhg_run_adaptive.lower(
         *_lp_specs(gp, one_chip), _spec((gp.n,), f32, one_chip),
         _spec((gp.m,), f32, one_chip), _spec((1,), f32, one_chip),
         _spec((gp.n,), i32, one_chip), _spec((gp.m,), i32, one_chip),
-        1, gp.m, gp.n, gp.m_eq, chunk, 4 * ITERS // chunk).compile()
+        1, gp.m, gp.n, gp.m_eq, chunk, 4 * ITERS // chunk,
+        tile_specs).compile()
+
+
+def test_pdhg_run_adaptive_compiles_for_one_v5e(one_chip, lp_k16):
+    """The batched path (sweep, run_online, run_service) with the COO
+    operator: the fused adaptive loop over the shape-bucketed stacked
+    LP, one scalar scatter-add per nonzero inside the loop."""
+    gp, _ = solver._pad_for_buckets(solver.block_stack([lp_k16]).lp)
+    compiled = _compile_adaptive(gp, one_chip)
     _fits_one_chip(compiled)
+    assert len(gp.val) in _loop_scatter_updates(compiled)
+
+
+def test_tiled_pdhg_run_adaptive_compiles_for_one_v5e(one_chip, lp_k16):
+    """The same loop with the tile operator, as a TPU runs it: it fits
+    the chip, and no scatter inside the loop moves one update per
+    nonzero (the tiles' segment-sums move one per tile)."""
+    g = solver.block_stack([lp_k16]).lp
+    gp, st = solver._tile_layout([lp_k16], g, True)
+    compiled = _compile_adaptive(gp, one_chip, st.arrays())
+    _fits_one_chip(compiled)
+    updates = _loop_scatter_updates(compiled)
+    assert st.kx.tiles in updates and st.kty.tiles in updates
+    assert len(gp.val) not in updates
+    assert max(updates) < len(g.val)
 
 
 def test_sharded_burst_compiles_for_v5e_2x2(tpu_topology, lp_k16):
