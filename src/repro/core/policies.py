@@ -48,7 +48,7 @@ from . import verify
 from .solver import (FastPathResult, FlowPath, RoutingIndex, _admissible,
                      _device_cost_per_gbit, _out_edges, _route_search,
                      solve_fast, temporal_pack)
-from .timeslot import ScheduleProblem, _hop_distances, evaluate
+from .timeslot import ScheduleProblem, evaluate, hop_rows
 
 DEFAULT_K_PATHS = 4      # candidate paths per flow for ecmp/least-loaded
 _GAP_NOISE = 0.02        # sub-1.0 gap ratios within this are certified ties
@@ -162,7 +162,6 @@ def path_sets(p: ScheduleProblem, k: int = DEFAULT_K_PATHS
     F, E, W, _ = p.shape_x
     passive = ~(p.is_server | p.is_switch)
     out_edges = _out_edges(p)
-    dist = _hop_distances(p.topo)
     e_dst = p.e_dst
     bounds = np.searchsorted(kf, np.arange(F + 1))
     k_map = np.full((E, W), -1, dtype=np.int64)
@@ -177,7 +176,8 @@ def path_sets(p: ScheduleProblem, k: int = DEFAULT_K_PATHS
         es, ws = ke[lo:hi], kw[lo:hi]
         k_map[es, ws] = np.arange(lo, hi)
         src, dst = int(p.coflow.src[f]), int(p.coflow.dst[f])
-        d0 = dist[src, dst]
+        to_dst = hop_rows(p.topo, [dst], to=True)[0]
+        d0 = to_dst[src]
         bound = (int(d0) if np.isfinite(d0) else E) + _ENUM_SLACK
 
         found: list[tuple[tuple[int, int], ...]] = []
@@ -196,7 +196,7 @@ def path_sets(p: ScheduleProblem, k: int = DEFAULT_K_PATHS
             convert = (w_in == -1) or not passive[u]
             for e in out_edges[u]:
                 v = int(e_dst[e])
-                if v in visited or len(trail) + 1 + dist[v, dst] > bound:
+                if v in visited or len(trail) + 1 + to_dst[v] > bound:
                     continue
                 for w in range(W):
                     if not convert and w != w_in:

@@ -21,6 +21,8 @@ import copy
 import dataclasses
 
 import numpy as np
+from scipy import sparse
+from scipy.sparse import csgraph
 
 from .. import trace
 from .topology import KIND_SERVER, KIND_SWITCH, Topology
@@ -74,39 +76,42 @@ class ScheduleProblem:
         self.eps = np.array([d.eps for d in t.devices])
         self.sigma = np.array([t.switch_sigma.get(i, np.inf)
                                for i in range(t.n_vertices)])
-        # flow-edge mask: 1 = flow f may use edge e
-        F, E = self.coflow.n_flows, t.n_edges
-        mask = np.ones((F, E), dtype=bool)
-        src, dst = self.coflow.src, self.coflow.dst
-        u_is_server = self.is_server[self.e_src]
-        v_is_server = self.is_server[self.e_dst]
-        # never re-enter the source / leave the destination
-        mask &= ~(self.e_dst[None, :] == src[:, None])
-        mask &= ~(self.e_src[None, :] == dst[:, None])
-        if t.server_relay:
-            # flows may pass through other servers (BCube/DCell/PON5), but a
-            # transit server must be enterable+exitable; nothing more to mask.
-            pass
-        else:
-            # eq. (46): servers never forward other servers' traffic (PON3)
-            mask &= ~(u_is_server[None, :] & (self.e_src[None, :] != src[:, None]))
-            mask &= ~(v_is_server[None, :] & (self.e_dst[None, :] != dst[:, None]))
-        if self.path_slack is not None:
-            dist = _hop_distances(t)
-            # edge (u, v) stays admissible for flow f iff it lies on some
-            # src->dst walk within path_slack hops of the shortest one
-            through = (dist[src][:, self.e_src] + 1
-                       + dist[:, dst].T[:, self.e_dst])
-            mask &= through <= (dist[src, dst] + self.path_slack)[:, None]
-        self.flow_edge_mask = mask
+        with trace.span("problem.mask"):
+            self.flow_edge_mask = self._flow_edge_mask()
         # wavelength availability per edge
         self.edge_w_ok = t.cap > 0.0            # (E, W)
         if self.flow_weight is not None:
             w = np.asarray(self.flow_weight, dtype=np.float64)
+            F = self.coflow.n_flows
             assert w.shape == (F,), (w.shape, F)
             assert np.isfinite(w).all() and (w > 0).all(), \
                 "flow_weight entries must be positive and finite"
             self.flow_weight = w
+
+    def _flow_edge_mask(self) -> np.ndarray:
+        """(F, E) bool: 1 = flow f may use edge e."""
+        t = self.topo
+        F = self.coflow.n_flows
+        src, dst = self.coflow.src, self.coflow.dst
+        # never re-enter the source / leave the destination
+        mask = ~(self.e_dst[None, :] == src[:, None])
+        mask &= ~(self.e_src[None, :] == dst[:, None])
+        if not t.server_relay:
+            # eq. (46): servers never forward other servers' traffic (PON3);
+            # where they do (BCube/DCell/PON5) nothing more is masked
+            u_is_server = self.is_server[self.e_src]
+            v_is_server = self.is_server[self.e_dst]
+            mask &= ~(u_is_server[None, :] & (self.e_src[None, :] != src[:, None]))
+            mask &= ~(v_is_server[None, :] & (self.e_dst[None, :] != dst[:, None]))
+        if self.path_slack is not None:
+            # edge (u, v) stays admissible for flow f iff it lies on some
+            # src->dst walk within path_slack hops of the shortest one
+            from_src = hop_rows(t, src)                       # (F, V)
+            to_dst = hop_rows(t, dst, to=True)                # (F, V)
+            through = from_src[:, self.e_src] + 1 + to_dst[:, self.e_dst]
+            shortest = from_src[np.arange(F), dst]
+            mask &= through <= (shortest + self.path_slack)[:, None]
+        return mask
 
     # -- convenience sizes --------------------------------------------------
     @property
@@ -163,37 +168,34 @@ class Metrics:
         return base + self.fairness_term
 
 
-def _hop_distances(topo: Topology) -> np.ndarray:
-    """(V, V) directed hop-count distance matrix (BFS per vertex),
-    memoized on the topology instance — sweeps build hundreds of
-    ScheduleProblems over the same handful of graphs."""
-    cached = getattr(topo, "_hop_dist_cache", None)
-    if cached is not None:
-        return cached
-    V = topo.n_vertices
-    nbrs: list[list[int]] = [[] for _ in range(V)]
-    # dead edges (all-zero capacity, e.g. cut by core.failures) are not
-    # traversable — distances must reflect the degraded connectivity
-    alive = topo.cap.sum(axis=1) > 0.0
-    for e, (u, v) in enumerate(topo.edges):
-        if alive[e]:
-            nbrs[int(u)].append(int(v))
-    dist = np.full((V, V), np.inf)
-    for s in range(V):
-        dist[s, s] = 0.0
-        frontier = [s]
-        d = 0
-        while frontier:
-            d += 1
-            nxt = []
-            for u in frontier:
-                for v in nbrs[u]:
-                    if dist[s, v] > d:
-                        dist[s, v] = d
-                        nxt.append(v)
-            frontier = nxt
-    topo._hop_dist_cache = dist
-    return dist
+def hop_rows(topo: Topology, vertices, *, to: bool = False) -> np.ndarray:
+    """(len(vertices), V) directed hop counts from each of `vertices`
+    (with `to`, from every vertex to each of them); inf where there is
+    no path.  Dead edges (all-zero capacity, e.g. cut by core.failures)
+    are not traversable.  Rows are found by BFS (scipy.sparse.csgraph)
+    on first use and memoized per vertex on the topology instance, since
+    sweeps build hundreds of ScheduleProblems over the same graphs and a
+    problem reads only its endpoints' rows."""
+    cache = getattr(topo, "_hop_rows_cache", None)
+    if cache is None:
+        alive = topo.cap.sum(axis=1) > 0.0
+        u = topo.edges[alive, 0]
+        v = topo.edges[alive, 1]
+        shape = (topo.n_vertices,) * 2
+        ones = np.ones(len(u))
+        cache = topo._hop_rows_cache = {
+            False: (sparse.csr_matrix((ones, (u, v)), shape=shape), {}),
+            True: (sparse.csr_matrix((ones, (v, u)), shape=shape), {})}
+    graph, rows = cache[to]
+    vertices = np.asarray(vertices, dtype=np.int64).tolist()
+    missing = sorted(set(vertices).difference(rows))
+    if missing:
+        with trace.span("problem.hops"):
+            rows.update(zip(missing, csgraph.shortest_path(
+                graph, unweighted=True, indices=missing)))
+    if not vertices:
+        return np.empty((0, topo.n_vertices))
+    return np.stack([rows[x] for x in vertices])
 
 
 def suggest_n_slots(topo: Topology, coflow: CoflowSet, *, rho: float = 8.0,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 from typing import Sequence
 
 import numpy as np
@@ -185,19 +186,33 @@ def spine_leaf(n_servers: int = 16, n_leaf: int = 4, n_spine: int = 2,
                    switch_sigma=sigma)
 
 
-def bcube(n: int = 4, slot_duration: float = 1.0) -> Topology:
-    """BCube(k=1, n) (Fig. 4c): n^2 servers, 2n switches, 2n^2 links.
-    Server-centric: servers relay; NIC power model applies."""
-    b = _Builder(f"bcube-n{n}")
-    servers = [[b.add(f"srv{g}.{i}", KIND_SERVER, P_NIC, EPS_NIC)
-                for i in range(n)] for g in range(n)]
-    lvl0 = [b.add(f"sw0.{g}", KIND_SWITCH, O_SG500) for g in range(n)]
-    lvl1 = [b.add(f"sw1.{i}", KIND_SWITCH, O_SG500) for i in range(n)]
-    for g in range(n):
-        for i in range(n):
-            b.link(servers[g][i], lvl0[g], _grey())
-            b.link(servers[g][i], lvl1[i], _grey())
-    sigma = {s: n * LINK_GBPS for s in lvl0 + lvl1}
+def bcube(n: int = 4, k: int = 1, slot_duration: float = 1.0) -> Topology:
+    """BCube_k(n) (Guo et al., SIGCOMM 2009, §3.1; Fig. 4c is k=1).
+
+    n^(k+1) servers with k+1 ports each, k+1 levels of n^k n-port
+    switches, (k+1) n^(k+1) links; k=1, n=4 gives 16 servers, 8
+    switches, 32 links.  A server's address is its digits a_k .. a_0
+    (a_k most significant) and the level-l switch joins the servers
+    whose addresses differ only in digit l.  Servers are numbered in
+    address order, then the switches level by level, each level's in
+    the order of the remaining digits; every server's links go up level
+    by level.  Server-centric: servers relay, and each carries
+    ceil((k+1)/2) two-port NICs (NIC power model, eq. 20)."""
+    if k < 0 or n < 2:
+        raise ValueError(f"BCube needs k >= 0 and n >= 2, got k={k}, n={n}")
+    b = _Builder(f"bcube-n{n}" if k == 1 else f"bcube-k{k}-n{n}")
+    addrs = list(itertools.product(range(n), repeat=k + 1))
+    nic_w = math.ceil((k + 1) / 2) * P_NIC
+    servers = [b.add("srv" + ".".join(map(str, a)), KIND_SERVER, nic_w,
+                     EPS_NIC) for a in addrs]
+    rest = list(itertools.product(range(n), repeat=k))
+    levels = [{r: b.add(f"sw{l}" + "".join(f".{d}" for d in r),
+                        KIND_SWITCH, O_SG500) for r in rest}
+              for l in range(k + 1)]
+    for s, a in zip(servers, addrs):
+        for l in range(k + 1):
+            b.link(s, levels[l][a[:k - l] + a[k - l + 1:]], _grey())
+    sigma = {s: n * LINK_GBPS for sw in levels for s in sw.values()}
     return b.build(n_wavelengths=1, slot_duration=slot_duration,
                    switch_sigma=sigma)
 

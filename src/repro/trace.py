@@ -19,8 +19,10 @@ groups the spans of one call into the program, such as one
 shares one batch id, and a batched call inside another keeps the outer
 id.
 
-Span names are dotted by layer: `lp.build`; `pdhg.stack`, `pdhg.run`,
-`pdhg.unstack`; `pack.decompose`, `pack.slots`, `pack.evaluate`.
+Span names are dotted by layer: `problem.mask` (a ScheduleProblem's
+flow-edge mask) with `problem.hops` inside it (hop-count rows found on
+a cache miss); `lp.build`; `pdhg.stack`, `pdhg.run`, `pdhg.unstack`;
+`pack.decompose`, `pack.slots`, `pack.evaluate`.
 Counters stay with the code they count (`solver.dispatch_stats()`).
 """
 from __future__ import annotations
