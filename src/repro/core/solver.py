@@ -1276,6 +1276,60 @@ def _route_search(p: ScheduleProblem, out_edges, src: int, dst: int,
     return None
 
 
+@dataclasses.dataclass
+class DecomposeStats:
+    """Counters for path_decompose: `paths` peeled (fallback routes
+    included) and DFS `states` expanded, summed over every search; their
+    ratio is the backtracking a peel does.  Read via `decompose_stats()`;
+    `python -m repro.sweep --profile` prints per-cell deltas."""
+
+    paths: int = 0
+    states: int = 0
+
+    def snapshot(self) -> "DecomposeStats":
+        return dataclasses.replace(self)
+
+
+DECOMPOSE_STATS = DecomposeStats()
+
+
+def decompose_stats() -> DecomposeStats:
+    """The live path-decomposition counters (see DecomposeStats)."""
+    return DECOMPOSE_STATS
+
+
+def _peel_search(adj: dict, live: list, src: int, dst: int,
+                 convert_ok: list) -> list[int] | None:
+    """`_route_search` over one flow's support: `adj[u]` lists the flow's
+    (local triple, w, head) entries leaving u in `_out_edges(p)[u]` order,
+    then w ascending, and `live[j]` gates a hop — the same stack DFS,
+    push order and `seen` test, so the same route.  A trail is a chain of
+    (j, parent trail) pairs, shared by its extensions, not a copied list.
+    Returns the route's local triples ([] when src == dst) or None if dst
+    is unreachable."""
+    stack = [(src, -1, None)]
+    seen = set()
+    route = None
+    while stack:
+        u, w_in, trail = stack.pop()
+        if u == dst:
+            route = []
+            while trail is not None:
+                j, trail = trail
+                route.append(j)
+            route.reverse()
+            break
+        if (u, w_in) in seen:
+            continue
+        seen.add((u, w_in))
+        convert = w_in == -1 or convert_ok[u]
+        for j, w, v in adj.get(u, ()):
+            if live[j] and (convert or w == w_in):
+                stack.append((v, w, (j, trail)))
+    DECOMPOSE_STATS.states += len(seen)
+    return route
+
+
 @trace.spanned("pack.decompose")
 def path_decompose(p: ScheduleProblem, idx: RoutingIndex,
                    vol: np.ndarray) -> list[FlowPath]:
@@ -1285,46 +1339,48 @@ def path_decompose(p: ScheduleProblem, idx: RoutingIndex,
     a path decomposition conserves *exactly* (wavelength-continuous at
     passive vertices, free conversion at electronic ones), drops cyclic
     residue, and — crucially for PON3 — tags each path with the wavelength
-    its source transmits on, so eq. 47 can be enforced per path."""
+    its source transmits on, so eq. 47 can be enforced per path.
+
+    Each flow's paths are peeled over its own support: an adjacency of its
+    triples built once, and its remaining volume as a list indexed by
+    local triple, searched by `_peel_search` in `_route_search`'s order."""
     F, E, W, _ = p.shape_x
-    passive = ~(p.is_server | p.is_switch)
     kf, ke, kw = idx.kf, idx.ke, idx.kw
-    out_edges = _out_edges(p)
-    convert_ok = ~passive
+    convert_ok = (p.is_server | p.is_switch).tolist()
     # per-flow triple ranges: kf is sorted by construction (lexicographic
-    # (f, e, w) order), so each flow owns one contiguous slice
+    # (f, e, w) order), so each flow owns one contiguous slice, and a
+    # vertex's triples within it come in `_out_edges` order, w ascending
     bounds = np.searchsorted(kf, np.arange(F + 1))
-    # dense per-flow scratch, touched cells reset between flows:
-    # k_map[e, w] = global triple index (-1 = inadmissible for this
-    # flow), g[e, w] = remaining decomposable volume — precomputed index
-    # arrays instead of the historical (f, e, w)-keyed dicts
-    k_map = np.full((E, W), -1, dtype=np.int64)
-    g = np.zeros((E, W))
+    tail, head, wl = p.e_src[ke].tolist(), p.e_dst[ke].tolist(), kw.tolist()
+    vol_live = np.where(vol > 1e-9, vol, 0.0).tolist()
 
     paths: list[FlowPath] = []
     for f in range(F):
-        lo, hi = bounds[f], bounds[f + 1]
-        es, ws = ke[lo:hi], kw[lo:hi]
-        k_map[es, ws] = np.arange(lo, hi)
-        vf = vol[lo:hi]
-        g[es, ws] = np.where(vf > 1e-9, vf, 0.0)
+        lo, hi = int(bounds[f]), int(bounds[f + 1])
+        adj: dict[int, list] = {}
+        for j, k in enumerate(range(lo, hi)):
+            adj.setdefault(tail[k], []).append((j, wl[k], head[k]))
+        g = vol_live[lo:hi]
+        live = [x > 1e-9 for x in g]
+        n_live = sum(live)
         src, dst = int(p.coflow.src[f]), int(p.coflow.dst[f])
         budget = float(p.coflow.size[f])
         n_before = len(paths)
         guard = 4 * E * W + 16
-        while (budget > 1e-9 and guard > 0
-               and g[es, ws].max(initial=0.0) > 1e-9):
+        while budget > 1e-9 and guard > 0 and n_live:
             guard -= 1
-            path = _route_search(p, out_edges, src, dst,
-                                 lambda e, w: g[e, w] > 1e-9, convert_ok)
-            if not path:   # no route, or degenerate src == dst (empty trail)
+            route = _peel_search(adj, live, src, dst, convert_ok)
+            if not route:  # no route, or degenerate src == dst
                 break
-            pe = np.array([e for e, _ in path], dtype=np.int64)
-            pw = np.array([w for _, w in path], dtype=np.int64)
-            amt = min(budget, float(g[pe, pw].min()))
-            np.subtract.at(g, (pe, pw), amt)
+            amt = min(budget, min(g[j] for j in route))
+            for j in route:
+                g[j] -= amt
+                if g[j] <= 1e-9:
+                    live[j] = False
+                    n_live -= 1
             budget -= amt
-            paths.append(FlowPath(f, k_map[pe, pw], amt, int(pw[0])))
+            paths.append(FlowPath(f, np.array(route, np.int64) + lo, amt,
+                                  wl[lo + route[0]]))
         if len(paths) > n_before and budget > 1e-9:
             # the LP iterate routed less than the demand (loose tolerance
             # or dropped cyclic residue): rescale this flow's paths so the
@@ -1338,14 +1394,12 @@ def path_decompose(p: ScheduleProblem, idx: RoutingIndex,
             # no LP volume survived the 1e-9 gate (tiny flows under a loose
             # LP tolerance) — ship the whole demand on any admissible route
             # so temporal_pack never silently drops a flow
-            path = _route_search(p, out_edges, src, dst,
-                                 lambda e, w: k_map[e, w] >= 0, convert_ok)
-            if path:       # empty trail (src == dst) has no tx wavelength
-                pe = np.array([e for e, _ in path], dtype=np.int64)
-                pw = np.array([w for _, w in path], dtype=np.int64)
-                paths.append(FlowPath(f, k_map[pe, pw], budget, int(pw[0])))
-        k_map[es, ws] = -1        # reset scratch for the next flow
-        g[es, ws] = 0.0
+            route = _peel_search(adj, [True] * (hi - lo), src, dst,
+                                 convert_ok)
+            if route:      # empty route (src == dst) has no tx wavelength
+                paths.append(FlowPath(f, np.array(route, np.int64) + lo,
+                                      budget, wl[lo + route[0]]))
+    DECOMPOSE_STATS.paths += len(paths)
     return paths
 
 

@@ -224,11 +224,12 @@ class SweepRecord:
 
 def _profile_mark() -> tuple:
     """Where a cell starts, for its --profile line: the number of spans
-    recorded so far, the build-cache and the dispatch counters."""
+    recorded so far, the build-cache, dispatch and decompose counters."""
     rec = trace.active()
     return (len(rec.records) if rec else 0,
             solver.build_cache_stats().snapshot(),
-            solver.dispatch_stats().snapshot())
+            solver.dispatch_stats().snapshot(),
+            solver.decompose_stats().snapshot())
 
 
 def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
@@ -236,14 +237,16 @@ def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
     since `mark` (_profile_mark): LP build (`lp.build`), PDHG
     (`pdhg.*`: stack, run, unstack), pack (`pack.*`: decompose, slots,
     evaluate) and the rest of the cell's wall time, with the build
-    caches' hits and how many PDHG dispatches applied K as tiles."""
-    start, snap, disp = mark
+    caches' hits, how many PDHG dispatches applied K as tiles, and the
+    paths the decomposition peeled and the DFS states it expanded."""
+    start, snap, disp, dec = mark
     rec = trace.active()
 
     def ms(prefix: str) -> float:
         return rec.total(prefix, start) * 1e3
 
     d, t = solver.build_cache_stats(), solver.dispatch_stats()
+    c = solver.decompose_stats()
     build, pdhg, pack = ms("lp.build"), ms("pdhg."), ms("pack.")
     say(f"    profile {label}: build {build:7.1f} ms "
         f"(structure {d.structure_hits - snap.structure_hits} hit"
@@ -257,6 +260,7 @@ def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
         f"tiled {t.tiled_dispatches - disp.tiled_dispatches}"
         f"/{t.dispatches - disp.dispatches} dispatches) | "
         f"pack {pack:8.1f} ms (decompose {ms('pack.decompose'):.1f}, "
+        f"paths {c.paths - dec.paths}, states {c.states - dec.states}, "
         f"slots {ms('pack.slots'):.1f}, "
         f"evaluate {ms('pack.evaluate'):.1f}) | "
         f"other {wall_s * 1e3 - build - pdhg - pack:8.1f} ms | "

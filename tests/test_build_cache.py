@@ -330,8 +330,11 @@ def test_sweep_profile_prints_build_solve_split():
     assert set(ms) == {"build", "pdhg", "pack", "other", "total"}
     parts = dict(re.findall(r"(\w+) ([\d.]+)[,)]", cell[0]))
     assert set(parts) >= {"stack", "run", "unstack", "decompose", "slots",
-                          "evaluate"}
+                          "evaluate", "paths", "states"}
     assert ms["build"] > 0 and ms["pdhg"] > 0 and ms["pack"] > 0
+    # the decomposition peels at least one path a flow (12 a problem)
+    assert int(parts["paths"]) >= 24
+    assert int(parts["states"]) >= int(parts["paths"])
     assert float(parts["run"]) > 0 and float(parts["slots"]) > 0
     assert ms["build"] + ms["pdhg"] + ms["pack"] <= ms["total"] + 0.1
     assert "structure" in cell[0]
