@@ -1,4 +1,4 @@
-"""Benchmark: warm vs cold rolling-horizon epoch re-solves, per backend.
+"""Benchmark: warm vs cold rolling-horizon epoch re-solves.
 
 The online arrival engine (core.arrivals.run_online) re-plans at every
 epoch boundary; the question this benchmark answers is what the
@@ -31,8 +31,8 @@ optimal face.
 Run:  PYTHONPATH=src python benchmarks/arrival_bench.py [--seeds 3]
 Prints ``name,ms,derived`` CSV rows like the other benchmarks and
 merges machine-readable records into BENCH_solver.json at the repo
-root (schema: benchmarks/bench_json.py).  The gate passes if the first
-backend's aggregate iteration OR wall speedup reaches --min-speedup.
+root (schema: benchmarks/bench_json.py).  The gate passes if the
+aggregate iteration OR wall speedup reaches --min-speedup.
 """
 from __future__ import annotations
 
@@ -46,7 +46,7 @@ try:
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
 from repro import compile_cache
-from repro.core import arrivals, solver, topology, traffic
+from repro.core import arrivals, topology, traffic
 
 
 def build_traces(topo_name: str, n_seeds: int, family: str, n_coflows: int,
@@ -61,11 +61,10 @@ def build_traces(topo_name: str, n_seeds: int, family: str, n_coflows: int,
 
 
 def run_traces(topo, traces, objective: str, *, warm: bool, epoch_s: float,
-               iters: int, tol: float, backend: str):
+               iters: int, tol: float):
     t0 = time.perf_counter()
     outs = [arrivals.run_online(topo, tr, objective, warm=warm,
-                                epoch_s=epoch_s, iters=iters, tol=tol,
-                                backend=backend)
+                                epoch_s=epoch_s, iters=iters, tol=tol)
             for tr in traces]
     wall = time.perf_counter() - t0
     for r in outs:
@@ -74,13 +73,11 @@ def run_traces(topo, traces, objective: str, *, warm: bool, epoch_s: float,
     return outs, wall
 
 
-def bench_cell(topo_name: str, objective: str, args, backend: str,
-               records: list[dict]):
+def bench_cell(topo_name: str, objective: str, args, records: list[dict]):
     topo, traces = build_traces(
         topo_name, args.seeds, args.family, args.coflows, args.mean_s,
         args.n_map, args.n_reduce, args.total_gbits)
-    kw = dict(epoch_s=args.epoch_s, iters=args.iters, tol=args.tol,
-              backend=backend)
+    kw = dict(epoch_s=args.epoch_s, iters=args.iters, tol=args.tol)
 
     # untimed passes populate the XLA compile cache for BOTH modes (their
     # epoch problems diverge in shape once schedules differ)
@@ -93,7 +90,7 @@ def bench_cell(topo_name: str, objective: str, args, backend: str,
     it_cold = float(sum(r.total_iterations for r in cold))
     it_warm = float(sum(r.total_iterations for r in warm))
     ep = int(sum(r.n_epochs for r in warm))
-    cell = f"{topo_name}/min-{objective}/{backend}"
+    cell = f"{topo_name}/min-{objective}"
     print(f"arrival/{cell}/cold,{t_cold*1e3:.1f},"
           f"{ep} epochs over {len(traces)} traces "
           f"({it_cold:.0f} total iters)")
@@ -103,11 +100,11 @@ def bench_cell(topo_name: str, objective: str, args, backend: str,
     records += [
         bench_json.record(
             f"arrival/{cell}/cold", topology=topo_name, objective=objective,
-            backend=backend, wall_ms=t_cold * 1e3, iterations=it_cold,
+            wall_ms=t_cold * 1e3, iterations=it_cold,
             derived=f"{ep} epochs over {len(traces)} traces"),
         bench_json.record(
             f"arrival/{cell}/warm", topology=topo_name, objective=objective,
-            backend=backend, wall_ms=t_warm * 1e3, iterations=it_warm,
+            wall_ms=t_warm * 1e3, iterations=it_warm,
             derived=f"{it_cold/max(it_warm, 1.0):.2f}x iteration / "
                     f"{t_cold/t_warm:.2f}x wall speedup vs cold"),
     ]
@@ -122,10 +119,6 @@ def main(argv=None) -> int:
     ap.add_argument("--tol", type=float, default=2e-3)
     ap.add_argument("--topos", default="spine-leaf,pon3")
     ap.add_argument("--objectives", default="energy,time")
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings to compare "
-                         f"({','.join(solver.BACKENDS)}); the speedup "
-                         "gate applies to the first one")
     ap.add_argument("--family", default="poisson",
                     help=f"arrival family ({','.join(arrivals.FAMILIES)})")
     ap.add_argument("--coflows", type=int, default=5)
@@ -137,53 +130,45 @@ def main(argv=None) -> int:
                     help="per co-flow; large enough that flows span "
                          "epochs, else warm starts have nothing to carry")
     ap.add_argument("--min-speedup", type=float, default=1.2,
-                    help="gate on the first backend's aggregate warm-vs-"
-                         "cold speedup (iterations or wall, whichever "
+                    help="gate on the aggregate warm-vs-cold speedup (iterations or wall, whichever "
                          "is higher)")
     ap.add_argument("--json-out", default=str(bench_json.DEFAULT_PATH),
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
     compile_cache.enable()
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
-    agg: dict[str, tuple[float, float, float, float]] = {}
-    for backend in backends:
-        tc = tw = ic = iw = 0.0
-        for t in args.topos.split(","):
-            for obj in args.objectives.split(","):
-                (c_t, w_t), (c_i, w_i) = bench_cell(t, obj, args, backend,
-                                                    records)
-                tc, tw, ic, iw = tc + c_t, tw + w_t, ic + c_i, iw + w_i
-        agg[backend] = (tc, tw, ic, iw)
-        speed_w = tc / tw
-        speed_i = ic / max(iw, 1.0)
-        print(f"arrival/aggregate/{backend},{tw*1e3:.1f},"
-              f"{speed_i:.2f}x iters / {speed_w:.2f}x wall warm-vs-cold")
-        records.append(bench_json.record(
-            f"arrival/aggregate/{backend}", backend=backend,
-            wall_ms=tw * 1e3, iterations=iw,
-            derived=f"{speed_i:.2f}x iteration / {speed_w:.2f}x wall "
-                    f"warm-vs-cold speedup"))
+    tc = tw = ic = iw = 0.0
+    for t in args.topos.split(","):
+        for obj in args.objectives.split(","):
+            (c_t, w_t), (c_i, w_i) = bench_cell(t, obj, args, records)
+            tc, tw, ic, iw = tc + c_t, tw + w_t, ic + c_i, iw + w_i
+    speed_w = tc / tw
+    speed_i = ic / max(iw, 1.0)
+    print(f"arrival/aggregate,{tw*1e3:.1f},"
+          f"{speed_i:.2f}x iters / {speed_w:.2f}x wall warm-vs-cold")
+    records.append(bench_json.record(
+        "arrival/aggregate", wall_ms=tw * 1e3, iterations=iw,
+        derived=f"{speed_i:.2f}x iteration / {speed_w:.2f}x wall "
+                f"warm-vs-cold speedup"))
     if args.json_out:
         path = bench_json.update(
             "arrival_bench", records, path=args.json_out,
             args={"seeds": args.seeds, "iters": args.iters, "tol": args.tol,
                   "topos": args.topos, "objectives": args.objectives,
-                  "backends": args.backends, "family": args.family,
+                  "family": args.family,
                   "coflows": args.coflows, "mean_s": args.mean_s,
                   "epoch_s": args.epoch_s, "n_map": args.n_map,
                   "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits})
         print(f"arrival/json,0.0,records merged into {path}")
-    tc, tw, ic, iw = agg[backends[0]]
-    speed = max(tc / tw, ic / max(iw, 1.0))
+    speed = max(speed_w, speed_i)
     if speed < args.min_speedup:
         print(f"FAIL: aggregate warm-vs-cold speedup {speed:.2f}x "
-              f"< {args.min_speedup}x ({backends[0]})")
+              f"< {args.min_speedup}x")
         return 1
     print(f"OK: aggregate warm-vs-cold speedup {speed:.2f}x "
-          f">= {args.min_speedup}x ({backends[0]})")
+          f">= {args.min_speedup}x")
     return 0
 
 

@@ -18,7 +18,6 @@ Schema (one file, merged across benchmarks):
           "records": [
             {"name": "...",               # the printed CSV row's name
              "topology": "...", "objective": "...",
-             "backend": "xla" | "pallas" | null,
              "wall_ms": float,
              "iterations": float | null,  # mean PDHG iters/instance
              "derived": "..."}            # the printed CSV row's comment
@@ -53,65 +52,37 @@ def git_sha() -> str:
 
 
 def record(name: str, *, topology: str | None = None,
-           objective: str | None = None, backend: str | None = None,
-           wall_ms: float, iterations: float | None = None,
-           derived: str = "") -> dict:
+           objective: str | None = None, wall_ms: float,
+           iterations: float | None = None, derived: str = "") -> dict:
     """One benchmark measurement in the shared flat schema."""
     return {"name": name, "topology": topology, "objective": objective,
-            "backend": backend, "wall_ms": round(float(wall_ms), 3),
+            "wall_ms": round(float(wall_ms), 3),
             "iterations": (None if iterations is None
                            else round(float(iterations), 1)),
             "derived": derived}
 
 
-def parse_backends(ap, value: str) -> list[str]:
-    """Split a --backends CLI value, rejecting an empty list."""
-    backends = [b.strip() for b in value.split(",") if b.strip()]
-    if not backends:
-        ap.error("--backends needs at least one backend")
-    return backends
+def finish_comparison(bench: str, prefix: str, ref: float, meas: float,
+                      records: list[dict], *, total_label: str,
+                      speed_label: str, json_out: str, run_args: dict,
+                      min_speedup: float) -> int:
+    """Shared tail of the comparison benchmarks: the aggregate row, the
+    BENCH_solver.json merge, and the min-speedup gate.
 
-
-def finish_comparison(bench: str, prefix: str, backends: list[str],
-                      agg: dict, records: list[dict], *, total_label: str,
-                      speed_label: str, ratio_label: str, json_out: str,
-                      run_args: dict, min_speedup: float) -> int:
-    """Shared tail of the backend-comparison benchmarks: per-backend
-    aggregate rows, cross-backend ratio rows, the BENCH_solver.json
-    merge, and the min-speedup gate on the first backend listed.
-
-    `agg[backend] = (reference_s, measured_s)` wall-time totals;
-    speedup = reference / measured.  Returns the process exit code."""
-    for backend in backends:
-        ref, meas = agg[backend]
-        speed = ref / meas
-        print(f"{prefix}/aggregate/{backend},{meas*1e3:.1f},"
-              f"{speed:.2f}x speedup ({total_label} {ref*1e3:.1f} ms)")
-        records.append(record(
-            f"{prefix}/aggregate/{backend}", backend=backend,
-            wall_ms=meas * 1e3, derived=f"{speed:.2f}x {speed_label}"))
-    if len(backends) > 1:
-        base = agg[backends[0]][1]
-        for backend in backends[1:]:
-            ratio = agg[backend][1] / base
-            print(f"{prefix}/backend-ratio/{backend},"
-                  f"{agg[backend][1]*1e3:.1f},"
-                  f"{ratio:.2f}x {backends[0]} {ratio_label}")
-            records.append(record(
-                f"{prefix}/backend-ratio/{backend}", backend=backend,
-                wall_ms=agg[backend][1] * 1e3,
-                derived=f"{ratio:.2f}x the {backends[0]} {ratio_label}"))
+    `ref` and `meas` are wall-time totals in seconds; speedup =
+    ref / meas.  Returns the process exit code."""
+    speed = ref / meas
+    print(f"{prefix}/aggregate,{meas*1e3:.1f},"
+          f"{speed:.2f}x speedup ({total_label} {ref*1e3:.1f} ms)")
+    records.append(record(f"{prefix}/aggregate", wall_ms=meas * 1e3,
+                          derived=f"{speed:.2f}x {speed_label}"))
     if json_out:
         path = update(bench, records, path=json_out, args=run_args)
         print(f"{prefix}/json,0.0,records merged into {path}")
-    ref, meas = agg[backends[0]]
-    speed = ref / meas
     if speed < min_speedup:
-        print(f"FAIL: aggregate speedup {speed:.2f}x < {min_speedup}x "
-              f"({backends[0]})")
+        print(f"FAIL: aggregate speedup {speed:.2f}x < {min_speedup}x")
         return 1
-    print(f"OK: aggregate speedup {speed:.2f}x >= {min_speedup}x "
-          f"({backends[0]})")
+    print(f"OK: aggregate speedup {speed:.2f}x >= {min_speedup}x")
     return 0
 
 

@@ -16,8 +16,7 @@ grid, per (topology, objective):
     degradation ensembles: only `_fill_lp`'s O(nnz) value refresh runs).
 
 It also times one batched solve per cell so the report shows the
-build-vs-solve wall split the sweep actually experiences, and — on the
-pallas backend — the blocked-ELL pack cold vs. plan-cached.
+build-vs-solve wall split the sweep actually experiences.
 
 The gate applies to the aggregate legacy/warm ratio (the
 "vectorized+cached" fast path, default ``--min-speedup 3``).  Cache
@@ -68,8 +67,8 @@ def _time_builds(probs, objective: str, builder) -> float:
 
 def bench_build_cell(topo_name: str, objective: str, probs,
                      records: list[dict]):
-    """One (topology, objective) cell's three build modes — backend-
-    independent, timed and recorded exactly once per cell.  Returns
+    """One (topology, objective) cell's three build modes, timed and
+    recorded once per cell.  Returns
     (legacy_s, cold_s, warm_s)."""
     cell = f"{topo_name}/min-{objective}"
     t_legacy = _time_builds(probs, objective,
@@ -105,15 +104,14 @@ def bench_build_cell(topo_name: str, objective: str, probs,
 
 
 def bench_solve_cell(topo_name: str, objective: str, probs, iters: int,
-                     tol: float, backend: str, t_warm: float,
-                     records: list[dict]):
-    """One (topology, objective, backend) batched solve, for the
+                     tol: float, t_warm: float, records: list[dict]):
+    """One (topology, objective) batched solve, for the
     build-vs-solve wall split (`t_warm` is the cell's cached build
     time from bench_build_cell)."""
     cell = f"{topo_name}/min-{objective}"
     t0 = time.perf_counter()
     results = solver.solve_fast_batch(probs, objective, iters=iters,
-                                      tol=tol, backend=backend)
+                                      tol=tol)
     # the sweep's horizon-doubling retry ladder, so the build-vs-solve
     # split reflects what a real sweep cell pays
     for i, (p, r) in enumerate(zip(probs, results)):
@@ -123,8 +121,7 @@ def bench_solve_cell(topo_name: str, objective: str, probs, iters: int,
             p = timeslot.rehorizon(
                 p, 2 * p.n_slots,
                 path_slack=p.path_slack if tries == 0 else None)
-            r = solver.solve_fast(p, objective, iters=iters, tol=tol,
-                                  backend=backend)
+            r = solver.solve_fast(p, objective, iters=iters, tol=tol)
             tries += 1
         results[i] = r
     t_solve = time.perf_counter() - t0
@@ -132,47 +129,17 @@ def bench_solve_cell(topo_name: str, objective: str, probs, iters: int,
         assert r.metrics.feasible, (topo_name, objective)
 
     split = t_warm / max(t_warm + t_solve, 1e-12)
-    print(f"build/{cell}/solve/{backend},{t_solve*1e3:.1f},"
+    print(f"build/{cell}/solve,{t_solve*1e3:.1f},"
           f"warm build is {split:.2%} of build+solve wall")
     records.append(
-        bench_json.record(f"build/{cell}/solve/{backend}",
+        bench_json.record(f"build/{cell}/solve",
                           topology=topo_name, objective=objective,
-                          backend=backend, wall_ms=t_solve * 1e3,
+                          wall_ms=t_solve * 1e3,
                           iterations=float(np.mean(
                               [r.iterations for r in results])),
                           derived=f"warm build {split:.2%} of "
                                   f"build+solve wall"))
     return t_solve
-
-
-def bench_ell(probs, backend: str, records: list[dict]) -> None:
-    """Blocked-ELL pack cold vs plan-cached (only meaningful for the
-    pallas backend, whose dispatches re-pack the operator)."""
-    lps = [solver.build_routing_lp(p, "energy")[0] for p in probs]
-    solver.reset_build_caches()
-    t0 = time.perf_counter()
-    for lp in lps:
-        solver._ell_operator_cached(lp.row, lp.col, lp.val, lp.m, lp.n)
-    t_cold = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for lp in lps:
-        solver._ell_operator_cached(lp.row, lp.col, lp.val, lp.m, lp.n)
-    t_warm = time.perf_counter() - t0
-    stats = solver.build_cache_stats()
-    assert stats.ell_misses == len(lps) and stats.ell_hits == len(lps)
-    print(f"build/ell-pack/{backend}/cold,{t_cold*1e3:.1f},"
-          f"{len(lps)} packs (plan cache empty)")
-    print(f"build/ell-pack/{backend}/warm,{t_warm*1e3:.1f},"
-          f"{t_cold/max(t_warm, 1e-12):.1f}x vs cold (plan cached)")
-    records += [
-        bench_json.record(f"build/ell-pack/{backend}/cold", backend=backend,
-                          wall_ms=t_cold * 1e3,
-                          derived=f"{len(lps)} packs, plan cache empty"),
-        bench_json.record(f"build/ell-pack/{backend}/warm", backend=backend,
-                          wall_ms=t_warm * 1e3,
-                          derived=f"{t_cold/max(t_warm, 1e-12):.1f}x "
-                                  f"vs cold"),
-    ]
 
 
 def main(argv=None) -> int:
@@ -183,11 +150,6 @@ def main(argv=None) -> int:
     ap.add_argument("--topos", default=",".join(topology.BUILDERS),
                     help="comma list (default: the full sweep grid)")
     ap.add_argument("--objectives", default="energy,time")
-    ap.add_argument("--backends", default="xla",
-                    help="comma list of PDHG lowerings for the solve "
-                         f"split ({','.join(solver.BACKENDS)}); the "
-                         "build phases are backend-independent and "
-                         "timed once")
     ap.add_argument("--pattern", default="uniform")
     ap.add_argument("--n-map", type=int, default=10)
     ap.add_argument("--n-reduce", type=int, default=6)
@@ -199,9 +161,6 @@ def main(argv=None) -> int:
                          "('' disables)")
     args = ap.parse_args(argv)
     compile_cache.enable()
-    backends = bench_json.parse_backends(ap, args.backends)
-    for b in backends:
-        solver._check_backend(b)
 
     records: list[dict] = []
     t_legacy = t_cold = t_warm = 0.0
@@ -209,17 +168,14 @@ def main(argv=None) -> int:
         probs = build_problems(topo_name, args.pattern, args.seeds,
                                args.n_map, args.n_reduce,
                                args.total_gbits)
-        if "pallas" in backends:
-            bench_ell(probs, "pallas", records)
         for objective in args.objectives.split(","):
             tl, tc, tw = bench_build_cell(topo_name, objective, probs,
                                           records)
             t_legacy += tl
             t_cold += tc
             t_warm += tw
-            for backend in backends:
-                bench_solve_cell(topo_name, objective, probs, args.iters,
-                                 args.tol, backend, tw, records)
+            bench_solve_cell(topo_name, objective, probs, args.iters,
+                             args.tol, tw, records)
 
     speed_cold = t_legacy / max(t_cold, 1e-12)
     speed_warm = t_legacy / max(t_warm, 1e-12)
@@ -242,8 +198,7 @@ def main(argv=None) -> int:
             "build_bench", records, path=args.json_out,
             args={"seeds": args.seeds, "iters": args.iters,
                   "tol": args.tol, "topos": args.topos,
-                  "objectives": args.objectives,
-                  "backends": args.backends, "pattern": args.pattern,
+                  "objectives": args.objectives, "pattern": args.pattern,
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits})
         print(f"build/json,0.0,records merged into {path}")

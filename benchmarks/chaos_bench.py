@@ -15,13 +15,9 @@ columns record the robustness outcome —
   * stranded Gbits    — carried volume whose decomposed paths died
   * completion inflation — chaos makespan over healthy makespan
 
-``--backends xla,pallas`` repeats every cell per PDHG lowering; event
-traces are backend-independent byte-identical, so any metric drift
-between backends is solver-side.  On CPU the Pallas kernels run in
-interpret mode — treat its wall times as a correctness signal, not
-kernel throughput.  The gate (disabled by default: chaos is overhead,
-not speedup) applies to the first backend's aggregate chaos-vs-healthy
-wall ratio.
+Event traces are byte-identical per seed, so any metric drift is
+solver-side.  The gate (disabled by default: chaos is overhead, not
+speedup) applies to the aggregate chaos-vs-healthy wall ratio.
 
 Run:  PYTHONPATH=src python benchmarks/chaos_bench.py [--seeds 2]
 Prints ``name,ms,derived`` CSV rows like the other benchmarks and
@@ -40,22 +36,22 @@ try:
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
 from repro import compile_cache
-from repro.core import arrivals, solver, topology, traffic
+from repro.core import arrivals, topology, traffic
 from repro.core import chaos as chaosmod
 
 PAPER_TOPOS = "fat-tree,spine-leaf,bcube,dcell,pon3,pon5,pon-cascaded"
 
 
 def _run(topo, trace, objective: str, iters: int, tol: float,
-         backend: str, events=None):
+         events=None):
     return arrivals.run_online(
-        topo, trace, objective, iters=iters, tol=tol, backend=backend,
+        topo, trace, objective, iters=iters, tol=tol,
         chaos=list(events) if events is not None else None,
         fallback_policy="scf" if events is not None else None)
 
 
 def bench_cell(topo_name: str, objective: str, n_seeds: int, preset: str,
-               iters: int, tol: float, scale, arrival, backend: str,
+               iters: int, tol: float, scale, arrival,
                records: list[dict]):
     n_map, n_reduce, total = scale
     n_coflows, mean_s = arrival
@@ -71,17 +67,15 @@ def bench_cell(topo_name: str, objective: str, n_seeds: int, preset: str,
 
     # untimed passes populate the XLA compile cache for both ladders
     # (healthy and degraded epochs stack different shapes)
-    _run(topo, traces[0], objective, iters, tol, backend)
-    _run(topo, traces[0], objective, iters, tol, backend,
-         events=event_sets[0])
+    _run(topo, traces[0], objective, iters, tol)
+    _run(topo, traces[0], objective, iters, tol, events=event_sets[0])
 
     t0 = time.perf_counter()
-    healthy = [_run(topo, tr, objective, iters, tol, backend)
-               for tr in traces]
+    healthy = [_run(topo, tr, objective, iters, tol) for tr in traces]
     t_healthy = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    stormy = [_run(topo, tr, objective, iters, tol, backend, events=evs)
+    stormy = [_run(topo, tr, objective, iters, tol, events=evs)
               for tr, evs in zip(traces, event_sets)]
     t_chaos = time.perf_counter() - t0
 
@@ -102,7 +96,7 @@ def bench_cell(topo_name: str, objective: str, n_seeds: int, preset: str,
     infl = float(np.mean(mk_c[ok] / mk_h[ok])) if ok.any() else float("nan")
     events_n = sum(len(evs) for evs in event_sets)
 
-    cell = f"{topo_name}/min-{objective}/{backend}"
+    cell = f"{topo_name}/min-{objective}"
     print(f"chaos/{cell}/healthy,{t_healthy*1e3:.1f},"
           f"{n_seeds} traces ({n_map}x{n_reduce} tasks, {total:g} Gbit, "
           f"{n_coflows} co-flows)")
@@ -112,14 +106,14 @@ def bench_cell(topo_name: str, objective: str, n_seeds: int, preset: str,
     records += [
         bench_json.record(
             f"chaos/{cell}/healthy", topology=topo_name,
-            objective=objective, backend=backend, wall_ms=t_healthy * 1e3,
+            objective=objective, wall_ms=t_healthy * 1e3,
             iterations=float(np.mean(
                 [r.total_iterations for r in healthy])),
             derived=f"{n_seeds} traces ({n_map}x{n_reduce} tasks, "
                     f"{total:g} Gbit)"),
         bench_json.record(
             f"chaos/{cell}/{preset}", topology=topo_name,
-            objective=objective, backend=backend, wall_ms=t_chaos * 1e3,
+            objective=objective, wall_ms=t_chaos * 1e3,
             iterations=float(np.mean(
                 [r.total_iterations for r in stormy])),
             derived=f"availability={avail:.4f} recover_s={ttr:.3f} "
@@ -139,9 +133,6 @@ def main(argv=None) -> int:
                     help="comma list (default: the six paper DCNs plus "
                          "the cascaded-AWGR PON)")
     ap.add_argument("--objectives", default="energy")
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings to compare "
-                         f"({','.join(solver.BACKENDS)})")
     ap.add_argument("--chaos", default="storm",
                     help=f"chaos preset ({', '.join(chaosmod.PRESETS)})")
     ap.add_argument("--n-map", type=int, default=4)
@@ -150,7 +141,7 @@ def main(argv=None) -> int:
     ap.add_argument("--arrival-coflows", type=int, default=3)
     ap.add_argument("--arrival-mean-s", type=float, default=1.0)
     ap.add_argument("--min-speedup", type=float, default=0.0,
-                    help="gate on the first backend's aggregate "
+                    help="gate on the aggregate "
                          "chaos-vs-healthy wall ratio (0 = report only; "
                          "chaos adds work, so ratios sit below 1)")
     ap.add_argument("--json-out", default=str(bench_json.DEFAULT_PATH),
@@ -163,28 +154,22 @@ def main(argv=None) -> int:
                  f"have {sorted(chaosmod.PRESETS)}")
     scale = (args.n_map, args.n_reduce, args.total_gbits)
     arrival = (args.arrival_coflows, args.arrival_mean_s)
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
-    agg: dict[str, tuple[float, float]] = {}
-    for backend in backends:
-        sum_chaos = sum_healthy = 0.0
-        for t in args.topos.split(","):
-            for obj in args.objectives.split(","):
-                tc, th = bench_cell(t, obj, args.seeds, args.chaos,
-                                    args.iters, args.tol, scale, arrival,
-                                    backend, records)
-                sum_chaos += tc
-                sum_healthy += th
-        agg[backend] = (sum_healthy, sum_chaos)
+    sum_chaos = sum_healthy = 0.0
+    for t in args.topos.split(","):
+        for obj in args.objectives.split(","):
+            tc, th = bench_cell(t, obj, args.seeds, args.chaos, args.iters,
+                                args.tol, scale, arrival, records)
+            sum_chaos += tc
+            sum_healthy += th
     return bench_json.finish_comparison(
-        "chaos_bench", "chaos", backends, agg, records,
+        "chaos_bench", "chaos", sum_healthy, sum_chaos, records,
         total_label="healthy total", speed_label="healthy-vs-chaos ratio",
-        ratio_label="chaos time", json_out=args.json_out,
+        json_out=args.json_out,
         min_speedup=args.min_speedup,
         run_args={"seeds": args.seeds, "iters": args.iters,
                   "tol": args.tol, "topos": args.topos,
-                  "objectives": args.objectives,
-                  "backends": args.backends, "chaos": args.chaos,
+                  "objectives": args.objectives, "chaos": args.chaos,
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits,
                   "arrival_coflows": args.arrival_coflows,

@@ -1,5 +1,4 @@
-"""Benchmark: cold vs warm-started failure-ensemble re-solves, per
-solver backend.
+"""Benchmark: cold vs warm-started failure-ensemble re-solves.
 
 A failure study multiplies the sweep grid: every healthy instance
 re-solves under each degraded fabric.  This benchmark measures that
@@ -14,19 +13,11 @@ inner loop both ways:
     row-by-row — core.solver.project_warm_start), so the fused adaptive
     dispatch freezes most members within one residual-check chunk.
 
-``--backends xla,pallas`` repeats every cell per PDHG lowering (COO
-scatters vs fused blocked-ELL Pallas bursts); the warm-start projection
-and freezing logic are backend-independent, so the warm-vs-cold ratio
-measures the same effect on either hot loop.  On CPU the Pallas kernels
-run in interpret mode — treat its wall times as a correctness/plumbing
-signal, not kernel throughput.
-
 Both sides run the same block-diagonal stacked dispatches to the same
 per-instance tolerance, and every schedule is verified feasible with the
 exact paper model before timings count.  An untimed cold pass populates
 the XLA compile cache first so neither side pays compilation; the gate
-applies to the aggregate warm-vs-cold speedup over all measured cells of
-the FIRST backend listed.
+applies to the aggregate warm-vs-cold speedup over all measured cells.
 
 Run:  PYTHONPATH=src python benchmarks/failure_bench.py [--seeds 8]
 Prints ``name,ms,derived`` CSV rows like the other benchmarks and
@@ -76,39 +67,38 @@ def build_cell(topo_name: str, n_seeds: int, presets: list[str],
 
 def bench_cell(topo_name: str, objective: str, n_seeds: int,
                presets: list[str], iters: int, tol: float, scale,
-               backend: str, records: list[dict]):
+               records: list[dict]):
     n_map, n_reduce, total = scale
     healthy_probs, degraded, origin = build_cell(
         topo_name, n_seeds, presets, n_map, n_reduce, total)
 
     t0 = time.perf_counter()
     healthy = solver.solve_fast_batch(healthy_probs, objective, iters=iters,
-                                      tol=tol, backend=backend)
+                                      tol=tol)
     t_healthy = time.perf_counter() - t0
     warm_pool = [healthy[i] for i in origin]
 
     # untimed passes populate the XLA compile cache for BOTH ladders (cold
     # and warm stack different straggler shapes, hence different kernels)
-    solver.solve_fast_ensemble(degraded, objective, iters=iters, tol=tol,
-                               backend=backend)
+    solver.solve_fast_ensemble(degraded, objective, iters=iters, tol=tol)
     solver.solve_fast_ensemble(degraded, objective, warm=warm_pool,
-                               iters=iters, tol=tol, backend=backend)
+                               iters=iters, tol=tol)
 
     t0 = time.perf_counter()
     cold = solver.solve_fast_ensemble(degraded, objective, iters=iters,
-                                      tol=tol, backend=backend)
+                                      tol=tol)
     t_cold = time.perf_counter() - t0
 
     t0 = time.perf_counter()
     warm = solver.solve_fast_ensemble(degraded, objective, warm=warm_pool,
-                                      iters=iters, tol=tol, backend=backend)
+                                      iters=iters, tol=tol)
     t_warm = time.perf_counter() - t0
 
     for r in cold + warm:
         assert r.metrics.feasible, topo_name
     it_cold = float(np.mean([r.iterations for r in cold]))
     it_warm = float(np.mean([r.iterations for r in warm]))
-    cell = f"{topo_name}/min-{objective}/{backend}"
+    cell = f"{topo_name}/min-{objective}"
     print(f"failure/{cell}/healthy,{t_healthy*1e3:.1f},"
           f"{n_seeds} seeds ({n_map}x{n_reduce} tasks, {total:g} Gbit)")
     print(f"failure/{cell}/cold,{t_cold*1e3:.1f},"
@@ -118,18 +108,18 @@ def bench_cell(topo_name: str, objective: str, n_seeds: int,
     records += [
         bench_json.record(
             f"failure/{cell}/healthy", topology=topo_name,
-            objective=objective, backend=backend, wall_ms=t_healthy * 1e3,
+            objective=objective, wall_ms=t_healthy * 1e3,
             iterations=float(np.mean([r.iterations for r in healthy])),
             derived=f"{n_seeds} seeds ({n_map}x{n_reduce} tasks, "
                     f"{total:g} Gbit)"),
         bench_json.record(
             f"failure/{cell}/cold", topology=topo_name,
-            objective=objective, backend=backend, wall_ms=t_cold * 1e3,
+            objective=objective, wall_ms=t_cold * 1e3,
             iterations=it_cold,
             derived=f"{len(degraded)} degraded instances"),
         bench_json.record(
             f"failure/{cell}/warm", topology=topo_name,
-            objective=objective, backend=backend, wall_ms=t_warm * 1e3,
+            objective=objective, wall_ms=t_warm * 1e3,
             iterations=it_warm,
             derived=f"{t_cold/t_warm:.2f}x speedup vs cold"),
     ]
@@ -145,17 +135,12 @@ def main(argv=None) -> int:
                          "re-scored exactly regardless)")
     ap.add_argument("--topos", default="bcube,dcell,pon3")
     ap.add_argument("--objectives", default="energy,time")
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings to compare "
-                         f"({','.join(solver.BACKENDS)}); the speedup "
-                         "gate applies to the first one")
     ap.add_argument("--failures", default="link1,link3,switch,degrade50")
     ap.add_argument("--n-map", type=int, default=4)
     ap.add_argument("--n-reduce", type=int, default=3)
     ap.add_argument("--total-gbits", type=float, default=8.0)
     ap.add_argument("--min-speedup", type=float, default=1.15,
-                    help="gate on the first backend's aggregate "
-                         "warm-vs-cold speedup")
+                    help="gate on the aggregate warm-vs-cold speedup")
     ap.add_argument("--json-out", default=str(bench_json.DEFAULT_PATH),
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
@@ -163,26 +148,22 @@ def main(argv=None) -> int:
     compile_cache.enable()
     scale = (args.n_map, args.n_reduce, args.total_gbits)
     presets = args.failures.split(",")
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
-    agg: dict[str, tuple[float, float]] = {}
-    for backend in backends:
-        sum_cold = sum_warm = 0.0
-        for t in args.topos.split(","):
-            for obj in args.objectives.split(","):
-                tc, tw = bench_cell(t, obj, args.seeds, presets, args.iters,
-                                    args.tol, scale, backend, records)
-                sum_cold += tc
-                sum_warm += tw
-        agg[backend] = (sum_cold, sum_warm)
+    sum_cold = sum_warm = 0.0
+    for t in args.topos.split(","):
+        for obj in args.objectives.split(","):
+            tc, tw = bench_cell(t, obj, args.seeds, presets, args.iters,
+                                args.tol, scale, records)
+            sum_cold += tc
+            sum_warm += tw
     return bench_json.finish_comparison(
-        "failure_bench", "failure", backends, agg, records,
+        "failure_bench", "failure", sum_cold, sum_warm, records,
         total_label="cold total", speed_label="warm-vs-cold speedup",
-        ratio_label="warm time", json_out=args.json_out,
+        json_out=args.json_out,
         min_speedup=args.min_speedup,
         run_args={"seeds": args.seeds, "iters": args.iters, "tol": args.tol,
                   "topos": args.topos, "objectives": args.objectives,
-                  "backends": args.backends, "failures": args.failures,
+                  "failures": args.failures,
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits})
 

@@ -4,7 +4,7 @@ The placement search (repro.search) prices every candidate generation
 with ONE stacked `core.solver.solve_fast_batch` dispatch.  Placement
 changes flow endpoints, so per-candidate structure-cache hits are
 impossible — batching is the only throughput lever, and this benchmark
-quantifies it per backend:
+quantifies it:
 
   * **batch** — evaluations/sec when a whole population is scored in
     one stacked dispatch (the search's inner loop);
@@ -30,9 +30,8 @@ dispatches plus the host-side restart ladder — so the margin grows with
 Run:  PYTHONPATH=src python benchmarks/placement_bench.py [--topos ...]
 Prints ``name,ms,derived`` CSV rows and merges records into
 BENCH_solver.json (schema: benchmarks/bench_json.py).  As in
-sweep_bench, the gate applies to the aggregate over all cells of the
-FIRST backend listed (the deployment default): it passes if batched
-evaluation reaches --min-speedup x the per-candidate loop's aggregate
+sweep_bench, the gate applies to the aggregate over all cells: it
+passes if batched evaluation reaches --min-speedup x the per-candidate loop's aggregate
 throughput (--min-speedup 0 = report-only, the CI mode).
 """
 from __future__ import annotations
@@ -60,21 +59,20 @@ def _candidates(topo, pat, n: int, seed: int):
             for _ in range(n)], map_out
 
 
-def bench_cell(topo_name: str, args, backend: str, records: list[dict]
+def bench_cell(topo_name: str, args, records: list[dict]
                ) -> tuple[float, float]:
-    """One topology x backend cell; returns (loop_s, batch_s) walls."""
+    """One topology cell; returns (loop_s, batch_s) walls."""
     topo = topology.build(topo_name)
     pat = traffic.pattern("uniform", n_map=args.n_map,
                           n_reduce=args.n_reduce,
                           total_gbits=args.total_gbits)
-    cfg = search.SearchConfig(iters=args.iters, backend=backend,
-                              seed=args.seed)
+    cfg = search.SearchConfig(iters=args.iters, seed=args.seed)
     pls, map_out = _candidates(topo, pat, args.population, args.seed)
     n_slots = max(timeslot.suggest_n_slots(
         topo, traffic.generate_from_placement(topo, pat, pl,
                                               map_out=map_out))
         for pl in pls)
-    cell = f"{topo_name}/{backend}"
+    cell = topo_name
     # one candidate generation's problems, built once (untimed): the
     # build is identical work on both evaluation paths
     problems = [timeslot.ScheduleProblem(
@@ -85,12 +83,11 @@ def bench_cell(topo_name: str, args, backend: str, records: list[dict]
 
     def run_batch():
         return solver.solve_fast_batch(problems, args.objective,
-                                       iters=cfg.iters, tol=cfg.tol,
-                                       backend=backend)
+                                       iters=cfg.iters, tol=cfg.tol)
 
     def run_loop():
         return [solver.solve_fast(p, args.objective, iters=cfg.iters,
-                                  tol=cfg.tol, backend=backend)
+                                  tol=cfg.tol)
                 for p in problems]
 
     # cold, loop first (sweep_bench order): both sides include the
@@ -111,12 +108,12 @@ def bench_cell(topo_name: str, args, backend: str, records: list[dict]
           f"{eps_loop:.1f} evals/s ({ratio:.1f}x slower than batch)")
     records.append(bench_json.record(
         f"placement/{cell}/batch", topology=topo_name,
-        objective=args.objective, backend=backend, wall_ms=t_batch * 1e3,
+        objective=args.objective, wall_ms=t_batch * 1e3,
         derived=f"{eps_batch:.1f} evals/s, {n} candidates, "
                 f"{ratio:.2f}x vs loop"))
     records.append(bench_json.record(
         f"placement/{cell}/loop", topology=topo_name,
-        objective=args.objective, backend=backend, wall_ms=t_loop * 1e3,
+        objective=args.objective, wall_ms=t_loop * 1e3,
         derived=f"{eps_loop:.1f} evals/s (per-candidate solve_fast)"))
 
     # search quality: a small SA run must beat fresh random placements
@@ -132,7 +129,7 @@ def bench_cell(topo_name: str, args, backend: str, records: list[dict]
           f"gain={res.gain:.3f}x vs best fixed, cert=ok")
     records.append(bench_json.record(
         f"placement/{cell}/search", topology=topo_name,
-        objective=args.objective, backend=backend, wall_ms=0.0,
+        objective=args.objective, wall_ms=0.0,
         derived=f"win={win_pct:.0%} vs {len(scores)} random, "
                 f"gain={res.gain:.3f}x, cert=ok"))
     return t_loop, t_batch
@@ -152,33 +149,26 @@ def main(argv=None) -> int:
                     help="candidates per evaluation batch")
     ap.add_argument("--generations", type=int, default=4,
                     help="SA generations for the quality row")
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings "
-                         f"({','.join(solver.BACKENDS)})")
     ap.add_argument("--min-speedup", type=float, default=2.0,
                     help="batched evaluation must reach this multiple of "
                          "the per-candidate loop's aggregate throughput "
-                         "on the first backend (0 = report-only)")
+                         "(0 = report-only)")
     ap.add_argument("--json-out", default=str(bench_json.DEFAULT_PATH),
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
     compile_cache.enable()
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
     agg_loop = agg_batch = 0.0
-    for backend in backends:
-        for t in args.topos.split(","):
-            t_loop, t_batch = bench_cell(t, args, backend, records)
-            if backend == backends[0]:
-                agg_loop += t_loop
-                agg_batch += t_batch
+    for t in args.topos.split(","):
+        t_loop, t_batch = bench_cell(t, args, records)
+        agg_loop += t_loop
+        agg_batch += t_batch
     agg = agg_loop / agg_batch
-    print(f"placement/aggregate/{backends[0]},{agg_batch*1e3:.1f},"
+    print(f"placement/aggregate,{agg_batch*1e3:.1f},"
           f"{agg:.2f}x speedup vs per-candidate loop")
     records.append(bench_json.record(
-        f"placement/aggregate/{backends[0]}", topology="all",
-        objective=args.objective, backend=backends[0],
+        "placement/aggregate", topology="all", objective=args.objective,
         wall_ms=agg_batch * 1e3,
         derived=f"{agg:.2f}x speedup vs per-candidate loop"))
     if args.json_out:
@@ -189,19 +179,17 @@ def main(argv=None) -> int:
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits,
                   "population": args.population,
-                  "generations": args.generations,
-                  "backends": args.backends})
+                  "generations": args.generations})
         print(f"placement/json,0.0,records merged into {path}")
     if args.min_speedup <= 0:       # report-only (CI): no gating
         print("OK: report-only (--min-speedup 0)")
         return 0
     if agg < args.min_speedup:
         print(f"FAIL: batched evaluation only {agg:.2f}x the "
-              f"per-candidate loop on {backends[0]} "
-              f"(< {args.min_speedup}x)")
+              f"per-candidate loop (< {args.min_speedup}x)")
         return 1
     print(f"OK: batched evaluation {agg:.2f}x the per-candidate loop "
-          f"aggregate on {backends[0]} (gate {args.min_speedup}x)")
+          f"aggregate (gate {args.min_speedup}x)")
     return 0
 
 

@@ -48,31 +48,31 @@ def build_problem(topo_name: str, args) -> ScheduleProblem:
                            path_slack=2)
 
 
-def bench_cell(topo_name: str, args, backend: str, records: list[dict]
+def bench_cell(topo_name: str, args, records: list[dict]
                ) -> dict[str, float]:
-    """One topology x backend cell; returns {policy: lp_wall/pol_wall}."""
+    """One topology cell; returns {policy: lp_wall/pol_wall}."""
     p = build_problem(topo_name, args)
     obj = args.objective
-    cell = f"{topo_name}/{backend}"
+    cell = topo_name
 
-    solver.solve_fast(p, obj, iters=args.iters, backend=backend)  # compile
+    solver.solve_fast(p, obj, iters=args.iters)  # compile
     t0 = time.perf_counter()
-    lp = solver.solve_fast(p, obj, iters=args.iters, backend=backend)
+    lp = solver.solve_fast(p, obj, iters=args.iters)
     t_lp = time.perf_counter() - t0
     verify.check_schedule(p, lp.schedule).assert_ok(f"lp {cell}")
     print(f"policy/{cell}/lp,{t_lp*1e3:.1f},"
           f"gap=1.00x ({lp.iterations} iters)")
     records.append(bench_json.record(
         f"policy/{cell}/lp", topology=topo_name, objective=obj,
-        backend=backend, wall_ms=t_lp * 1e3, iterations=lp.iterations,
+        wall_ms=t_lp * 1e3, iterations=lp.iterations,
         derived="gap=1.00x (the LP reference)"))
 
     speedups: dict[str, float] = {}
     for name, pol in policies.POLICIES.items():
         pp = build_problem(topo_name, args)
-        pol.solve(pp, obj, backend=backend)        # warm path-set caches
+        pol.solve(pp, obj)        # warm path-set caches
         t0 = time.perf_counter()
-        r = pol.solve(pp, obj, backend=backend)
+        r = pol.solve(pp, obj)
         t_pol = time.perf_counter() - t0
         r.certificate.assert_ok(f"{name} {cell}")
         assert r.remaining_gbits <= 1e-6, (name, r.remaining_gbits)
@@ -82,7 +82,7 @@ def bench_cell(topo_name: str, args, backend: str, records: list[dict]
               f"gap={gap:.2f}x ({speedups[name]:.0f}x faster than LP)")
         records.append(bench_json.record(
             f"policy/{cell}/{name}", topology=topo_name, objective=obj,
-            backend=backend, wall_ms=t_pol * 1e3,
+            wall_ms=t_pol * 1e3,
             derived=f"gap={gap:.2f}x vs LP, "
                     f"{speedups[name]:.0f}x faster"))
         if gap < 1.0 - 1e-4:
@@ -101,9 +101,6 @@ def main(argv=None) -> int:
     ap.add_argument("--n-map", type=int, default=10)
     ap.add_argument("--n-reduce", type=int, default=6)
     ap.add_argument("--total-gbits", type=float, default=30.0)
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings "
-                         f"({','.join(solver.BACKENDS)})")
     ap.add_argument("--min-speedup", type=float, default=10.0,
                     help="at least one policy must beat the LP's wall "
                          "time by this factor (0 = report-only)")
@@ -112,21 +109,18 @@ def main(argv=None) -> int:
                          "('' disables)")
     args = ap.parse_args(argv)
     compile_cache.enable()
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
     best = 0.0
-    for backend in backends:
-        for t in args.topos.split(","):
-            speedups = bench_cell(t, args, backend, records)
-            best = max(best, max(speedups.values()))
+    for t in args.topos.split(","):
+        speedups = bench_cell(t, args, records)
+        best = max(best, max(speedups.values()))
     if args.json_out:
         path = bench_json.update(
             "policy_bench", records, path=args.json_out,
             args={"topos": args.topos, "objective": args.objective,
                   "iters": args.iters, "seed": args.seed,
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
-                  "total_gbits": args.total_gbits,
-                  "backends": args.backends})
+                  "total_gbits": args.total_gbits})
         print(f"policy/json,0.0,records merged into {path}")
     if args.min_speedup <= 0:       # report-only (CI): no gating
         print("OK: report-only (--min-speedup 0)")

@@ -1,25 +1,16 @@
-"""Benchmark: solver scaling over large-topology instances, per
-backend x shard count x precision.
+"""Benchmark: solver scaling over large-topology instances, per shard
+count.
 
 Each configured size builds one routing-LP instance on a parameterized
 large topology (fat-tree k in {8,16}, multi-level DCell, multi-cell
 PON — core.topology's generator families), solves it end-to-end through
 the fast path (LP -> PDHG -> slot packing -> exact re-scoring), and
 certifies the packed schedule with core.verify.check_schedule before
-any timing counts.  Per size the grid crosses:
-
-  * backend   — "xla" (COO scatters) vs "pallas" (fused blocked-ELL
-                bursts, repro.kernels.pdhg_spmv);
-  * shards    — row-block partition of the PDHG operator across N
-                devices (pallas only; runtime.sharding.solver_mesh).
-                On CPU the devices come from
-                XLA_FLAGS=--xla_force_host_platform_device_count, which
-                this script sets itself BEFORE importing jax;
-  * precision — fp32 vs bf16 iterate storage (pallas only; arithmetic
-                and residuals stay fp32 — docs/SOLVER.md §9).
-
-Combinations the solver rejects (xla with shards>1 or bf16) are
-skipped, not failed.  The flagship `fat-tree-k16` size is a k=16
+any timing counts.  Per size the rows cross the shard count: a row-block
+partition of the PDHG operator across N devices
+(runtime.sharding.solver_mesh; N = 1 is the single-device solve).  On
+CPU the devices come from XLA_FLAGS=--xla_force_host_platform_device_count,
+which this script sets itself BEFORE importing jax.  The flagship `fat-tree-k16` size is a k=16
 fat-tree (1024 servers, 1344 vertices) whose routing LP exceeds 1e5
 nonzeros — the scale gate `--min-nnz` asserts it.
 
@@ -28,14 +19,8 @@ iterations, and the process peak RSS after the run (resource.getrusage
 ru_maxrss — cumulative high-water mark, so sizes should be read
 smallest-first within one invocation).
 
-On CPU the Pallas kernels run in interpret mode: treat cross-backend
-wall-time ratios as plumbing signal, not kernel throughput, and
-sharded runs as correctness/overhead measurements (host "devices"
-share the same silicon).  bf16 rows additionally include restart-ladder
-overshoot whenever --tol sits below bf16's representable residual floor
-(~4e-3 of the demand scale): the LP never reports converged, every
-restart rung runs, and the packed schedule still certifies — the row
-measures that worst case, not steady-state throughput.
+On CPU, treat sharded runs as correctness/overhead measurements (host
+"devices" share the same silicon).
 
 Run:  PYTHONPATH=src python benchmarks/scale_bench.py \
           [--sizes spine-leaf,fat-tree-k8] [--shards 1,4]
@@ -69,8 +54,8 @@ def peak_rss_mb() -> float:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
 
 
-def bench_size(size: str, backend: str, shards: int, precision: str,
-               iters: int, tol: float, records: list[dict],
+def bench_size(size: str, shards: int, iters: int, tol: float,
+               records: list[dict],
                min_nnz: dict[str, int]) -> None:
     from repro.core import solver, timeslot, topology, traffic, verify
 
@@ -93,20 +78,17 @@ def bench_size(size: str, backend: str, shards: int, precision: str,
                           f"expected >= {floor}")
 
     t0 = time.perf_counter()
-    r = solver.solve_fast(p, "energy", iters=iters, tol=tol,
-                          backend=backend, shards=shards,
-                          precision=precision)
+    r = solver.solve_fast(p, "energy", iters=iters, tol=tol, shards=shards)
     cert = verify.check_schedule(p, r.schedule)
     wall = time.perf_counter() - t0
-    assert cert.ok, (size, backend, shards, precision, cert)
+    assert cert.ok, (size, shards, cert)
 
-    name = f"scale/{size}/{backend}/s{shards}/{precision}"
+    name = f"scale/{size}/s{shards}"
     derived = (f"V={topo.n_vertices} E={topo.n_edges} nnz={nnz} "
                f"cert=ok peak={peak_rss_mb():.0f}MB")
     print(f"{name},{wall * 1e3:.1f},{derived}")
     records.append(bench_json.record(
-        name, topology=topo.name, objective="energy", backend=backend,
-        wall_ms=wall * 1e3, iterations=float(r.iterations),
+        name, topology=topo.name, objective="energy", wall_ms=wall * 1e3, iterations=float(r.iterations),
         derived=derived))
 
 
@@ -115,12 +97,10 @@ def main(argv=None) -> int:
     ap.add_argument("--sizes", default="spine-leaf,fat-tree-k8",
                     help=f"comma list from {','.join(SIZES)} "
                          "(read peak-RSS smallest-first)")
-    ap.add_argument("--backends", default="xla,pallas")
     ap.add_argument("--shards", default="1",
-                    help="comma list of device counts for the sharded "
-                         "pallas rows (e.g. 1,4); counts > 1 force host "
-                         "devices via XLA_FLAGS before jax loads")
-    ap.add_argument("--precisions", default="fp32,bf16")
+                    help="comma list of device counts (e.g. 1,4); counts "
+                         "> 1 force host devices via XLA_FLAGS before jax "
+                         "loads")
     ap.add_argument("--iters", type=int, default=1500)
     ap.add_argument("--tol", type=float, default=2e-3,
                     help="LP tolerance (schedules are re-scored and "
@@ -153,9 +133,6 @@ def main(argv=None) -> int:
         from benchmarks import bench_json
 
     sizes = [s.strip() for s in args.sizes.split(",") if s.strip()]
-    backends = bench_json.parse_backends(ap, args.backends)
-    precisions = [p.strip() for p in args.precisions.split(",")
-                  if p.strip()]
     min_nnz = {"fat-tree-k16": args.min_nnz} if args.min_nnz else {}
     for s in sizes:
         if s not in SIZES:
@@ -163,14 +140,9 @@ def main(argv=None) -> int:
 
     records: list[dict] = []
     for size in sizes:
-        for backend in backends:
-            for shards in shard_counts:
-                for precision in precisions:
-                    if backend != "pallas" and (shards > 1
-                                                or precision != "fp32"):
-                        continue       # the solver rejects these; skip
-                    bench_size(size, backend, shards, precision,
-                               args.iters, args.tol, records, min_nnz)
+        for shards in shard_counts:
+            bench_size(size, shards, args.iters, args.tol, records,
+                       min_nnz)
 
     if args.json_out != "none":
         path = args.json_out or bench_json.DEFAULT_PATH
@@ -180,8 +152,7 @@ def main(argv=None) -> int:
         records = _merge_previous(path, records)
         path = bench_json.update(
             "scale_bench", records, path=path,
-            args={"sizes": args.sizes, "backends": args.backends,
-                  "shards": args.shards, "precisions": args.precisions,
+            args={"sizes": args.sizes, "shards": args.shards,
                   "iters": args.iters, "tol": args.tol})
         print(f"scale/json,0.0,records merged into {path}")
     return 0

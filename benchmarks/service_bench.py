@@ -26,9 +26,8 @@ requests / end-to-end wall time.
 Run:  PYTHONPATH=src python benchmarks/service_bench.py [--tenants 4]
 Prints ``name,ms,derived`` CSV rows and merges records into
 BENCH_solver.json (schema: benchmarks/bench_json.py).  The gate passes
-if no backend regresses (ratio >= 1.0, p99 within --p99-budget-s) and
-at least one backend's aggregate coalesced-vs-serial throughput ratio
-reaches --min-speedup.
+if the aggregate coalesced-vs-serial throughput ratio reaches
+--min-speedup with the coalesced p99 within --p99-budget-s.
 """
 from __future__ import annotations
 
@@ -40,7 +39,7 @@ try:
 except ImportError:                        # module: python -m benchmarks....
     from benchmarks import bench_json
 from repro import compile_cache, service
-from repro.core import arrivals, solver, topology, traffic
+from repro.core import arrivals, topology, traffic
 
 
 def build_tenants(topo_name: str, args) -> list[service.TenantSpec]:
@@ -56,9 +55,9 @@ def build_tenants(topo_name: str, args) -> list[service.TenantSpec]:
             for k in range(args.tenants)]
 
 
-def run_mode(tenants, args, backend: str, *, coalesce: bool):
+def run_mode(tenants, args, *, coalesce: bool):
     cfg = service.ServiceConfig(
-        iters=args.iters, tol=args.tol, backend=backend,
+        iters=args.iters, tol=args.tol,
         coalesce=coalesce, overlap_build=coalesce,
         slo_p99_s=args.p99_budget_s,
         cost=service.SolveCostModel(mode="measured"))
@@ -69,22 +68,22 @@ def run_mode(tenants, args, backend: str, *, coalesce: bool):
     return res, wall
 
 
-def bench_cell(topo_name: str, args, backend: str, records: list[dict]):
+def bench_cell(topo_name: str, args, records: list[dict]):
     tenants = build_tenants(topo_name, args)
 
     # untimed passes populate the compile caches for BOTH dispatch
     # shapes (serial B=1 stacks vs coalesced multi-member stacks)
-    run_mode(tenants, args, backend, coalesce=False)
-    run_mode(tenants, args, backend, coalesce=True)
+    run_mode(tenants, args, coalesce=False)
+    run_mode(tenants, args, coalesce=True)
 
-    serial, t_serial = run_mode(tenants, args, backend, coalesce=False)
-    coal, t_coal = run_mode(tenants, args, backend, coalesce=True)
+    serial, t_serial = run_mode(tenants, args, coalesce=False)
+    coal, t_coal = run_mode(tenants, args, coalesce=True)
 
     done_s = sum(r.status == "done" for r in serial.requests)
     done_c = sum(r.status == "done" for r in coal.requests)
     thr_s = done_s / t_serial
     thr_c = done_c / t_coal
-    cell = f"{topo_name}/{backend}"
+    cell = topo_name
     print(f"service/{cell}/serial,{t_serial*1e3:.1f},"
           f"{thr_s:.2f} co-flows/s p99={serial.latency.p99:.3f}s "
           f"({serial.counters.dispatches} dispatches)")
@@ -94,14 +93,14 @@ def bench_cell(topo_name: str, args, backend: str, records: list[dict]):
           f"{coal.counters.bucket_hits} bucket hits)")
     records += [
         bench_json.record(
-            f"service/{cell}/serial", topology=topo_name, backend=backend,
+            f"service/{cell}/serial", topology=topo_name,
             wall_ms=t_serial * 1e3,
             derived=f"{thr_s:.2f} co-flows/s at p99="
                     f"{serial.latency.p99:.3f}s "
                     f"({serial.counters.dispatches} dispatches)"),
         bench_json.record(
             f"service/{cell}/coalesced", topology=topo_name,
-            backend=backend, wall_ms=t_coal * 1e3,
+            wall_ms=t_coal * 1e3,
             derived=f"{thr_c:.2f} co-flows/s at p99="
                     f"{coal.latency.p99:.3f}s "
                     f"({coal.counters.dispatches} dispatches, "
@@ -119,9 +118,6 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3000)
     ap.add_argument("--tol", type=float, default=2e-3)
     ap.add_argument("--topos", default="spine-leaf,pon3")
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings to compare "
-                         f"({','.join(solver.BACKENDS)})")
     ap.add_argument("--family", default="poisson",
                     help=f"arrival family ({','.join(arrivals.FAMILIES)})")
     ap.add_argument("--mean-s", type=float, default=1.0)
@@ -136,69 +132,53 @@ def main(argv=None) -> int:
                          "window wait, so it is bounded below by ~1 "
                          "window even at zero solve cost)")
     ap.add_argument("--min-speedup", type=float, default=1.05,
-                    help="at least one backend's aggregate coalesced-vs-"
-                         "serial throughput ratio must reach this; every "
-                         "backend must stay >= 1.0 (no regression)")
+                    help="the aggregate coalesced-vs-serial throughput "
+                         "ratio must reach this")
     ap.add_argument("--json-out", default=str(bench_json.DEFAULT_PATH),
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
     compile_cache.enable()
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
-    agg: dict[str, tuple[float, float, float]] = {}
-    for backend in backends:
-        ds = dc = ts = tc = 0.0
-        p99_c = 0.0
-        for t in args.topos.split(","):
-            (n_s, w_s, _), (n_c, w_c, p_c) = bench_cell(t, args, backend,
-                                                        records)
-            ds, ts = ds + n_s, ts + w_s
-            dc, tc = dc + n_c, tc + w_c
-            p99_c = max(p99_c, p_c)
-        thr_s, thr_c = ds / ts, dc / tc
-        agg[backend] = (thr_s, thr_c, p99_c)
-        print(f"service/aggregate/{backend},{tc*1e3:.1f},"
-              f"{thr_c:.2f} coalesced vs {thr_s:.2f} serial co-flows/s "
-              f"({thr_c/thr_s:.2f}x) p99={p99_c:.3f}s")
-        records.append(bench_json.record(
-            f"service/aggregate/{backend}", backend=backend,
-            wall_ms=tc * 1e3,
-            derived=f"{thr_c:.2f} coalesced vs {thr_s:.2f} serial "
-                    f"co-flows/s ({thr_c/thr_s:.2f}x) at "
-                    f"p99={p99_c:.3f}s"))
+    ds = dc = ts = tc = 0.0
+    p99_c = 0.0
+    for t in args.topos.split(","):
+        (n_s, w_s, _), (n_c, w_c, p_c) = bench_cell(t, args, records)
+        ds, ts = ds + n_s, ts + w_s
+        dc, tc = dc + n_c, tc + w_c
+        p99_c = max(p99_c, p_c)
+    thr_s, thr_c = ds / ts, dc / tc
+    print(f"service/aggregate,{tc*1e3:.1f},"
+          f"{thr_c:.2f} coalesced vs {thr_s:.2f} serial co-flows/s "
+          f"({thr_c/thr_s:.2f}x) p99={p99_c:.3f}s")
+    records.append(bench_json.record(
+        "service/aggregate", wall_ms=tc * 1e3,
+        derived=f"{thr_c:.2f} coalesced vs {thr_s:.2f} serial "
+                f"co-flows/s ({thr_c/thr_s:.2f}x) at p99={p99_c:.3f}s"))
     if args.json_out:
         path = bench_json.update(
             "service_bench", records, path=args.json_out,
             args={"tenants": args.tenants, "coflows": args.coflows,
                   "iters": args.iters, "tol": args.tol,
-                  "topos": args.topos, "backends": args.backends,
-                  "family": args.family, "mean_s": args.mean_s,
+                  "topos": args.topos, "family": args.family, "mean_s": args.mean_s,
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits,
                   "p99_budget_s": args.p99_budget_s})
         print(f"service/json,0.0,records merged into {path}")
-    ratios = {b: c / max(s, 1e-9) for b, (s, c, _) in agg.items()}
+    ratio = thr_c / max(thr_s, 1e-9)
     if args.min_speedup <= 0:       # report-only (CI): no gating
         print("OK: report-only (--min-speedup 0)")
         return 0
-    for b, r in ratios.items():
-        if r < 1.0:
-            print(f"FAIL: coalescing regresses throughput on {b} "
-                  f"({r:.2f}x < 1.0x)")
-            return 1
-        if agg[b][2] > args.p99_budget_s:
-            print(f"FAIL: coalesced p99 {agg[b][2]:.3f}s > budget "
-                  f"{args.p99_budget_s}s ({b})")
-            return 1
-    best = max(ratios, key=ratios.get)
-    if ratios[best] < args.min_speedup:
-        print(f"FAIL: best coalesced-vs-serial throughput "
-              f"{ratios[best]:.2f}x ({best}) < {args.min_speedup}x")
+    if p99_c > args.p99_budget_s:
+        print(f"FAIL: coalesced p99 {p99_c:.3f}s > budget "
+              f"{args.p99_budget_s}s")
         return 1
-    print(f"OK: coalesced-vs-serial throughput {ratios[best]:.2f}x on "
-          f"{best} >= {args.min_speedup}x within p99 budget "
-          f"(all backends >= 1.0x)")
+    if ratio < args.min_speedup:
+        print(f"FAIL: coalesced-vs-serial throughput {ratio:.2f}x "
+              f"< {args.min_speedup}x")
+        return 1
+    print(f"OK: coalesced-vs-serial throughput {ratio:.2f}x "
+          f">= {args.min_speedup}x within p99 budget")
     return 0
 
 

@@ -1,5 +1,4 @@
-"""Benchmark: the batched sweep engine vs a per-instance Python loop,
-per solver backend.
+"""Benchmark: the batched sweep engine vs a per-instance Python loop.
 
 Measures sweep grid cells end-to-end, both ways:
 
@@ -14,17 +13,10 @@ Measures sweep grid cells end-to-end, both ways:
     500 iterations, converged instances freeze), with stragglers
     re-stacked into narrower dispatches instead of dragging the batch.
 
-``--backends xla,pallas`` repeats every cell per PDHG lowering (COO
-scatters vs fused blocked-ELL Pallas bursts, see docs/SOLVER.md
-"Backends") so the two hot loops are compared on identical work; on CPU
-the Pallas kernels run in interpret mode, so treat its wall times as a
-correctness/plumbing signal, not kernel throughput.
-
 Both sides solve to the same per-instance tolerance, include XLA
 compilation (the wall time a fresh sweep cell pays), and every schedule
 is verified feasible with the exact paper model before timings count.
-The speedup gate applies to the aggregate over all cells of the FIRST
-backend listed (the deployment default).
+The speedup gate applies to the aggregate over all cells.
 
 The win is largest where the sweep lives — many small/medium LPs per
 cell (bcube/dcell/PON rack cells: ~3-5x).  On topologies whose single
@@ -65,25 +57,23 @@ def build_problems(topo_name: str, n_seeds: int, pat_name: str,
 
 def bench_cell(topo_name: str, objective: str, pat_name: str, n_seeds: int,
                iters: int, tol: float, scale: tuple[int, int, float],
-               backend: str, records: list[dict]):
+               records: list[dict]):
     n_map, n_reduce, total = scale
     probs = build_problems(topo_name, n_seeds, pat_name, n_map, n_reduce,
                            total)
 
     t0 = time.perf_counter()
-    loop = [solver.solve_fast(p, objective, iters=iters, tol=tol,
-                              backend=backend)
+    loop = [solver.solve_fast(p, objective, iters=iters, tol=tol)
             for p in probs]
     t_loop = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    batch = solver.solve_fast_batch(probs, objective, iters=iters, tol=tol,
-                                    backend=backend)
+    batch = solver.solve_fast_batch(probs, objective, iters=iters, tol=tol)
     t_batch = time.perf_counter() - t0
 
     for r in loop + batch:
         assert r.metrics.feasible and r.remaining_gbits < 1e-6, topo_name
-    cell = f"{topo_name}/{pat_name}/min-{objective}/{backend}"
+    cell = f"{topo_name}/{pat_name}/min-{objective}"
     it_mean = float(np.mean([r.iterations for r in batch]))
     print(f"sweep/{cell}/loop,{t_loop*1e3:.1f},"
           f"{n_seeds} seeds ({n_map}x{n_reduce} tasks, {total:g} Gbit)")
@@ -92,13 +82,13 @@ def bench_cell(topo_name: str, objective: str, pat_name: str, n_seeds: int,
     records += [
         bench_json.record(
             f"sweep/{cell}/loop", topology=topo_name, objective=objective,
-            backend=backend, wall_ms=t_loop * 1e3,
+            wall_ms=t_loop * 1e3,
             iterations=float(np.mean([r.iterations for r in loop])),
             derived=f"{n_seeds} seeds ({n_map}x{n_reduce} tasks, "
                     f"{total:g} Gbit)"),
         bench_json.record(
             f"sweep/{cell}/batch", topology=topo_name, objective=objective,
-            backend=backend, wall_ms=t_batch * 1e3, iterations=it_mean,
+            wall_ms=t_batch * 1e3, iterations=it_mean,
             derived=f"{t_loop/t_batch:.2f}x speedup vs loop"),
     ]
     return t_loop, t_batch
@@ -113,44 +103,34 @@ def main(argv=None) -> int:
                          "re-scored exactly regardless)")
     ap.add_argument("--topos", default="bcube,dcell,pon3")
     ap.add_argument("--objectives", default="energy,time")
-    ap.add_argument("--backends", default="xla,pallas",
-                    help="comma list of PDHG lowerings to compare "
-                         f"({','.join(solver.BACKENDS)}); the speedup "
-                         "gate applies to the first one")
     ap.add_argument("--pattern", default="uniform")
     ap.add_argument("--n-map", type=int, default=4)
     ap.add_argument("--n-reduce", type=int, default=3)
     ap.add_argument("--total-gbits", type=float, default=8.0)
     ap.add_argument("--min-speedup", type=float, default=3.0,
-                    help="gate on the first backend's aggregate speedup "
-                         "over all cells")
+                    help="gate on the aggregate speedup over all cells")
     ap.add_argument("--json-out", default=str(bench_json.DEFAULT_PATH),
                     help="BENCH_solver.json to merge records into "
                          "('' disables)")
     args = ap.parse_args(argv)
     compile_cache.enable()
     scale = (args.n_map, args.n_reduce, args.total_gbits)
-    backends = bench_json.parse_backends(ap, args.backends)
     records: list[dict] = []
-    agg: dict[str, tuple[float, float]] = {}
-    for backend in backends:
-        sum_loop = sum_batch = 0.0
-        for t in args.topos.split(","):
-            for obj in args.objectives.split(","):
-                tl, tb = bench_cell(t, obj, args.pattern, args.seeds,
-                                    args.iters, args.tol, scale, backend,
-                                    records)
-                sum_loop += tl
-                sum_batch += tb
-        agg[backend] = (sum_loop, sum_batch)
+    sum_loop = sum_batch = 0.0
+    for t in args.topos.split(","):
+        for obj in args.objectives.split(","):
+            tl, tb = bench_cell(t, obj, args.pattern, args.seeds,
+                                args.iters, args.tol, scale, records)
+            sum_loop += tl
+            sum_batch += tb
     return bench_json.finish_comparison(
-        "sweep_bench", "sweep", backends, agg, records,
+        "sweep_bench", "sweep", sum_loop, sum_batch, records,
         total_label="loop total", speed_label="speedup vs per-instance loop",
-        ratio_label="batch time", json_out=args.json_out,
+        json_out=args.json_out,
         min_speedup=args.min_speedup,
         run_args={"seeds": args.seeds, "iters": args.iters, "tol": args.tol,
                   "topos": args.topos, "objectives": args.objectives,
-                  "backends": args.backends, "pattern": args.pattern,
+                  "pattern": args.pattern,
                   "n_map": args.n_map, "n_reduce": args.n_reduce,
                   "total_gbits": args.total_gbits})
 

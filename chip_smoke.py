@@ -8,7 +8,7 @@ One chip, in order (any failure exits non-zero; no exception is caught):
   1. device gate: the first JAX device must be a TPU; there is no CPU
      fallback;
   2. golden cells: spine-leaf and pon3 x min-energy and min-time, seed 0,
-     through `solve_fast` on the default backend, each schedule certified
+     through `solve_fast` (dense tiles on the chip), each schedule certified
      by `core.verify`, metrics held to tests/golden/metrics.json at the
      golden test's RTOL; then the pinned two-tenant `run_service` run;
   3. the sweep CLI, in process, over every topology with both
@@ -21,8 +21,8 @@ One chip, in order (any failure exits non-zero; no exception is caught):
      demand may leak.
 
 `--four-chips` solves the fat-tree-k16 instance row-sharded over four
-chips (`backend="pallas", shards=4`) and on one chip with the default
-backend; metrics must agree to 1e-4 relative and both schedules certify.
+chips (`shards=4`) and on one chip; metrics must agree to 1e-4 relative
+and both schedules certify.
 
 All traffic is generated from seeds.  Wall times printed here are smoke
 values, not metrics.  The last line of standard output is the verdict:
@@ -39,7 +39,6 @@ import sys
 import tempfile
 import time
 
-import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT / "tests")]
@@ -89,7 +88,7 @@ def phase_golden() -> None:
     want_all = golden._golden()
     for topo_name, objective in golden.GRID:
         key = f"{topo_name}/min-{objective}/seed{golden.SEED}"
-        got = golden._solve(topo_name, objective, "xla")   # certifies
+        got = golden._solve(topo_name, objective)   # certifies
         want = want_all[key]
         assert got["feasible"] and want["feasible"], key
         devs = {k: rel_dev(got[k], want[k]) for k in METRIC_KEYS}
@@ -101,7 +100,7 @@ def phase_golden() -> None:
                                 abs_tol=1e-9), (key, k, got[k], want[k])
 
     want = want_all[golden.SERVICE_KEY]
-    got = golden._service_run("xla")          # every member certified
+    got = golden._service_run()          # every member certified
     for k in ("n_done", "arrived", "admitted"):
         assert got[k] == want[k], (golden.SERVICE_KEY, k, got[k], want[k])
     worst = 0.0
@@ -203,13 +202,12 @@ def phase_service() -> None:
 def phase_four_chips(devices) -> None:
     """fat-tree-k16 row-sharded over 4 chips vs the one-chip default."""
     import jax.numpy as jnp
-    from repro.kernels import ops as kops
+    from repro.core import pdhg
     from repro.runtime.sharding import solver_mesh
 
     (p,) = k16_problems([0])
     runs = {}
-    for label, kw in (("xla 1 chip", {}),
-                      ("pallas 4 shards", dict(backend="pallas", shards=4))):
+    for label, kw in (("1 chip", {}), ("4 shards", dict(shards=4))):
         t0 = time.perf_counter()
         r = solver.solve_fast(p, "energy", iters=K16_ITERS, tol=K16_TOL,
                               **kw)
@@ -219,7 +217,7 @@ def phase_four_chips(devices) -> None:
         say(f"four-chips {label}: E={r.metrics.energy_j!r} J "
             f"M={r.metrics.completion_s!r} s iterations={r.iterations} "
             f"cert=ok smoke_wall_s={wall:.3f}")
-    one, four = runs["xla 1 chip"].metrics, runs["pallas 4 shards"].metrics
+    one, four = runs["1 chip"].metrics, runs["4 shards"].metrics
     for name, a, b in (("energy_j", one.energy_j, four.energy_j),
                        ("completion_s", one.completion_s, four.completion_s),
                        ("served_gbits", one.served.sum(), four.served.sum())):
@@ -228,13 +226,9 @@ def phase_four_chips(devices) -> None:
 
     # the burst's own outputs: y is split by rows over all four chips
     lp, _ = solver.build_routing_lp(p, "energy")
-    c = lp.c / max(float(np.abs(lp.c).max()), 1e-12)
-    xmax = np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
-    op, vecs, ell = solver._pack_pallas_sharded(
-        c, lp.row, lp.col, lp.val, lp.b, lp.h, xmax, lp.m_eq, 4)
-    x, y, worst = kops.pdhg_burst_sharded(
-        solver_mesh(4), *vecs, jnp.zeros(op.n_pad, bool),
-        jnp.zeros(op.m_pad, bool), *ell, jnp.zeros(op.n_pad),
+    op, vecs, ell = solver._pack_sharded(solver.block_stack([lp]).lp, 4)
+    x, y, worst = pdhg.burst_sharded(
+        solver_mesh(4), *vecs, *ell, jnp.zeros(op.n_pad),
         jnp.zeros(op.m_pad), row_meta=op.row_meta, col_meta=op.col_meta,
         iters=10)
     spans = {name: sorted(s.device.id for s in a.addressable_shards)

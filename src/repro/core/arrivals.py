@@ -15,8 +15,7 @@ module turns the one-shot optimizer into a simulated online scheduler:
     warm-started from the previous epoch's PDHG state via
     ``solver.project_warm_start`` (``flow_map`` carries residual flows
     forward under their new indices; topology-shape changes or
-    projection failures fall back to a cold solve), on either solver
-    backend;
+    projection failures fall back to a cold solve);
   * :func:`interleave_traces` / :func:`merge_traces` merge per-tenant
     traces into one deterministic global stream — the request feed of
     the multi-tenant scheduler service (:mod:`repro.service`).
@@ -267,7 +266,7 @@ class OnlineResult:
                                   # time-to-recover per episode, seconds
     chaos_log: list[str] = dataclasses.field(default_factory=list)
                                   # canonical replay log lines (byte-
-                                  # stable per seed and backend)
+                                  # stable per seed and platform)
 
     @property
     def n_epochs(self) -> int:
@@ -324,7 +323,7 @@ def run_online(topo: Topology, trace: list[Arrival],
                rho: float = 8.0, q_weight: float = 100.0,
                path_slack: int | None = 2, iters: int = 3000,
                tol: float | None = 2e-3, chunk: int = 250,
-               backend: str = "xla", warm: bool = True,
+               warm: bool = True,
                max_epochs: int = 128,
                chaos: list[chaosmod.ChaosEvent] | None = None,
                fallback_policy: str | None = None) -> OnlineResult:
@@ -357,7 +356,6 @@ def run_online(topo: Topology, trace: list[Arrival],
     the eq. 39 in-slot transmission-time convention."""
     if objective not in ("energy", "time"):
         raise ValueError(f"objective {objective!r} not in ('energy', 'time')")
-    solver._check_backend(backend)
     D = topo.slot_duration
     if epoch_s is None:
         epoch_s = 4.0 * D
@@ -474,8 +472,7 @@ def run_online(topo: Topology, trace: list[Arrival],
         r = solver.solve_fast_warm(p, objective,
                                    warm=prev if use_warm else None,
                                    flow_map=flow_map if use_warm else None,
-                                   iters=iters, tol=tol, chunk=chunk,
-                                   backend=backend)
+                                   iters=iters, tol=tol, chunk=chunk)
         # what actually ran, not what was attempted: solve_fast_warm
         # silently falls back to cold when the projection is unusable
         warm_ran = r.warm_started
@@ -492,7 +489,7 @@ def run_online(topo: Topology, trace: list[Arrival],
             p = rehorizon(p, 2 * p.n_slots,
                           path_slack=path_slack if tries == 0 else None)
             r = solver.solve_fast_warm(p, objective, iters=iters, tol=tol,
-                                       chunk=chunk, backend=backend)
+                                       chunk=chunk)
             spent += r.iterations
             tries += 1
         if (fallback is not None and len(src) > 0
@@ -501,7 +498,7 @@ def run_online(topo: Topology, trace: list[Arrival],
             # epoch to a certified baseline policy on a stretched
             # horizon; accepted only if it drains the demand feasibly
             p_fb = rehorizon(p, 2 * p.n_slots)
-            fb = fallback.solve(p_fb, objective, backend=backend)
+            fb = fallback.solve(p_fb, objective)
             if fb.metrics.feasible and fb.remaining_gbits <= 1e-6:
                 p, r = p_fb, fb
                 tries += 1

@@ -30,7 +30,7 @@ see docs/CHAOS.md for the recovery ladder and metric definitions.
 
 Determinism: every stream is seeded through crc32 tags of (module,
 class/preset, topology name) plus the integer seed — byte-identical
-traces across processes, platforms, and solver backends, immune to
+traces across processes, platforms, and solvers, immune to
 PYTHONHASHSEED.
 """
 from __future__ import annotations

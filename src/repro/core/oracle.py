@@ -2,7 +2,7 @@
 
 Builds the paper's MILP verbatim (variables x^{sd}_{uvwt}, delta_{sdt},
 B_{iwt}, A_{iwt}, Gamma_{uvwt}, M; constraints eqs. 25-47) and solves it
-with scipy's HiGHS backend.  This is the reproduction reference: the JAX
+with scipy's HiGHS solver.  This is the reproduction reference: the JAX
 fast path (core.solver) is benchmarked against it, and tests assert the
 fast path's schedules are feasible with bounded optimality gap.
 

@@ -19,8 +19,7 @@ with a policy zoo every sweep cell can run next to the LP:
                  max-min-lite fair sharing
   fair-lp        the LP fast path under the "fair" objective (energy
                  re-priced by 1 / ScheduleProblem.flow_weight): the
-                 weighted max-min fairness variant, solved by PDHG on
-                 either backend
+                 weighted max-min fairness variant, solved by PDHG
 
 Every policy returns the same `FastPathResult` type as
 `core.solver.solve_fast` — exact `core.timeslot.evaluate` metrics, a
@@ -347,8 +346,8 @@ def _strict_priority_pack(p: ScheduleProblem, idx: RoutingIndex,
 @dataclasses.dataclass(frozen=True)
 class Policy:
     """One baseline scheduler.  `solve` mirrors solve_fast's signature;
-    heuristic policies ignore iters/tol/backend (accepted for drop-in
-    interface parity) and are pure numpy, hence backend-independent."""
+    heuristic policies ignore iters/tol (accepted for drop-in interface
+    parity) and are pure numpy, hence platform-independent."""
 
     name: str
     summary: str
@@ -362,8 +361,7 @@ class Policy:
         return temporal_pack(p, idx, np.zeros(len(idx.kf)), paths=paths)
 
     def solve(self, p: ScheduleProblem, objective: str = "energy", *,
-              iters: int = 0, tol: float | None = None,
-              backend: str = "xla") -> FastPathResult:
+              iters: int = 0, tol: float | None = None) -> FastPathResult:
         idx, paths = self.route(p, objective)
         x = self.pack(p, idx, paths)
         return _result(p, objective, idx, paths, x)
@@ -449,14 +447,12 @@ class FairSharePolicy(Policy):
 @dataclasses.dataclass(frozen=True)
 class FairLpPolicy(Policy):
     """The LP fast path under the "fair" objective (weighted max-min
-    fairness surrogate).  The one policy that runs PDHG — iters/tol/
-    backend are honoured; with uniform weights it coincides with the
-    min-energy LP."""
+    fairness surrogate).  The one policy that runs PDHG — iters/tol are
+    honoured; with uniform weights it coincides with the min-energy
+    LP."""
 
-    def solve(self, p, objective="energy", *, iters=3000,
-              tol=None, backend="xla"):
-        r = solve_fast(p, "fair", iters=iters or 3000, tol=tol,
-                       backend=backend)
+    def solve(self, p, objective="energy", *, iters=3000, tol=None):
+        r = solve_fast(p, "fair", iters=iters or 3000, tol=tol)
         return dataclasses.replace(
             r, certificate=verify.check_schedule(p, r.schedule))
 

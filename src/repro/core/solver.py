@@ -6,11 +6,10 @@ MILP into:
 
   1. a *routing LP* over (flow, edge, wavelength) volumes for the whole
      horizon — solved with diagonally-preconditioned PDHG
-     (Chambolle-Pock) written entirely in JAX.  Many instances solve in
-     one dispatch: block-diagonal stacking with a fused in-graph adaptive
-     convergence loop (solve_lp_batch / solve_fast_batch), plus a literal
-     vmap variant (pad_and_stack + _pdhg_run_batch) for accelerators with
-     fast batched scatter;
+     (Chambolle-Pock, core.pdhg) written entirely in JAX.  Many instances
+     solve in one dispatch: block-diagonal stacking with a fused
+     in-graph adaptive convergence loop (solve_lp_batch /
+     solve_fast_batch);
   2. a *temporal packing* pass that quantizes the fractional routing into
      the paper's discrete slots (greedy earliest-slot water-filling, with
      the PON3 one-wavelength-per-server-per-slot rule honoured);
@@ -35,7 +34,7 @@ assembly is vectorized index arithmetic, constraint sparsity and
 RoutingIndex are cached across solves keyed by a structure hash
 (ProblemStructure; arrival epochs, horizon retries, and scaled
 degradations rebuild nothing — build_cache_stats() counts hits), the
-blocked-ELL layout is plan-cached per sparsity pattern, and batched/
+tile layout is plan-cached per sparsity pattern, and batched/
 warm dispatches are padded onto shape buckets so compiled executables
 are reused across grid cells instead of recompiled per exact shape.
 
@@ -48,14 +47,14 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import trace
-from . import tile_spmv
+from . import pdhg, tile_spmv
 from .timeslot import Metrics, ScheduleProblem, evaluate
 
 Array = jax.Array
@@ -103,128 +102,36 @@ class PDHGResult:
     y: np.ndarray | None = None
 
 
-def _coo_pair(row, col, val, m, n):
-    """(Kx, KTy) as one scalar gather and scatter-add per nonzero."""
-    def Kx(x):
-        with jax.named_scope("pdhg/Kx"):
-            return jnp.zeros(m).at[row].add(val * x[col])
-
-    def KTy(y):
-        with jax.named_scope("pdhg/KTy"):
-            return jnp.zeros(n).at[col].add(val * y[row])
-
-    return Kx, KTy
-
-
-def _pdhg_ops(c, row, col, val, b, h, m, n, m_eq, tiles=None):
-    """Shared PDHG machinery: stacked rhs q, diagonal preconditioners
-    (tau_j = 1/sum_i |K_ij|, sig_i = 1/sum_j |K_ij|), the sparse operator
-    pair (Kx, KTy), and the inequality-row mask.  Single source of truth
-    for both the resumable kernel and the fused adaptive batch kernel —
-    their trajectories must stay identical.
-
-    The operator pair is the COO scatter (`tiles` None) or, given the
-    stacked tile plan's arrays, the block-sparse tile operator of
-    core.tile_spmv; the update formulas are the same for both.
-
-    The pallas backend mirrors these formulas: _pack_pallas
-    (preconditioners/q/ub mask, numpy) and the shared update body
-    kernels/pdhg_spmv.py::pdhg_update_burst (used by both the kernel
-    and its ref.py oracle).  Any change here must be replicated there,
-    or the backend-equivalence tests (tests/test_pdhg_kernels.py) will
-    drift apart."""
-    q = jnp.concatenate([b, h])
-    abs_val = jnp.abs(val)
-    col_sum = jnp.zeros(n).at[col].add(abs_val)
-    row_sum = jnp.zeros(m).at[row].add(abs_val)
-    tau = 1.0 / jnp.maximum(col_sum, 1e-12)
-    sig = 1.0 / jnp.maximum(row_sum, 1e-12)
-
-    Kx, KTy = (_coo_pair(row, col, val, m, n) if tiles is None
-               else tile_spmv.operator_pair(tiles, val, m, n))
-    ub_mask = jnp.arange(m) >= m_eq
-    return q, tau, sig, Kx, KTy, ub_mask
+def _operator(row, col, val, m, n, tiles):
+    """The COO pair, or with `tiles` (core.tile_spmv.StackedTiles.arrays)
+    the tile pair, whose tables are filled here, outside any loop."""
+    if tiles is None:
+        return pdhg.coo_pair(row, col, val, m, n)
+    return tile_spmv.operator_pair(tiles, val, m, n)
 
 
 def _pdhg_kernel_state(c, row, col, val, b, h, xmax, x0, y0,
-                       m, n, m_eq, iters):
-    """Diagonally-preconditioned PDHG (Pock & Chambolle 2011), resumable:
-    starts from (x0, y0) and returns the final (x, y, primal, gap) so
-    restarts continue the trajectory instead of re-running from zero."""
-    q, tau, sig, Kx, KTy, ub_mask = _pdhg_ops(c, row, col, val, b, h,
-                                              m, n, m_eq)
-
-    def body(_, state):
-        x, y = state
-        x_new = jnp.clip(x - tau * (c + KTy(y)), 0.0, xmax)
-        x_bar = 2.0 * x_new - x
-        y_new = y + sig * (Kx(x_bar) - q)
-        y_new = jnp.where(ub_mask, jnp.maximum(y_new, 0.0), y_new)
-        return x_new, y_new
-
-    x, y = jax.lax.fori_loop(0, iters, body, (x0, y0))
-    r = Kx(x) - q
-    res_eq = jnp.abs(jnp.where(ub_mask, 0.0, r)).max(initial=0.0)
-    res_ub = jnp.maximum(jnp.where(ub_mask, r, -jnp.inf), 0.0).max(initial=0.0)
-    primal = jnp.maximum(res_eq, res_ub)
+                       m, n, m_eq, iters, tiles=None):
+    """`iters` PDHG iterations (core.pdhg) from (x0, y0), resumable:
+    returns the final (x, y, primal, gap) so restarts continue the
+    trajectory instead of re-running from zero."""
+    q, tau, sig, ub = pdhg.preconditioners(row, col, val, b, h, m, n, m_eq)
+    Kx, KTy = _operator(row, col, val, m, n, tiles)
+    x, y = pdhg.burst(Kx, KTy, c, tau, sig, q, xmax, ub, x0, y0, iters)
+    primal = pdhg.residual(Kx, q, ub, x).max(initial=0.0)
     # crude gap proxy: |c.x + q.y_clamped| / (1+|c.x|)
     obj = c @ x
     gap = jnp.abs(obj + q @ y) / (1.0 + jnp.abs(obj))
     return x, y, primal, gap
 
 
-@functools.partial(jax.jit, static_argnames=("m", "n", "m_eq", "iters", "check_every"))
-def _pdhg_run(c, row, col, val, b, h, xmax, m, n, m_eq, iters, check_every):
-    """Cold-start single-instance PDHG (kept for callers/tests that want
-    the historical (x, primal, gap) interface)."""
-    x, _, primal, gap = _pdhg_kernel_state(
-        c, row, col, val, b, h, xmax, jnp.zeros(n), jnp.zeros(m),
-        m, n, m_eq, iters)
-    return x, primal, gap
-
-
 _pdhg_resume = functools.partial(jax.jit, static_argnames=(
     "m", "n", "m_eq", "iters"))(_pdhg_kernel_state)
 
 
-# ---------------------------------------------------------------------------
-# Pallas backend: the same PDHG update over a blocked-ELL operator
-# ---------------------------------------------------------------------------
-#
-# backend="xla" (default) runs the COO scatter kernels above, bit-for-bit
-# unchanged.  backend="pallas" re-packs the operator into the blocked-ELL
-# layout of repro.kernels.pdhg_spmv and runs whole iteration bursts as one
-# fused Pallas kernel (K^T.y gather, prox/clip, K.x, dual ascent, terminal
-# residuals) — validated on CPU in interpret mode.  On one TPU, Mosaic
-# refuses the burst's flat gather ("Only 2D gather is supported"), so
-# this backend runs on the chip only row-sharded (shards > 1, plain jnp
-# inside shard_map).  Same math, same freeze semantics; only the SpMV
-# reduction order differs, so results agree to fp tolerance, not bitwise
-# (see docs/SOLVER.md "Backends" and docs/KERNELS.md).
-
-BACKENDS = ("xla", "pallas")
-PRECISIONS = ("fp32", "bf16")
-
-
-def _check_backend(backend: str) -> None:
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown solver backend {backend!r}; "
-                         f"have {BACKENDS}")
-
-
-def _check_scale_opts(backend: str, shards: int, precision: str) -> None:
-    """Validate the scale knobs: both the sharded operator and the bf16
-    iterate storage exist only in the blocked-ELL lowering, so anything
-    but the defaults requires backend="pallas"."""
-    if precision not in PRECISIONS:
-        raise ValueError(f"unknown precision {precision!r}; "
-                         f"have {PRECISIONS}")
+def _check_shards(shards: int) -> None:
     if shards < 1:
         raise ValueError(f"shards must be >= 1, got {shards}")
-    if backend != "pallas" and (shards > 1 or precision != "fp32"):
-        raise ValueError(
-            f"shards={shards}, precision={precision!r} require "
-            f"backend='pallas' (the xla COO path is single-device fp32)")
 
 
 def _solve_lp_trivial(lp: StructuredLP) -> PDHGResult:
@@ -240,37 +147,10 @@ def _solve_lp_trivial(lp: StructuredLP) -> PDHGResult:
     return PDHGResult(x, 0.0, 0.0, 0, y=np.zeros(lp.m))
 
 
-def _ell_operator_cached(row, col, val, m, n):
-    """Blocked-ELL pack with the layout plan cached per sparsity pattern.
-
-    The plan (stable argsort, per-block widths, gather indices) depends
-    only on (row, col, m, n); re-solves over an unchanged structure —
-    arrival epochs, scaled degradations, warm restarts — refresh the
-    coefficient values in O(nnz) instead of re-packing (`ell_fill`).
-    Keyed by a content digest, so equal patterns hit regardless of which
-    problem object produced them; counters land in BUILD_STATS."""
-    from repro.kernels import pdhg_spmv
-
-    key = (m, n, len(val),
-           hashlib.blake2b(np.ascontiguousarray(row).tobytes()
-                           + np.ascontiguousarray(col).tobytes(),
-                           digest_size=16).digest())
-    plan = _ELL_PLAN_CACHE.get(key)
-    if plan is None:
-        plan = pdhg_spmv.ell_plan(row, col, m, n)
-        BUILD_STATS.ell_misses += 1
-        if len(_ELL_PLAN_CACHE) >= _ELL_PLAN_CACHE_MAX:
-            _ELL_PLAN_CACHE.pop(next(iter(_ELL_PLAN_CACHE)))
-        _ELL_PLAN_CACHE[key] = plan
-    else:
-        BUILD_STATS.ell_hits += 1
-    return pdhg_spmv.ell_fill(plan, val)
-
-
 def _tile_plan_cached(lp: StructuredLP) -> tile_spmv.InstancePlan:
     """One instance's tile plan (core.tile_spmv.instance_plan), cached
-    per sparsity pattern like _ell_operator_cached: keyed by a digest of
-    (row, col) and (m, n, m_eq); counters land in BUILD_STATS."""
+    per sparsity pattern: keyed by a digest of (row, col) and (m, n,
+    m_eq); counters land in BUILD_STATS."""
     key = (lp.m, lp.n, lp.m_eq, len(lp.val),
            hashlib.blake2b(np.ascontiguousarray(lp.row).tobytes()
                            + np.ascontiguousarray(lp.col).tobytes(),
@@ -287,154 +167,87 @@ def _tile_plan_cached(lp: StructuredLP) -> tile_spmv.InstancePlan:
     return plan
 
 
-def _pack_pallas(c, row, col, val, b, h, xmax, m_eq):
-    """Pack one (already max-normalized, xmax-clamped) LP for the Pallas
-    kernels: blocked-ELL tables for both SpMV directions plus the
-    storage-padded vector arguments.  Padded x-slots carry tau=c=xmax=0
-    and padded y-slots sig=q=0, so they stay pinned at zero through any
-    number of iterations.
-
-    The tau/sig/q/ub formulas are a numpy mirror of _pdhg_ops (which
-    builds them in-trace from the COO arrays) — keep the two in
-    lockstep."""
-    n, m = len(c), len(b) + len(h)
-    op = _ell_operator_cached(row, col, val, m, n)
-    q = np.concatenate([b, h])
-    abs_val = np.abs(val)
-    col_sum = np.zeros(n)
-    np.add.at(col_sum, col, abs_val)
-    row_sum = np.zeros(m)
-    np.add.at(row_sum, row, abs_val)
-    tau = 1.0 / np.maximum(col_sum, 1e-12)
-    sig = 1.0 / np.maximum(row_sum, 1e-12)
-    ub = np.arange(m) >= m_eq
-
-    def padn(a):
-        return jnp.asarray(np.pad(np.asarray(a, np.float32),
-                                  (0, op.n_pad - n)))
-
-    def padm(a):
-        return jnp.asarray(np.pad(np.asarray(a, np.float32),
-                                  (0, op.m_pad - m)))
-
-    vecs = (padn(c), padn(tau), padn(xmax), padm(q), padm(sig),
-            jnp.asarray(np.pad(ub, (0, op.m_pad - m), constant_values=True)))
-    ell = tuple(jnp.asarray(a) for a in (op.rows.idx, op.rows.val,
-                                         op.cols.idx, op.cols.val))
-    return op, vecs, ell
-
-
-def _solve_lp_pallas(lp: StructuredLP, iters: int, tol: float,
-                     max_restarts: int, x0, y0,
-                     precision: str = "fp32") -> PDHGResult:
-    """solve_lp's restart ladder with each rung one fused Pallas burst."""
-    from repro.kernels import ops as kops
-
-    xmax = np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
-    cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
-    op, vecs, ell = _pack_pallas(lp.c / cscale, lp.row, lp.col, lp.val,
-                                 lp.b, lp.h, xmax, lp.m_eq)
-    keep_n = jnp.zeros(op.n_pad, bool)
-    keep_m = jnp.zeros(op.m_pad, bool)
-    x = jnp.zeros(op.n_pad) if x0 is None else jnp.asarray(
-        np.pad(np.asarray(x0, np.float32), (0, op.n_pad - lp.n)))
-    y = jnp.zeros(op.m_pad) if y0 is None else jnp.asarray(
-        np.pad(np.asarray(y0, np.float32), (0, op.m_pad - lp.m)))
-    total_iters = 0
-    for attempt in range(max_restarts + 1):
-        x, y, worst = kops.pdhg_burst(
-            *vecs, keep_n, keep_m, *ell, x, y,
-            row_meta=op.rows.meta, col_meta=op.cols.meta, iters=iters,
-            precision=precision)
-        total_iters += iters
-        primal = float(jnp.max(worst))        # padded rows contribute 0
-        if primal <= tol:
-            break
-        iters *= 2
-    x_np = np.asarray(x)[:lp.n].astype(np.float64)
-    y_np = np.asarray(y)[:lp.m].astype(np.float64)
-    obj = float(lp.c @ x_np) / cscale
-    gap = abs(obj + float(np.concatenate([lp.b, lp.h]) @ y_np)) \
-        / (1.0 + abs(obj))
-    return PDHGResult(x_np, primal, gap, total_iters, y=y_np)
-
-
-def _pack_pallas_sharded(c, row, col, val, b, h, xmax, m_eq, shards):
-    """_pack_pallas for the row-block-sharded operator: same tau/sig/q/ub
-    formulas, but the y-side vectors are padded to shards*m_loc (the
-    concatenation of the per-shard row blocks) and the ELL tables come
-    from ell_pack_sharded (per-shard widths unified so shard_map traces
-    one program).  Padded rows carry sig=q=0 / ub=True exactly as in the
-    single-device pack, so they never move and never pollute psum."""
+def _pack_sharded(g: StructuredLP, shards: int):
+    """Pack a (max-normalized, xmax-clamped) LP for the row-sharded
+    burst: the blocked-ELL tables of kernels.pdhg_spmv.ell_pack_sharded
+    and the vectors of core.pdhg.preconditioners, padded to the pack.
+    The y side is padded to shards * m_loc, the rows of every shard.
+    Padded x-slots carry tau=c=xmax=0 and padded rows sig=q=0 and
+    ub=True, so they stay at zero and add nothing to the psum."""
     from repro.kernels import pdhg_spmv
 
-    n, m = len(c), len(b) + len(h)
-    op = pdhg_spmv.ell_pack_sharded(row, col, val, m, n, shards)
-    q = np.concatenate([b, h])
-    abs_val = np.abs(val)
-    col_sum = np.zeros(n)
-    np.add.at(col_sum, col, abs_val)
-    row_sum = np.zeros(m)
-    np.add.at(row_sum, row, abs_val)
-    tau = 1.0 / np.maximum(col_sum, 1e-12)
-    sig = 1.0 / np.maximum(row_sum, 1e-12)
-    ub = np.arange(m) >= m_eq
+    op = pdhg_spmv.ell_pack_sharded(g.row, g.col, g.val, g.m, g.n, shards)
+    q, tau, sig, ub = pdhg.preconditioners(
+        *(jnp.asarray(a) for a in (g.row, g.col, g.val, g.b, g.h)),
+        g.m, g.n, g.m_eq)
 
     def padn(a):
-        return jnp.asarray(np.pad(np.asarray(a, np.float32),
-                                  (0, op.n_pad - n)))
+        return jnp.pad(jnp.asarray(a, jnp.float32), (0, op.n_pad - g.n))
 
-    def padm(a):
-        return jnp.asarray(np.pad(np.asarray(a, np.float32),
-                                  (0, op.m_pad - m)))
+    def padm(a, value=0.0):
+        return jnp.pad(a, (0, op.m_pad - g.m), constant_values=value)
 
-    vecs = (padn(c), padn(tau), padn(xmax), padm(q), padm(sig),
-            jnp.asarray(np.pad(ub, (0, op.m_pad - m), constant_values=True)))
+    vecs = (padn(g.c), padn(tau), padn(g.xmax), padm(q), padm(sig),
+            padm(ub, True))
     ell = tuple(jnp.asarray(a) for a in (op.row_idx, op.row_val,
                                          op.col_idx, op.col_val))
     return op, vecs, ell
 
 
-def _solve_lp_pallas_sharded(lp: StructuredLP, iters: int, tol: float,
-                             max_restarts: int, x0, y0, shards: int,
-                             precision: str = "fp32") -> PDHGResult:
-    """_solve_lp_pallas with the [eq; ub] rows partitioned across `shards`
-    devices (runtime.sharding.solver_mesh) and each burst a shard_map'd
-    program with one psum per iteration for K^T.y.  Only engaged for
-    shards > 1 — solve_lp routes shards=1 through _solve_lp_pallas so
-    the single-device trajectory stays bit-for-bit untouched."""
-    from repro.kernels import ops as kops
+class _Staged(NamedTuple):
+    """One staged dispatch (_stage_xla, _stage_sharded), laid out and
+    uploaded.  `place(x0, y0)` uploads host iterates in the coordinates
+    of the stacked LP; `run(x, y, budget)` runs up to `budget`
+    iterations from device iterates and returns (x, y, iterations per
+    instance, primal, gap) with x and y left on the device (primal and
+    gap device scalars, or None where the dispatch has none); `unpad(x,
+    y)` brings iterates back to the host.  `key` is the static shape of
+    the dispatch but its budget, `nnz` the dispatched nonzeros and
+    `tile_slots` a direction's tile entries (0 but for the tiles)."""
+    place: Callable
+    run: Callable
+    unpad: Callable
+    key: tuple
+    nnz: int
+    tile_slots: int
+
+
+def _stage_sharded(g: StructuredLP, shards: int, n_inst: int) -> _Staged:
+    """Stage a dispatch of the stacked LP `g` (of `n_inst` instances)
+    across `shards` devices (runtime.sharding.solver_mesh).  Each run is
+    one fixed burst, whose instances all run `budget` iterations; its
+    primal is the worst row's residual and it has no gap."""
     from repro.runtime.sharding import solver_mesh
 
     mesh = solver_mesh(shards)
-    xmax = np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
+    op, vecs, ell = _pack_sharded(g, shards)
+
+    def place(x0, y0):
+        return (jnp.pad(jnp.asarray(x0, jnp.float32), (0, op.n_pad - g.n)),
+                jnp.pad(jnp.asarray(y0, jnp.float32), (0, op.m_pad - g.m)))
+
+    def run(x, y, budget):
+        x, y, worst = pdhg.burst_sharded(
+            mesh, *vecs, *ell, x, y, row_meta=op.row_meta,
+            col_meta=op.col_meta, iters=budget)
+        # padded rows contribute 0 to the worst residual
+        return x, y, np.full(n_inst, budget), jnp.max(worst), None
+
+    def unpad(x, y):
+        return np.asarray(x)[:g.n], np.asarray(y)[:g.m]
+
+    return _Staged(place, run, unpad, ("sharded", shards, op.n_pad,
+                                       op.m_pad, n_inst),
+                   (op.row_idx.size + op.col_idx.size) // 2, 0)
+
+
+def _gap(lp: StructuredLP, x: np.ndarray, y: np.ndarray) -> float:
+    """The relative duality-gap proxy |c.x + q.y| / (1 + |c.x|) of an
+    instance, on the host, with c max-normalized as block_stack does."""
     cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
-    op, vecs, ell = _pack_pallas_sharded(lp.c / cscale, lp.row, lp.col,
-                                         lp.val, lp.b, lp.h, xmax, lp.m_eq,
-                                         shards)
-    keep_n = jnp.zeros(op.n_pad, bool)
-    keep_m = jnp.zeros(op.m_pad, bool)
-    x = jnp.zeros(op.n_pad) if x0 is None else jnp.asarray(
-        np.pad(np.asarray(x0, np.float32), (0, op.n_pad - lp.n)))
-    y = jnp.zeros(op.m_pad) if y0 is None else jnp.asarray(
-        np.pad(np.asarray(y0, np.float32), (0, op.m_pad - lp.m)))
-    total_iters = 0
-    for attempt in range(max_restarts + 1):
-        x, y, worst = kops.pdhg_burst_sharded(
-            mesh, *vecs, keep_n, keep_m, *ell, x, y,
-            row_meta=op.row_meta, col_meta=op.col_meta, iters=iters,
-            precision=precision)
-        total_iters += iters
-        primal = float(jnp.max(worst))        # padded rows contribute 0
-        if primal <= tol:
-            break
-        iters *= 2
-    x_np = np.asarray(x)[:lp.n].astype(np.float64)
-    y_np = np.asarray(y)[:lp.m].astype(np.float64)
-    obj = float(lp.c @ x_np) / cscale
-    gap = abs(obj + float(np.concatenate([lp.b, lp.h]) @ y_np)) \
+    obj = float(lp.c @ x) / cscale
+    return abs(obj + float(np.concatenate([lp.b, lp.h]) @ y)) \
         / (1.0 + abs(obj))
-    return PDHGResult(x_np, primal, gap, total_iters, y=y_np)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -444,64 +257,48 @@ def _pdhg_run_adaptive(c, row, col, val, b, h, xmax, x0, y0, tols,
                        num_inst, m, n, m_eq, chunk, max_chunks, tiles=None):
     """Fused adaptive PDHG over a block-stacked instance batch.
 
-    Runs `chunk`-iteration bursts inside one jitted lax.while_loop,
-    computing per-instance primal residuals on-device (segment-max over
-    the instance id of each row) after every burst.  An instance whose
-    residual meets its tolerance is *frozen* — its coordinates stop
-    updating — so every instance follows exactly the trajectory it would
-    have followed solving alone with the same chunk schedule, while the
-    batch stops as soon as the last straggler converges.  This replaces
-    the per-instance Python restart ladder (which overshoots by up to 2x
-    per doubling and pays a host round-trip per restart) with a single
-    dispatch of near-minimal total iterations.
+    Runs `chunk`-iteration bursts (core.pdhg.burst) inside one jitted
+    lax.while_loop, computing per-instance primal residuals on-device
+    (segment-max over the instance id of each row) after every burst.
+    An instance whose residual meets its tolerance is *frozen* — its
+    coordinates stop updating — so every instance follows exactly the
+    trajectory it would have followed solving alone with the same chunk
+    schedule, while the batch stops as soon as the last straggler
+    converges.  This replaces the per-instance Python restart ladder
+    (which overshoots by up to 2x per doubling and pays a host
+    round-trip per restart) with a single dispatch of near-minimal
+    total iterations.
 
     Coordinates may be storage-padded (shape bucketing, see
     _pad_for_buckets): `inst_n`/`inst_m` map padded slots to the dump
     segment `num_inst`, which is always treated as frozen and sliced off
-    the residual vector — identical semantics to kernels.ops'
-    pdhg_adaptive.
+    the residual vector.
 
     `tiles` (core.tile_spmv.StackedTiles.arrays) selects the tile
     operator; its tables are filled once, before the loop.
 
     Returns (x, y, per-instance residuals, per-instance chunks used)."""
-    q, tau, sig, Kx, KTy, ub_mask = _pdhg_ops(c, row, col, val, b, h,
-                                              m, n, m_eq, tiles)
+    q, tau, sig, ub = pdhg.preconditioners(row, col, val, b, h, m, n, m_eq)
+    Kx, KTy = _operator(row, col, val, m, n, tiles)
 
     def residuals(x):
         with jax.named_scope("pdhg/residual"):
-            r = Kx(x) - q
-            worst = jnp.where(ub_mask, jnp.maximum(r, 0.0), jnp.abs(r))
-            return jax.ops.segment_max(worst, inst_m,
+            return jax.ops.segment_max(pdhg.residual(Kx, q, ub, x), inst_m,
                                        num_segments=num_inst + 1)[:num_inst]
 
-    def burst(x, y, frozen):
+    def step(state):
+        x, y, k, frozen, used = state
         frozen_ext = jnp.concatenate([frozen, jnp.ones((1,), bool)])
-        keep_n = frozen_ext[inst_n]
-        keep_m = frozen_ext[inst_m]
-
-        def body(_, state):
-            x, y = state
-            x_new = jnp.clip(x - tau * (c + KTy(y)), 0.0, xmax)
-            x_new = jnp.where(keep_n, x, x_new)
-            x_bar = 2.0 * x_new - x
-            y_new = y + sig * (Kx(x_bar) - q)
-            y_new = jnp.where(ub_mask, jnp.maximum(y_new, 0.0), y_new)
-            y_new = jnp.where(keep_m, y, y_new)
-            return x_new, y_new
-
-        return jax.lax.fori_loop(0, chunk, body, (x, y))
+        x, y = pdhg.burst(Kx, KTy, c, tau, sig, q, xmax, ub, x, y, chunk,
+                          keep_n=frozen_ext[inst_n],
+                          keep_m=frozen_ext[inst_m])
+        frozen_new = frozen | (residuals(x) <= tols)
+        used = jnp.where(frozen, used, k + 1)
+        return x, y, k + 1, frozen_new, used
 
     def cond(state):
         _, _, k, frozen, _ = state
         return (k < max_chunks) & ~frozen.all()
-
-    def step(state):
-        x, y, k, frozen, used = state
-        x, y = burst(x, y, frozen)
-        frozen_new = frozen | (residuals(x) <= tols)
-        used = jnp.where(frozen, used, k + 1)
-        return x, y, k + 1, frozen_new, used
 
     frozen0 = jnp.zeros(num_inst, dtype=bool)
     used0 = jnp.zeros(num_inst, dtype=jnp.int32)
@@ -510,24 +307,11 @@ def _pdhg_run_adaptive(c, row, col, val, b, h, xmax, x0, y0, tols,
     return x, y, residuals(x), used
 
 
-@functools.partial(jax.jit, static_argnames=("m", "n", "m_eq", "iters"))
-def _pdhg_run_batch(c, row, col, val, b, h, xmax, x0, y0, m, n, m_eq, iters):
-    """vmapped resumable PDHG: leading axis of every array is the instance
-    axis.  One XLA dispatch advances the whole batch; instances must be
-    padded to common (n, m_eq, m, nnz) first (see pad_and_stack)."""
-    def one(c_, row_, col_, val_, b_, h_, xmax_, x0_, y0_):
-        return _pdhg_kernel_state(c_, row_, col_, val_, b_, h_, xmax_,
-                                  x0_, y0_, m, n, m_eq, iters)
-
-    return jax.vmap(one)(c, row, col, val, b, h, xmax, x0, y0)
-
-
 def solve_lp(lp: StructuredLP, iters: int = 4000, *,
              tol: float | None = None, max_restarts: int = 3,
              x0: np.ndarray | None = None,
              y0: np.ndarray | None = None,
-             backend: str = "xla", shards: int = 1,
-             precision: str = "fp32") -> PDHGResult:
+             shards: int = 1) -> PDHGResult:
     """Solve with PDHG; objective is max-normalized (the schedule is re-scored
     exactly afterwards, so only the argmin matters).  If the primal residual
     exceeds `tol`, continue the trajectory with doubled iterations (warm
@@ -535,47 +319,35 @@ def solve_lp(lp: StructuredLP, iters: int = 4000, *,
     primal/dual iterates (e.g. a projected healthy solution for a degraded
     re-solve, see project_warm_start); default is a cold start from zero.
 
-    `backend` selects the PDHG lowering: "xla" (default, COO scatters,
-    bit-for-bit the historical trajectory) or "pallas" (fused blocked-ELL
-    bursts via repro.kernels.pdhg_spmv; same math, fp-level differences
-    only — see docs/SOLVER.md "Backends").
-
-    `shards` > 1 partitions the constraint rows across that many devices
-    (runtime.sharding.solver_mesh — on CPU requires
-    XLA_FLAGS=--xla_force_host_platform_device_count); `precision="bf16"`
-    stores the PDHG iterates in bfloat16 between iterations with fp32
-    arithmetic and residuals.  Both require backend="pallas"; the
-    defaults (shards=1, fp32) leave every existing trajectory bit-for-bit
-    untouched — see docs/SOLVER.md §9."""
-    _check_backend(backend)
-    _check_scale_opts(backend, shards, precision)
+    K is applied as _stage_xla chooses: dense tiles on a TPU, COO
+    scatters elsewhere.  `shards` > 1 partitions the constraint rows
+    across that many devices (runtime.sharding.solver_mesh — on CPU
+    requires XLA_FLAGS=--xla_force_host_platform_device_count) and
+    applies K as row-sharded blocked ELL — see docs/SOLVER.md §9."""
+    _check_shards(shards)
     if tol is None:
         tol = 1e-4 * max(float(np.abs(lp.b).max(initial=0.0)), 1.0)
     if lp.n == 0 or lp.m == 0:
         return _solve_lp_trivial(lp)
-    if backend == "pallas":
-        if shards > 1:
-            return _solve_lp_pallas_sharded(lp, iters, tol, max_restarts,
-                                            x0, y0, shards, precision)
-        return _solve_lp_pallas(lp, iters, tol, max_restarts, x0, y0,
-                                precision)
-    xmax = np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
-    cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
-    args = (jnp.asarray(lp.c / cscale), jnp.asarray(lp.row),
-            jnp.asarray(lp.col), jnp.asarray(lp.val), jnp.asarray(lp.b),
-            jnp.asarray(lp.h), jnp.asarray(xmax))
-    x = jnp.zeros(lp.n) if x0 is None else jnp.asarray(x0)
-    y = jnp.zeros(lp.m) if y0 is None else jnp.asarray(y0)
-    total_iters = 0
-    for attempt in range(max_restarts + 1):
-        x, y, primal, gap = _pdhg_resume(*args, x, y, lp.m, lp.n, lp.m_eq,
-                                         iters)
-        total_iters += iters
+    bs = block_stack([lp])
+    st = (_stage_sharded(bs.lp, shards, 1) if shards > 1
+          else _stage_xla([lp], bs, bucket=False))
+    # the iterates stay on the device between rungs; each rung sends
+    # the host one scalar, the primal residual
+    x, y = st.place(np.zeros(lp.n) if x0 is None else x0,
+                    np.zeros(lp.m) if y0 is None else y0)
+    total = 0
+    for _ in range(max_restarts + 1):
+        x, y, _, primal, gap = st.run(x, y, iters)
+        total += iters
         if float(primal) <= tol:
             break
         iters *= 2
-    return PDHGResult(np.asarray(x), float(primal), float(gap), total_iters,
-                      y=np.asarray(y))
+    x, y = st.unpad(x, y)
+    if gap is None:
+        x, y = x.astype(np.float64), y.astype(np.float64)
+        gap = _gap(lp, x, y)
+    return PDHGResult(x, float(primal), float(gap), total, y=y)
 
 
 # ---------------------------------------------------------------------------
@@ -729,15 +501,13 @@ class ProblemStructure:
 
 @dataclasses.dataclass
 class BuildCacheStats:
-    """Counters for the problem-construction fast path (structure cache,
-    blocked-ELL and tile plan caches).  Read via `build_cache_stats()`, cleared
+    """Counters for the problem-construction fast path (structure cache
+    and tile plan cache).  Read via `build_cache_stats()`, cleared
     via `reset_build_caches()`; `python -m repro.sweep --profile` prints
     per-cell deltas."""
 
     structure_hits: int = 0
     structure_misses: int = 0
-    ell_hits: int = 0
-    ell_misses: int = 0
     tile_hits: int = 0
     tile_misses: int = 0
 
@@ -748,8 +518,6 @@ class BuildCacheStats:
 BUILD_STATS = BuildCacheStats()
 _STRUCTURE_CACHE: dict = {}
 _STRUCTURE_CACHE_MAX = 256
-_ELL_PLAN_CACHE: dict = {}
-_ELL_PLAN_CACHE_MAX = 256
 _TILE_PLAN_CACHE: dict = {}
 _TILE_PLAN_CACHE_MAX = 256
 _TILE_CAPACITY: dict = {}
@@ -761,10 +529,8 @@ def build_cache_stats() -> BuildCacheStats:
 
 
 def reset_build_caches() -> None:
-    """Drop the structure, ELL-plan and tile-plan caches and zero the
-    counters."""
+    """Drop the structure and tile-plan caches and zero the counters."""
     _STRUCTURE_CACHE.clear()
-    _ELL_PLAN_CACHE.clear()
     _TILE_PLAN_CACHE.clear()
     _TILE_CAPACITY.clear()
     for f in dataclasses.fields(BuildCacheStats):
@@ -777,7 +543,7 @@ class DispatchStats:
 
     A dispatch's compiled executable is keyed by its *post-bucketing*
     static shape (padded n/m_eq/m/nnz, instance count, chunk schedule,
-    backend) — `shape_hits` counts dispatches that landed on a shape
+    operator) — `shape_hits` counts dispatches that landed on a shape
     this process has dispatched before (the jitted kernel, and the
     persistent compilation cache of repro.compile_cache, can reuse the
     compiled executable), `shape_misses` counts first-seen shapes.  The
@@ -1589,8 +1355,7 @@ def _assemble_fast_result(p: ScheduleProblem, lp: StructuredLP,
 @trace.batched
 def solve_fast(p: ScheduleProblem, objective: str = "energy", *,
                iters: int = 4000, tol: float | None = None,
-               backend: str = "xla", shards: int = 1,
-               precision: str = "fp32") -> FastPathResult:
+               shards: int = 1) -> FastPathResult:
     """Single-instance fast path: routing LP -> PDHG -> slot packing ->
     exact re-scoring.
 
@@ -1603,88 +1368,25 @@ def solve_fast(p: ScheduleProblem, objective: str = "energy", *,
       iters: PDHG iterations per restart rung (doubled on each restart,
         up to solve_lp's max_restarts).
       tol: primal-residual target in Gbits; default 1e-4 * max demand.
-      backend: PDHG lowering, "xla" (default) or "pallas" (fused
-        blocked-ELL bursts; see docs/SOLVER.md "Backends").
-      shards: row-partition the LP across this many devices (pallas
-        only; see docs/SOLVER.md §9).
-      precision: "fp32" (default) or "bf16" iterate storage (pallas
-        only; arithmetic and residuals stay fp32).
+      shards: row-partition the LP across this many devices (see
+        docs/SOLVER.md §9).
 
     Returns a FastPathResult whose `metrics` are always the exact paper
     equations evaluated on the packed schedule — never LP estimates.
 
     Determinism: bitwise-reproducible for a fixed (jax version, platform,
-    precision config, backend); there is no RNG anywhere in the fast
-    path, so repeated calls with equal inputs return identical
-    schedules.  The two backends agree to fp tolerance (~1e-4 relative
-    on metrics), not bitwise."""
+    shards); there is no RNG anywhere in the fast path, so repeated
+    calls with equal inputs return identical schedules.  The operators
+    (COO, tiles, sharded ELL) agree to fp tolerance (~1e-4 relative on
+    metrics), not bitwise."""
     lp, idx = build_routing_lp(p, objective)
-    res = solve_lp(lp, iters=iters, tol=tol, backend=backend,
-                   shards=shards, precision=precision)
+    res = solve_lp(lp, iters=iters, tol=tol, shards=shards)
     return _assemble_fast_result(p, lp, idx, res)
 
 
 # ---------------------------------------------------------------------------
-# Batched solve (instance axis): pad LPs to a common shape, one vmapped PDHG
+# Batched solve (instance axis): block-stacked LPs, one PDHG dispatch
 # ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class BatchedLP:
-    """`B` StructuredLPs padded to common (n, m_eq, m, nnz) and stacked.
-
-    Padding is value-neutral: extra COO entries carry val=0 (contribute
-    nothing to K x, K^T y, or the diagonal preconditioners), padded
-    equality rows have b=0 and no entries (their duals stay 0), and
-    padded variables have c=0 and xmax=0 (clipped to 0 every step).  The
-    per-instance PDHG trajectory is therefore identical to the unpadded
-    solve up to floating-point reduction order."""
-
-    c: np.ndarray          # (B, n) — already max-normalized per instance
-    row: np.ndarray        # (B, nnz)
-    col: np.ndarray        # (B, nnz)
-    val: np.ndarray        # (B, nnz)
-    b: np.ndarray          # (B, m_eq)
-    h: np.ndarray          # (B, m - m_eq)
-    xmax: np.ndarray       # (B, n) — infs already clamped to 1e12
-    n_true: list[int]      # original variable counts, for unpadding
-    m: int
-    n: int
-    m_eq: int
-
-
-def pad_and_stack(lps: list[StructuredLP]) -> BatchedLP:
-    """Stack LPs with (possibly) different shapes into one instance-axis
-    batch.  Equality rows keep their indices; inequality rows are shifted
-    so every instance's ub block starts at the common m_eq."""
-    B = len(lps)
-    n = max(lp.n for lp in lps)
-    m_eq = max(lp.m_eq for lp in lps)
-    m_ub = max(lp.m - lp.m_eq for lp in lps)
-    nnz = max(len(lp.val) for lp in lps)
-    m = m_eq + m_ub
-
-    c = np.zeros((B, n))
-    row = np.zeros((B, nnz), np.int64)
-    col = np.zeros((B, nnz), np.int64)
-    val = np.zeros((B, nnz))
-    b = np.zeros((B, m_eq))
-    h = np.zeros((B, m_ub))
-    xmax = np.zeros((B, n))
-    for i, lp in enumerate(lps):
-        cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
-        c[i, :lp.n] = lp.c / cscale
-        k = len(lp.val)
-        # shift each instance's inequality block to start at the padded m_eq
-        row[i, :k] = np.where(lp.row < lp.m_eq, lp.row,
-                              lp.row + (m_eq - lp.m_eq))
-        col[i, :k] = lp.col
-        val[i, :k] = lp.val
-        b[i, :lp.m_eq] = lp.b
-        h[i, :lp.m - lp.m_eq] = lp.h
-        xmax[i, :lp.n] = np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
-    return BatchedLP(c=c, row=row, col=col, val=val, b=b, h=h, xmax=xmax,
-                     n_true=[lp.n for lp in lps], m=m, n=n, m_eq=m_eq)
-
 
 @dataclasses.dataclass
 class BlockStackedLP:
@@ -1763,9 +1465,9 @@ def _pad_for_buckets(g: StructuredLP) -> tuple[StructuredLP,
                                                tuple[int, int, int]]:
     """Pad a (stacked) LP to bucketed (n, m_eq, m_ub, nnz).
 
-    Padding is value-neutral, exactly like BatchedLP's: extra COO
-    entries carry val=0 at (row 0, col 0) — adding 0.0 to a scatter sum
-    is an fp identity — padded variables have c=0/xmax=0 (clipped to 0
+    Padding is value-neutral: extra COO entries carry val=0 at (row 0,
+    col 0) — adding 0.0 to a scatter sum is an fp identity — padded
+    variables have c=0/xmax=0 (clipped to 0
     every step), padded equality rows b=0 with no entries, padded
     inequality rows h=0.  Real inequality rows shift up by the equality
     padding; returns the padded LP plus the true (n, m_eq, m_ub) for
@@ -1793,9 +1495,10 @@ def _tile_layout(lps: list[StructuredLP], g: StructuredLP, bucket: bool
                  ) -> tuple[StructuredLP, tile_spmv.StackedTiles]:
     """Lay out the stack `g` of `lps` (block_stack) for the tile
     operator: each instance's columns, equality rows and inequality rows
-    start on a tile boundary, then (with `bucket`) the totals, the nnz
-    and each direction's tile count are padded to shape buckets.  The
-    padding is value-neutral, as _pad_for_buckets' is.  So the shapes
+    start on a tile boundary, then (with `bucket`) the totals and the
+    nnz are padded to shape buckets, and (always) each direction's tile
+    count to its capacity (_tile_capacity).  The padding is
+    value-neutral, as _pad_for_buckets' is.  So the shapes
     depend on the instances and not on their order, and an instance's
     tiles are the same alone or stacked."""
     plans = [_tile_plan_cached(lp) for lp in lps]
@@ -1805,9 +1508,9 @@ def _tile_layout(lps: list[StructuredLP], g: StructuredLP, bucket: bool
     m_eq = dim(sum(p.m_eq_a for p in plans))
     m = m_eq + dim(sum(p.m_ub_a for p in plans))
     nnz = _bucket(len(g.val)) if bucket else len(g.val)
-    tiles = (sum(p.kx.tiles for p in plans), sum(p.kty.tiles for p in plans))
-    if bucket:
-        tiles = _tile_capacity((n, m_eq, m, nnz), tiles)
+    tiles = _tile_capacity((n, m_eq, m, nnz),
+                           (sum(p.kx.tiles for p in plans),
+                            sum(p.kty.tiles for p in plans)))
     st = tile_spmv.stack(plans, n, m_eq, m, nnz, *tiles)
     c, xmax, q = np.zeros(n), np.zeros(n), np.zeros(m)
     c[st.pos_n], xmax[st.pos_n] = g.c, g.xmax
@@ -1835,23 +1538,98 @@ def _tile_capacity(dims: tuple, tiles: tuple[int, int]) -> tuple[int, int]:
 
 
 def _tiled_spmv() -> bool:
-    """Whether the XLA backend's adaptive dispatches apply K as dense
-    tiles (core.tile_spmv).  On a TPU every scalar gather and
-    scatter-add of the COO pair costs a fixed time, so they do; on a
-    CPU, whose scatter is cheap, the COO pair stays, bit for bit."""
+    """Whether single-device dispatches apply K as dense tiles
+    (core.tile_spmv).  On a TPU every scalar gather and scatter-add of
+    the COO pair costs a fixed time, so they do; on a CPU, whose scatter
+    is cheap, the COO pair stays, bit for bit."""
     return jax.default_backend() == "tpu"
+
+
+def _stage_xla(lps: list[StructuredLP], bs: BlockStackedLP, *,
+               bucket: bool, adaptive: bool = False, chunk: int = 0,
+               tols: np.ndarray | None = None) -> _Staged:
+    """Stage one single-device dispatch of `lps`, block-stacked as `bs`:
+    lay it out and upload it.  K is applied as dense tiles exactly when
+    _tiled_spmv() holds, else as COO scatters.  With `bucket` the dims
+    (and, adaptive, the instance count) are padded to shape buckets, so
+    nearby shapes share one compiled program; the padding is
+    value-neutral (see _pad_for_buckets).  The tile counts are padded
+    either way (_tile_capacity).
+
+    With `adaptive`, a run is the fused loop of _pdhg_run_adaptive
+    (chunks of `chunk`, instance i frozen once its residual is within
+    `tols[i]`; primal and gap None), else one fixed burst of
+    _pdhg_resume (the kernel's primal and gap)."""
+    g = bs.lp
+    B = len(lps)
+    # `pos_n` / `pos_m` say where each column and row of `g` went
+    if _tiled_spmv():
+        gp, st = _tile_layout(lps, g, bucket)
+        pos_n, pos_m = st.pos_n, st.pos_m
+        tiles = tuple(jnp.asarray(a) for a in st.arrays())
+        t_kx, t_kty = st.kx.tiles, st.kty.tiles
+    else:
+        gp, (n_t, meq_t, mub_t) = (
+            _pad_for_buckets(g) if bucket
+            else (g, (g.n, g.m_eq, g.m - g.m_eq)))
+        pos_n = np.arange(n_t)
+        pos_m = np.concatenate([np.arange(meq_t),
+                                gp.m_eq + np.arange(mub_t)])
+        tiles, t_kx, t_kty = None, 0, 0
+    args = (jnp.asarray(gp.c), jnp.asarray(gp.row),
+            jnp.asarray(gp.col), jnp.asarray(gp.val),
+            jnp.asarray(gp.b), jnp.asarray(gp.h),
+            jnp.asarray(gp.xmax))
+    dims = (gp.m, gp.n, gp.m_eq)
+    shape = (gp.n, gp.m, gp.m_eq, len(gp.val), t_kx, t_kty)
+    tile_slots = (t_kx + t_kty) * tile_spmv.SLOTS // 2
+
+    def place(x0, y0):
+        x0p, y0p = np.zeros(gp.n), np.zeros(gp.m)
+        x0p[pos_n], y0p[pos_m] = x0, y0
+        return jnp.asarray(x0p), jnp.asarray(y0p)
+
+    def unpad(x, y):
+        return np.asarray(x)[pos_n], np.asarray(y)[pos_m]
+
+    if not adaptive:
+        def run(x, y, budget):
+            x, y, primal, gap = _pdhg_resume(*args, x, y, *dims, budget,
+                                             tiles)
+            return x, y, np.full(B, budget), primal, gap
+        return _Staged(place, run, unpad, ("fixed",) + shape,
+                       len(gp.val), tile_slots)
+
+    # padded coords go to the dump segment num_b; fake instances
+    # (instance-count bucketing) have no rows and tol=inf, so they
+    # freeze at the first residual check
+    num_b = (1 << max(B - 1, 0).bit_length()) if bucket else B
+    inst_n = np.full(gp.n, num_b, np.int32)
+    inst_n[pos_n] = np.repeat(np.arange(B), np.diff(bs.n_off))
+    inst_m = np.full(gp.m, num_b, np.int32)
+    inst_m[pos_m] = np.concatenate(
+        [np.repeat(np.arange(B), np.diff(bs.eq_off)),
+         np.repeat(np.arange(B), np.diff(bs.ub_off))])
+    tols_d = jnp.asarray(np.concatenate([tols, np.full(num_b - B, np.inf)]))
+    inst_n_d, inst_m_d = jnp.asarray(inst_n), jnp.asarray(inst_m)
+
+    def run(x, y, budget):
+        x, y, _, used_chunks = _pdhg_run_adaptive(
+            *args, x, y, tols_d, inst_n_d, inst_m_d, num_b,
+            *dims, chunk, budget // chunk, tiles)
+        return x, y, np.asarray(used_chunks)[:B] * chunk, None, None
+    return _Staged(place, run, unpad, ("adaptive", chunk, num_b) + shape,
+                   len(gp.val), tile_slots)
 
 
 def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                    tol: float | None = None, max_restarts: int = 3,
                    adaptive: bool = True, chunk: int = 500,
                    warm_starts: list[tuple[np.ndarray, np.ndarray]] | None
-                   = None, backend: str = "xla",
-                   bucket: bool = True, shards: int = 1,
-                   precision: str = "fp32") -> list[PDHGResult]:
+                   = None, bucket: bool = True,
+                   shards: int = 1) -> list[PDHGResult]:
     """Solve a batch of LPs over the instance axis in one jitted PDHG
-    dispatch (block-diagonal stacking; see BlockStackedLP for why this
-    beats a literal vmap on CPU).
+    dispatch (block-diagonal stacking; see BlockStackedLP).
 
     Both modes run an escalation ladder that re-stacks only the
     still-unconverged instances each level, so every instance follows
@@ -1875,16 +1653,12 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
     jax build, and independent of batch composition up to the float
     reduction order of the stacked scatters.
 
-    `backend="pallas"` runs every dispatch as fused blocked-ELL Pallas
-    bursts (repro.kernels.pdhg_spmv) instead of COO scatters — identical
-    escalation/freezing semantics, fp-level trajectory differences only;
-    the default "xla" path is untouched.
-
-    `bucket=True` (default, xla backend) pads every stacked dispatch's
-    (n, m_eq, m_ub, nnz) — and the instance count — up to shape-bucket
-    boundaries (_bucket: 4-bit-mantissa grid, <~14% padding waste), so
-    grid cells and arrival epochs with nearby shapes reuse one compiled
-    executable instead of recompiling per exact shape.  The padding is
+    Every dispatch is staged by _stage_xla, which picks the operator.
+    `bucket=True` (default) pads every stacked dispatch's (n, m_eq,
+    m_ub, nnz) — and the instance count — up to shape-bucket boundaries
+    (_bucket: 4-bit-mantissa grid, <~14% padding waste), so grid cells
+    and arrival epochs with nearby shapes reuse one compiled executable
+    instead of recompiling per exact shape.  The padding is
     value-neutral (see _pad_for_buckets), so results match the
     unbucketed dispatch to fp reduction order; `bucket=False` restores
     exact-shape dispatches.
@@ -1892,162 +1666,13 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
     `shards` > 1 row-partitions each stacked dispatch across that many
     devices and runs fixed sharded bursts (no in-dispatch adaptive loop —
     the outer re-stacking ladder plus the host-side per-instance
-    residual check provides the convergence control); `precision="bf16"`
-    stores iterates in bfloat16 between iterations.  Both require
-    backend="pallas" (see solve_lp)."""
-    _check_backend(backend)
-    _check_scale_opts(backend, shards, precision)
-    tiled = backend == "xla" and adaptive and _tiled_spmv()
+    residual check provides the convergence control; see solve_lp)."""
+    _check_shards(shards)
     B = len(lps)
     all_tols = np.array([tol if tol is not None
                          else 1e-4 * max(float(np.abs(lp.b).max(initial=0.0)),
                                          1.0)
                          for lp in lps])
-
-    def _stage_pallas(g: StructuredLP, bs: BlockStackedLP, x0, y0,
-                      sub: list[int], budget: int):
-        """Stage the stacked dispatch for the Pallas kernels: pack the
-        stacked LP into blocked-ELL once per dispatch shape and upload
-        it.  Returns a function that runs the fused adaptive loop (or
-        one fixed burst) via repro.kernels and brings (x, y, iterations
-        per instance) to the host, and the entries one SpMV direction
-        stores (the mean of the two directions' padded tables)."""
-        from repro.kernels import ops as kops
-
-        if shards > 1:
-            # sharded dispatch: fixed bursts over the row-partitioned
-            # operator; the outer ladder's host-side residual check and
-            # re-stacking supply the adaptive control
-            from repro.runtime.sharding import solver_mesh
-
-            mesh = solver_mesh(shards)
-            op, vecs, ell = _pack_pallas_sharded(
-                g.c, g.row, g.col, g.val, g.b, g.h, g.xmax, g.m_eq, shards)
-            _note_dispatch(("pallas-sharded", shards, precision, budget,
-                            op.n_pad, op.m_pad, len(sub)))
-            x0p = jnp.pad(x0.astype(jnp.float32), (0, op.n_pad - g.n))
-            y0p = jnp.pad(y0.astype(jnp.float32), (0, op.m_pad - g.m))
-
-            def launch():
-                x, y, _ = kops.pdhg_burst_sharded(
-                    mesh, *vecs, jnp.zeros(op.n_pad, bool),
-                    jnp.zeros(op.m_pad, bool), *ell, x0p, y0p,
-                    row_meta=op.row_meta, col_meta=op.col_meta,
-                    iters=budget, precision=precision)
-                return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
-                        np.full(len(sub), budget))
-            return launch, (op.row_idx.size + op.col_idx.size) // 2, 0
-
-        op, vecs, ell = _pack_pallas(g.c, g.row, g.col, g.val, g.b, g.h,
-                                     g.xmax, g.m_eq)
-        # the blocked-ELL packer's padded grid is the compile key here
-        _note_dispatch(("pallas", adaptive, chunk if adaptive else 0,
-                        budget, op.n_pad, op.m_pad, len(sub)))
-        x0p = jnp.pad(x0.astype(jnp.float32), (0, op.n_pad - g.n))
-        y0p = jnp.pad(y0.astype(jnp.float32), (0, op.m_pad - g.m))
-        if adaptive:
-            # storage coordinate -> instance id; padded slots go to the
-            # dump segment len(sub) (always treated as frozen/converged)
-            inst_n = np.full(op.n_pad, len(sub), np.int32)
-            inst_n[:g.n] = np.repeat(np.arange(len(sub)), np.diff(bs.n_off))
-            inst_m = np.full(op.m_pad, len(sub), np.int32)
-            inst_m[:g.m] = np.concatenate(
-                [np.repeat(np.arange(len(sub)), np.diff(bs.eq_off)),
-                 np.repeat(np.arange(len(sub)), np.diff(bs.ub_off))])
-            tols_d = jnp.asarray(all_tols[sub])
-            inst_n_d, inst_m_d = jnp.asarray(inst_n), jnp.asarray(inst_m)
-
-            def launch():
-                x, y, _, used_chunks = kops.pdhg_adaptive(
-                    *vecs, *ell, x0p, y0p, tols_d, inst_n_d, inst_m_d,
-                    num_inst=len(sub), row_meta=op.rows.meta,
-                    col_meta=op.cols.meta, chunk=chunk,
-                    max_chunks=budget // chunk, precision=precision)
-                return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
-                        np.asarray(used_chunks) * chunk)
-        else:
-            def launch():
-                x, y, _ = kops.pdhg_burst(
-                    *vecs, jnp.zeros(op.n_pad, bool),
-                    jnp.zeros(op.m_pad, bool), *ell, x0p, y0p,
-                    row_meta=op.rows.meta, col_meta=op.cols.meta,
-                    iters=budget, precision=precision)
-                return (np.asarray(x)[:g.n], np.asarray(y)[:g.m],
-                        np.full(len(sub), budget))
-        return launch, (op.rows.idx.size + op.cols.idx.size) // 2, 0
-
-    def _stage_xla(g: StructuredLP, bs: BlockStackedLP, x0, y0,
-                   sub: list[int], budget: int):
-        """Stage the stacked dispatch for the XLA kernels: lay it out
-        and upload.  Returns a function that runs the jitted PDHG and
-        brings (x, y, iterations per instance) to the host, in the
-        coordinates of `g`, the dispatched nnz, and a direction's tile
-        entries (0 for the COO operator)."""
-        # shape bucketing: pad the stacked dims (and the instance
-        # count) up to bucket boundaries so the jitted kernels are
-        # compiled per bucket, not per exact shape — the padding is
-        # value-neutral (see _pad_for_buckets), so trajectories
-        # match the unbucketed dispatch.  `pos_n` / `pos_m` say where
-        # each column and row of `g` went.
-        B_sub = len(sub)
-        if tiled:
-            gp, st = _tile_layout([lps[i] for i in sub], g, bucket)
-            pos_n, pos_m = st.pos_n, st.pos_m
-            tiles = tuple(jnp.asarray(a) for a in st.arrays())
-            t_kx, t_kty = st.kx.tiles, st.kty.tiles
-        else:
-            gp, (n_t, meq_t, mub_t) = (
-                _pad_for_buckets(g) if bucket
-                else (g, (g.n, g.m_eq, g.m - g.m_eq)))
-            pos_n = np.arange(n_t)
-            pos_m = np.concatenate([np.arange(meq_t),
-                                    gp.m_eq + np.arange(mub_t)])
-            tiles, t_kx, t_kty = None, 0, 0
-        x0p, y0p = np.zeros(gp.n), np.zeros(gp.m)
-        x0p[pos_n], y0p[pos_m] = x0, y0
-        x0p, y0p = jnp.asarray(x0p), jnp.asarray(y0p)
-        args = (jnp.asarray(gp.c), jnp.asarray(gp.row),
-                jnp.asarray(gp.col), jnp.asarray(gp.val),
-                jnp.asarray(gp.b), jnp.asarray(gp.h),
-                jnp.asarray(gp.xmax))
-
-        def unpad(x, y):
-            return np.asarray(x)[pos_n], np.asarray(y)[pos_m]
-
-        if adaptive:
-            # padded coords go to the dump segment num_b; fake
-            # instances (instance-count bucketing) have no rows and
-            # tol=inf, so they freeze at the first residual check
-            num_b = ((1 << max(B_sub - 1, 0).bit_length()) if bucket
-                     else B_sub)
-            inst_n = np.full(gp.n, num_b, np.int32)
-            inst_n[pos_n] = np.repeat(np.arange(B_sub), np.diff(bs.n_off))
-            inst_m = np.full(gp.m, num_b, np.int32)
-            inst_m[pos_m] = np.concatenate(
-                [np.repeat(np.arange(B_sub), np.diff(bs.eq_off)),
-                 np.repeat(np.arange(B_sub), np.diff(bs.ub_off))])
-            tols_sub = np.concatenate(
-                [all_tols[sub], np.full(num_b - B_sub, np.inf)])
-            _note_dispatch(("xla", True, chunk, budget, gp.n, gp.m,
-                            gp.m_eq, len(gp.val), num_b, t_kx, t_kty))
-            tols_d = jnp.asarray(tols_sub)
-            inst_n_d, inst_m_d = jnp.asarray(inst_n), jnp.asarray(inst_m)
-
-            def launch():
-                x, y, _, used_chunks = _pdhg_run_adaptive(
-                    *args, x0p, y0p, tols_d, inst_n_d, inst_m_d, num_b,
-                    gp.m, gp.n, gp.m_eq, chunk, budget // chunk, tiles)
-                used = np.asarray(used_chunks)[:B_sub] * chunk
-                return *unpad(x, y), used
-        else:
-            _note_dispatch(("xla", False, 0, budget, gp.n, gp.m,
-                            gp.m_eq, len(gp.val)))
-
-            def launch():
-                x, y, _, _ = _pdhg_resume(*args, x0p, y0p, gp.m, gp.n,
-                                          gp.m_eq, budget)
-                return *unpad(x, y), np.full(B_sub, budget)
-        return launch, len(gp.val), (t_kx + t_kty) * tile_spmv.SLOTS // 2
 
     def _run(sub: list[int], states, budget: int):
         """One stacked dispatch over the instances in `sub`; returns
@@ -2062,11 +1687,16 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
                 y0 = np.concatenate(
                     [states[i][1][:lps[i].m_eq] for i in sub]
                     + [states[i][1][lps[i].m_eq:] for i in sub])
-            stage = _stage_pallas if backend == "pallas" else _stage_xla
-            launch, nnz, tile_slots = stage(g, bs, x0, y0, sub, budget)
+            st = (_stage_sharded(g, shards, len(sub)) if shards > 1
+                  else _stage_xla([lps[i] for i in sub], bs, bucket=bucket,
+                                  adaptive=adaptive, chunk=chunk,
+                                  tols=all_tols[sub]))
+            _note_dispatch(st.key + (budget,))
         with trace.span("pdhg.run"):
-            x_np, y_np, used = launch()
-        _note_work(used, nnz, [len(lps[i].val) for i in sub], tile_slots)
+            x, y, used = st.run(*st.place(x0, y0), budget)[:3]
+            x_np, y_np = st.unpad(x, y)
+        _note_work(used, st.nnz, [len(lps[i].val) for i in sub],
+                   st.tile_slots)
         with trace.span("pdhg.unstack"):
             res = _per_instance_residuals(bs, x_np)
             outs = {}
@@ -2128,26 +1758,18 @@ def solve_lp_batch(lps: list[StructuredLP], iters: int = 4000, *,
         spent += budget
         budget *= 2
 
-    out = []
-    for i, lp in enumerate(lps):
-        xi = x_fin[i]
-        obj = float(lp.c @ xi)
-        # per-instance gap proxy mirrors the kernel's (|c.x + q.y| form)
-        qi = np.concatenate([lp.b, lp.h])
-        cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
-        objn = obj / cscale
-        gap = abs(objn + float(qi @ y_fin[i])) / (1.0 + abs(objn))
-        out.append(PDHGResult(xi, float(res_fin[i]), gap, int(iters_fin[i]),
-                              y=y_fin[i]))
-    return out
+    # the per-instance gap proxy mirrors the kernel's (|c.x + q.y| form)
+    return [PDHGResult(x_fin[i], float(res_fin[i]),
+                       _gap(lp, x_fin[i], y_fin[i]), int(iters_fin[i]),
+                       y=y_fin[i])
+            for i, lp in enumerate(lps)]
 
 
 def solve_fast_batch(problems: list[ScheduleProblem],
                      objective: str = "energy", *,
                      iters: int = 4000, tol: float | None = None,
-                     adaptive: bool = True, backend: str = "xla",
-                     bucket: bool = True, shards: int = 1,
-                     precision: str = "fp32") -> list[FastPathResult]:
+                     adaptive: bool = True, bucket: bool = True,
+                     shards: int = 1) -> list[FastPathResult]:
     """Batched fast path over ScheduleProblems sharing one topology.
 
     The routing LPs (which differ per instance through task placement and
@@ -2174,9 +1796,8 @@ def solve_fast_batch(problems: list[ScheduleProblem],
             raise ValueError("solve_fast_batch requires a shared topology "
                              f"structure; got {t0.name} and {t.name}")
     return solve_fast_ensemble(problems, objective, iters=iters, tol=tol,
-                               adaptive=adaptive, chunk=500, backend=backend,
-                               bucket=bucket, shards=shards,
-                               precision=precision)
+                               adaptive=adaptive, chunk=500,
+                               bucket=bucket, shards=shards)
 
 
 # ---------------------------------------------------------------------------
@@ -2361,8 +1982,7 @@ def stranded_volume(warm: FastPathResult, p_dst: ScheduleProblem, *,
 def resolve_incremental(p: ScheduleProblem, objective: str,
                         warm: FastPathResult, *, iters: int = 4000,
                         tol: float | None = None,
-                        backend: str = "xla", shards: int = 1,
-                        precision: str = "fp32") -> FastPathResult:
+                        shards: int = 1) -> FastPathResult:
     """Re-solve a degraded instance starting from a healthy solution.
 
     `p` is the degraded problem (same coflow/flow indexing as the healthy
@@ -2374,8 +1994,7 @@ def resolve_incremental(p: ScheduleProblem, objective: str,
     itself warm-start further re-solves (cascading failures)."""
     lp, idx = build_routing_lp(p, objective)
     x0, y0 = project_warm_start(warm, p, lp, idx)
-    res = solve_lp(lp, iters=iters, tol=tol, x0=x0, y0=y0, backend=backend,
-                   shards=shards, precision=precision)
+    res = solve_lp(lp, iters=iters, tol=tol, x0=x0, y0=y0, shards=shards)
     return _assemble_fast_result(p, lp, idx, res)
 
 
@@ -2384,9 +2003,8 @@ def solve_fast_warm(p: ScheduleProblem, objective: str = "energy", *,
                     warm: FastPathResult | None = None,
                     flow_map: np.ndarray | None = None,
                     iters: int = 4000, tol: float | None = None,
-                    chunk: int = 250, backend: str = "xla",
-                    bucket: bool = True, shards: int = 1,
-                    precision: str = "fp32") -> FastPathResult:
+                    chunk: int = 250, bucket: bool = True,
+                    shards: int = 1) -> FastPathResult:
     """Single-instance fast path with an optional projected warm start and
     the fused adaptive convergence loop.
 
@@ -2404,7 +2022,6 @@ def solve_fast_warm(p: ScheduleProblem, objective: str = "energy", *,
     its topology shape differs from `p`'s (different edge/wavelength
     indexing — the projection would be meaningless), or the projection
     itself fails, the solve silently starts from zero."""
-    _check_backend(backend)
     lp, idx = build_routing_lp(p, objective)
     warm_starts = None
     if (warm is not None and warm.index is not None
@@ -2417,9 +2034,8 @@ def solve_fast_warm(p: ScheduleProblem, objective: str = "energy", *,
         except (ValueError, KeyError, IndexError):
             warm_starts = None         # structure changed -> cold start
     res = solve_lp_batch([lp], iters=iters, tol=tol, chunk=chunk,
-                         warm_starts=warm_starts, backend=backend,
-                         bucket=bucket, shards=shards,
-                         precision=precision)[0]
+                         warm_starts=warm_starts, bucket=bucket,
+                         shards=shards)[0]
     out = _assemble_fast_result(p, lp, idx, res)
     out.warm_started = warm_starts is not None
     return out
@@ -2431,9 +2047,8 @@ def solve_fast_ensemble(problems: list[ScheduleProblem],
                         warm: list[FastPathResult] | None = None,
                         iters: int = 4000, tol: float | None = None,
                         adaptive: bool = True, chunk: int | None = None,
-                        backend: str = "xla",
-                        bucket: bool = True, shards: int = 1,
-                        precision: str = "fp32") -> list[FastPathResult]:
+                        bucket: bool = True,
+                        shards: int = 1) -> list[FastPathResult]:
     """Batched fast path over a (possibly heterogeneous) instance list.
 
     Unlike solve_fast_batch this does not require a shared topology —
@@ -2461,8 +2076,7 @@ def solve_fast_ensemble(problems: list[ScheduleProblem],
         chunk = 250 if warm_starts is not None else 500
     results = solve_lp_batch(lps, iters=iters, tol=tol, adaptive=adaptive,
                              chunk=chunk, warm_starts=warm_starts,
-                             backend=backend, bucket=bucket, shards=shards,
-                             precision=precision)
+                             bucket=bucket, shards=shards)
     return [_assemble_fast_result(p, lp, idx, res)
             for p, (lp, idx), res in zip(problems, built, results)]
 
@@ -2474,9 +2088,8 @@ def solve_fast_group(problems: list[ScheduleProblem],
                      flow_maps: list[np.ndarray | None] | None = None,
                      iters: int = 4000, tol: float | None = None,
                      adaptive: bool = True, chunk: int = 250,
-                     backend: str = "xla",
-                     bucket: bool = True, shards: int = 1,
-                     precision: str = "fp32") -> list[FastPathResult]:
+                     bucket: bool = True,
+                     shards: int = 1) -> list[FastPathResult]:
     """One stacked dispatch over a heterogeneous tenant group.
 
     The coalescing primitive of the multi-tenant scheduler service
@@ -2501,7 +2114,6 @@ def solve_fast_group(problems: list[ScheduleProblem],
     correctness test pins this at 1e-4 relative).  Degenerate members
     (zero flows) solve in closed form inside solve_lp_batch and never
     widen the dispatch."""
-    _check_backend(backend)
     if not problems:
         return []
     B = len(problems)
@@ -2533,8 +2145,7 @@ def solve_fast_group(problems: list[ScheduleProblem],
     results = solve_lp_batch(lps, iters=iters, tol=tol, adaptive=adaptive,
                              chunk=chunk,
                              warm_starts=starts if any(flags) else None,
-                             backend=backend, bucket=bucket, shards=shards,
-                             precision=precision)
+                             bucket=bucket, shards=shards)
     out = []
     for (p, (lp, idx), res, f) in zip(problems, built, results, flags):
         r = _assemble_fast_result(p, lp, idx, res)

@@ -1,6 +1,6 @@
 """Block-sparse tile operator for PDHG's K.x and K^T.y.
 
-The COO pair of `solver._pdhg_ops` does one scalar gather and one scalar
+The COO pair of `core.pdhg` does one scalar gather and one scalar
 scatter-add per nonzero and direction.  On a TPU each of those indexed
 moves costs about the same whatever it carries, so the scatter path runs
 at a fixed cost per nonzero, far from the chip's byte bound.  This

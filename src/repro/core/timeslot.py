@@ -12,7 +12,7 @@ A schedule is a pair of tensors
     x[f, e, w, t]  - Gbits of flow f carried on directed edge e,
                      wavelength w, during slot t
     (delta[f, t] = net injection is implied: sum of x out of src_f)
-so both solver backends (core.oracle exact MILP, core.solver JAX fast
+so both solvers (core.oracle exact MILP, core.solver JAX fast
 path) and any heuristic can be scored identically.
 """
 from __future__ import annotations
@@ -265,8 +265,8 @@ def evaluate(p: ScheduleProblem, x: np.ndarray) -> Metrics:
     `x` has shape (F, E, W, T) in Gbits; returns energy in Joules
     (eqs. 19-22), completion time in seconds (eqs. 39-45), and the worst
     constraint violation in Gbits (feasible iff <= 1e-4).  Pure numpy,
-    deterministic, and backend-independent — this is the single source
-    of truth both solver backends and all sweeps report through."""
+    deterministic, and platform-independent — this is the single
+    source of truth both solvers and all sweeps report through."""
     F, E, W, T = p.shape_x
     assert x.shape == (F, E, W, T), (x.shape, p.shape_x)
     D = p.topo.slot_duration

@@ -3,7 +3,7 @@
 Each topology is a directed multigraph over *devices* (servers, switches,
 OLT ports, polymer backplanes, AWGR ports) with per-wavelength link
 capacities.  The schema is deliberately uniform so the time-slotted
-scheduler (core.timeslot) and both solver backends operate on any of the
+scheduler (core.timeslot) and both solvers operate on any of the
 six paper DCNs or the TPU fabric (core.fabric) unchanged.
 
 Paper parameters (Tables II & III):

@@ -2,8 +2,8 @@
 
   flash_attention - online-softmax attention; GQA, causal/SWA, softcap
   rglru_scan      - RG-LRU linear recurrence (VMEM-resident sequential dim)
-  pdhg_spmv       - blocked-ELL SpMV + fused PDHG iteration burst (the
-                    core.solver backend="pallas" hot loop)
+  pdhg_spmv       - blocked-ELL SpMV, the row-sharded PDHG's operator
+                    pair (core.pdhg runs the update; no Pallas kernel)
   ops             - jit'd public wrappers (layout, padding, block sizes,
                     and the only interpret-mode decision)
   ref             - pure-jnp oracles for allclose validation

@@ -39,28 +39,3 @@ def rglru_ref(a, b):
         return (l[0] * r[0], r[0] * l[1] + r[1])
     _, h = jax.lax.associative_scan(combine, (a, b), axis=1)
     return h
-
-
-def ell_spmv(vec, blocks):
-    """Blocked-ELL SpMV oracle: y[i] = sum_j val[i,j] * vec[idx[i,j]].
-
-    `blocks` is a kernels.pdhg_spmv.EllBlocks; returns (n_rows_pad,)."""
-    from . import pdhg_spmv
-    o, w, bm, p = blocks.meta
-    return pdhg_spmv.spmv_blocks(jnp.asarray(vec), jnp.asarray(blocks.idx),
-                                 jnp.asarray(blocks.val),
-                                 offsets=o, widths=w, bm=bm, n_rows_pad=p)
-
-
-def pdhg_ell_burst_ref(c, tau, xmax, q, sig, ub, keep_n, keep_m,
-                       row_idx, row_val, col_idx, col_val, x0, y0, *,
-                       row_meta, col_meta, iters):
-    """Pure-jnp oracle for kernels.pdhg_spmv.pdhg_burst: the *same*
-    shared update body (pdhg_spmv.pdhg_update_burst) run as plain traced
-    ops with no pallas_call around it, so kernel-vs-oracle differences
-    can only come from Pallas lowering."""
-    from . import pdhg_spmv
-    return pdhg_spmv.pdhg_update_burst(
-        x0, y0, c, tau, xmax, q, sig, ub, keep_n, keep_m,
-        row_idx, row_val, col_idx, col_val,
-        row_meta=row_meta, col_meta=col_meta, iters=iters)

@@ -57,7 +57,6 @@ class SearchConfig:
     seed: int = 0
     iters: int = 1500          # PDHG iterations per evaluator dispatch
     tol: float = 2e-3
-    backend: str = "xla"
     rho: float = 8.0
     path_slack: int | None = 2
     n_slots: int | None = None  # None: pin max(seed-generation suggestions)
@@ -73,9 +72,6 @@ class SearchConfig:
         if self.generations < 0 or self.population < 1:
             raise ValueError(f"need generations >= 0 and population >= 1, "
                              f"got {self.generations}, {self.population}")
-        if self.backend not in solver.BACKENDS:
-            raise ValueError(f"unknown backend {self.backend!r}; "
-                             f"have {solver.BACKENDS}")
         if not 0 < self.alpha <= 1 or self.t0_frac <= 0:
             raise ValueError("need 0 < alpha <= 1 and t0_frac > 0")
         if self.elite < 0 or self.elite >= max(self.population, 1) + 3:
@@ -130,7 +126,7 @@ def evaluate_placements(topo: Topology, pat: TrafficPattern,
             topo, cf, n_slots=n_slots, rho=cfg.rho,
             path_slack=cfg.path_slack))
     results = solver.solve_fast_batch(problems, objective, iters=cfg.iters,
-                                      tol=cfg.tol, backend=cfg.backend)
+                                      tol=cfg.tol)
     return [Candidate(pl, p, r, _score(objective, r))
             for pl, p, r in zip(placements, problems, results)]
 
@@ -143,8 +139,7 @@ def _retry(c: Candidate, objective: str, cfg: SearchConfig) -> Candidate:
         p = timeslot.rehorizon(p, 2 * p.n_slots,
                                path_slack=p.path_slack if tries == 0
                                else None)
-        r = solver.solve_fast(p, objective, iters=cfg.iters, tol=cfg.tol,
-                              backend=cfg.backend)
+        r = solver.solve_fast(p, objective, iters=cfg.iters, tol=cfg.tol)
         tries += 1
     return Candidate(c.placement, p, r, _score(objective, r))
 

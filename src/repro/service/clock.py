@@ -55,7 +55,7 @@ class SolveCostModel:
         cost_s = base_s + per_iteration_s * iters + per_instance_s * B
 
     — where `iters` is the PDHG iterations the dispatch actually spent
-    (deterministic for a fixed jax build/backend) and `B` its member
+    (deterministic for a fixed jax build/platform) and `B` its member
     count.  `base_s` models the fixed dispatch overhead (trace, device
     launch) that coalescing amortizes across tenants; `per_instance_s`
     the per-member LP assembly/unpack work that it cannot.

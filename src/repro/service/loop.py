@@ -40,7 +40,7 @@ Every timestamp flows through the injectable VirtualClock and (in the
 default "iterations" cost mode) every control-plane cost is a
 deterministic function of solver iteration counts, so two runs with
 identical specs produce byte-identical event logs — the replay
-property tests/test_service.py pins on both backends.
+property tests/test_service.py pins on both operators.
 
 Units follow the paper: Gbits, Gbps, seconds, Joules.
 """
@@ -105,7 +105,6 @@ class ServiceConfig:
     iters: int = 3000               # per-window PDHG budget (first rung)
     tol: float | None = 2e-3
     chunk: int = 250
-    backend: str = "xla"
     coalesce: bool = True           # False: one dispatch per tenant
     bucket: bool = True
     warm: bool = True
@@ -193,7 +192,7 @@ class ServiceResult:
         """The canonical event log: one line per event, in order.
 
         Deterministic byte-for-byte for fixed (specs, config, jax
-        build, backend) under the "iterations" cost model."""
+        build, platform) under the "iterations" cost model."""
         return "\n".join(e.line for e in self.events)
 
     @property
@@ -310,7 +309,6 @@ def run_service(tenants: list[TenantSpec],
     when it trips is reported as backlog, never silently dropped."""
     if not tenants:
         raise ValueError("need at least one tenant")
-    solver._check_backend(config.backend)
     fallback = (policy_zoo.get(config.fallback_policy)
                 if config.fallback_policy else None)
     clock = clock or VirtualClock()
@@ -567,7 +565,7 @@ def run_service(tenants: list[TenantSpec],
                 results = solver.solve_fast_group(
                     probs, objs, warm=warms, flow_maps=maps,
                     iters=config.iters, tol=config.tol, chunk=config.chunk,
-                    backend=config.backend, bucket=config.bucket)
+                    bucket=config.bucket)
                 wall = time.perf_counter() - t0
                 spent = sum(r.iterations for r in results)
                 counters.dispatches += 1
@@ -588,7 +586,7 @@ def run_service(tenants: list[TenantSpec],
                         r = solver.solve_fast_warm(
                             m["p"], st.spec.objective, iters=config.iters,
                             tol=config.tol, chunk=config.chunk,
-                            backend=config.backend, bucket=config.bucket)
+                            bucket=config.bucket)
                         wall += time.perf_counter() - t1
                         spent += r.iterations
                         tries += 1
@@ -608,8 +606,7 @@ def run_service(tenants: list[TenantSpec],
                         # and drains the demand
                         p_fb = rehorizon(m["p"], 2 * m["p"].n_slots)
                         t1 = time.perf_counter()
-                        fb = fallback.solve(p_fb, st.spec.objective,
-                                            backend=config.backend)
+                        fb = fallback.solve(p_fb, st.spec.objective)
                         wall += time.perf_counter() - t1
                         if (fb.metrics.feasible
                                 and fb.remaining_gbits <= 1e-6):

@@ -18,7 +18,7 @@ import pathlib
 import time
 
 from repro import compile_cache, search
-from repro.core import arrivals, failures, solver, topology, traffic
+from repro.core import arrivals, failures, topology, traffic
 from repro.core import chaos as chaosmod
 from repro.core import policies as policy_zoo
 
@@ -64,8 +64,7 @@ def _run_service_smoke(args) -> int:
     chaos = (_csv_list(args.chaos, chaosmod.PRESETS, "chaos preset")
              if args.chaos else ())
     cfg = service.ServiceConfig(window_s=args.epoch_s or None,
-                                iters=args.iters, backend=args.backend,
-                                slo_p99_s=args.slo_s,
+                                iters=args.iters, slo_p99_s=args.slo_s,
                                 chaos=chaos, chaos_seed=args.chaos_seed)
     t0 = time.perf_counter()
     res = service.run_service(tenants, cfg)
@@ -172,21 +171,11 @@ def main(argv=None) -> int:
                     help="fixed slot count (default: auto per instance)")
     ap.add_argument("--iters", type=int, default=3000,
                     help="PDHG iterations before residual-driven restarts")
-    ap.add_argument("--backend", default="xla", choices=solver.BACKENDS,
-                    help="PDHG lowering: xla (COO scatters, default) or "
-                         "pallas (fused blocked-ELL kernel bursts; "
-                         "interpret mode on CPU; on a TPU only with "
-                         "--mesh > 1, see docs/KERNELS.md)")
     ap.add_argument("--mesh", type=int, default=1,
                     help="row-partition every PDHG dispatch across this "
-                         "many devices (pallas only; on CPU requires "
+                         "many devices (on CPU requires "
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N — see docs/SOLVER.md §9)")
-    ap.add_argument("--precision", default="fp32",
-                    choices=solver.PRECISIONS,
-                    help="PDHG iterate storage: fp32 (default) or bf16 "
-                         "(pallas only; arithmetic and residuals stay "
-                         "fp32 — see docs/SOLVER.md §9)")
     ap.add_argument("--oracle-check", type=int, default=2,
                     help="instances to spot-check against the exact MILP "
                          "(cheapest first; 0 disables)")
@@ -241,8 +230,7 @@ def main(argv=None) -> int:
         epoch_s=args.epoch_s or None,
         total_gbits=args.total_gbits, n_map=args.n_map,
         n_reduce=args.n_reduce, n_slots=args.slots or None,
-        iters=args.iters, backend=args.backend,
-        mesh=args.mesh, precision=args.precision,
+        iters=args.iters, mesh=args.mesh,
         oracle_check=args.oracle_check,
         oracle_time_limit=args.oracle_time_limit,
         profile=args.profile)

@@ -18,7 +18,7 @@ E columns are Joules from the activity-power accounting of eqs.
 term), M columns are seconds from the completion-time equations
 (39)-(45), volumes are Gbits and capacities Gbps (Tables II-III).
 Every number is core.timeslot.evaluate applied to the packed schedule
-— the same single source of truth both solver backends report through;
+— the same single source of truth both solvers report through;
 docs/REPRODUCING.md carries the field-by-field CSV glossary.
 """
 from __future__ import annotations

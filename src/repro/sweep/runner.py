@@ -85,17 +85,11 @@ class SweepSpec:
     # loose LP tolerance: the packed schedule is re-scored with the exact
     # paper model regardless, and packing is robust to ~1e-3 residuals
     tol: float = 2e-3
-    # PDHG lowering: "xla" (COO scatters, default) or "pallas" (fused
-    # blocked-ELL bursts, repro.kernels.pdhg_spmv); metrics agree to
-    # ~1e-4 relative — see docs/SOLVER.md "Backends"
-    backend: str = "xla"
-    # scale knobs (docs/SOLVER.md §9), both pallas-only: mesh > 1 row-
-    # partitions every PDHG dispatch across that many devices;
-    # precision="bf16" stores iterates in bfloat16 between iterations.
-    # Applied to the LP fast path (healthy + failure cells); baseline
-    # policies and rolling-horizon arrival runs stay single-device fp32.
+    # mesh > 1 row-partitions every PDHG dispatch across that many
+    # devices (docs/SOLVER.md §9).  Applied to the LP fast path (healthy
+    # + failure cells); baseline policies and rolling-horizon arrival
+    # runs stay single-device.
     mesh: int = 1
-    precision: str = "fp32"
     path_slack: int | None = 2        # near-shortest route pruning; None = off
     oracle_check: int = 0             # instances to spot-check vs the MILP
     oracle_time_limit: float = 60.0
@@ -123,11 +117,7 @@ class SweepSpec:
             if pt not in traffic.PATTERNS:
                 raise ValueError(f"unknown pattern {pt!r}; "
                                  f"have {sorted(traffic.PATTERNS)}")
-        if self.backend not in solver.BACKENDS:
-            raise ValueError(f"unknown solver backend {self.backend!r}; "
-                             f"have {solver.BACKENDS}")
-        # mesh/precision constraints (pallas-only) mirror the solver's
-        solver._check_scale_opts(self.backend, self.mesh, self.precision)
+        solver._check_shards(self.mesh)
         for fl in self.failures:
             if fl not in failures.SCENARIOS or fl == "none":
                 # "none" is rejected too: its records would carry
@@ -155,8 +145,7 @@ class SweepSpec:
             # fail before solving anything, not inside the search loop
             search.SearchConfig(
                 generations=self.placement_generations,
-                population=self.placement_population,
-                backend=self.backend).validate()
+                population=self.placement_population).validate()
 
 
 @dataclasses.dataclass
@@ -179,7 +168,6 @@ class SweepRecord:
     failure: str = "none"             # failure preset ("none" = healthy)
     degradation_ratio: float = 0.0    # fraction of aggregate Gbps lost
     survivability: float = 1.0        # served / offered Gbits
-    backend: str = "xla"              # PDHG lowering that produced this row
     # online-arrival rows (core.arrivals rolling-horizon driver);
     # arrivals == "none" marks an offline (one-shot) row
     arrivals: str = "none"            # arrival-process family
@@ -251,8 +239,6 @@ def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
     say(f"    profile {label}: build {build:7.1f} ms "
         f"(structure {d.structure_hits - snap.structure_hits} hit"
         f"/{d.structure_misses - snap.structure_misses} miss, "
-        f"ell {d.ell_hits - snap.ell_hits} hit"
-        f"/{d.ell_misses - snap.ell_misses} miss, "
         f"tiles {d.tile_hits - snap.tile_hits} hit"
         f"/{d.tile_misses - snap.tile_misses} miss) | "
         f"pdhg {pdhg:8.1f} ms (stack {ms('pdhg.stack'):.1f}, "
@@ -294,9 +280,7 @@ def _retry_unfinished(probs, results, internal_obj: str, spec: SweepSpec):
                 p, 2 * p.n_slots,
                 path_slack=p.path_slack if tries == 0 else None)
             r = solver.solve_fast(p, internal_obj, iters=spec.iters,
-                                  tol=spec.tol, backend=spec.backend,
-                                  shards=spec.mesh,
-                                  precision=spec.precision)
+                                  tol=spec.tol, shards=spec.mesh)
             tries += 1
         probs[i], results[i] = p, r
 
@@ -305,9 +289,7 @@ def _solve_group(probs, internal_obj: str, spec: SweepSpec):
     """Batched healthy solve + retry ladder; returns amortized wall time."""
     t0 = time.perf_counter()
     results = solver.solve_fast_batch(probs, internal_obj, iters=spec.iters,
-                                      tol=spec.tol, backend=spec.backend,
-                                      shards=spec.mesh,
-                                      precision=spec.precision)
+                                      tol=spec.tol, shards=spec.mesh)
     _retry_unfinished(probs, results, internal_obj, spec)
     return results, (time.perf_counter() - t0) / max(len(probs), 1)
 
@@ -323,9 +305,7 @@ def _solve_failure_group(healthy_probs, healthy_results, fail_name: str,
     results = solver.solve_fast_ensemble(probs, internal_obj,
                                          warm=healthy_results,
                                          iters=spec.iters, tol=spec.tol,
-                                         backend=spec.backend,
-                                         shards=spec.mesh,
-                                         precision=spec.precision)
+                                         shards=spec.mesh)
     _retry_unfinished(probs, results, internal_obj, spec)
     return probs, results, (time.perf_counter() - t0) / max(len(probs), 1)
 
@@ -341,16 +321,14 @@ def _solve_policy_group(probs, pol_name: str, internal_obj: str,
     pol = policy_zoo.get(pol_name)
     out_p, out_r = [], []
     for p in probs:
-        r = pol.solve(p, internal_obj, iters=spec.iters, tol=spec.tol,
-                      backend=spec.backend)
+        r = pol.solve(p, internal_obj, iters=spec.iters, tol=spec.tol)
         tries = 0
         while ((r.remaining_gbits > 1e-6 or not r.metrics.feasible)
                and tries < 2):
             p = timeslot.rehorizon(
                 p, 2 * p.n_slots,
                 path_slack=p.path_slack if tries == 0 else None)
-            r = pol.solve(p, internal_obj, iters=spec.iters, tol=spec.tol,
-                          backend=spec.backend)
+            r = pol.solve(p, internal_obj, iters=spec.iters, tol=spec.tol)
             tries += 1
         out_p.append(p)
         out_r.append(r)
@@ -389,8 +367,7 @@ def _policy_records(records, problems, spec: SweepSpec, say,
             if gap < 1.0 and i not in tight:
                 tight[i] = solver.solve_fast(
                     lp_p, OBJECTIVES[obj], tol=spec.tol,
-                    iters=max(8 * spec.iters, 24000),
-                    backend=spec.backend)
+                    iters=max(8 * spec.iters, 24000))
                 gap = policy_zoo.gap_vs_lp(OBJECTIVES[obj], pp,
                                            pr.schedule, lp_p, tight[i])
             gaps.append(gap)
@@ -398,7 +375,7 @@ def _policy_records(records, problems, spec: SweepSpec, say,
                 topo_name, obj, pat_name, seed, pp, pr, pol_s,
                 offered=off, failure=failure,
                 degradation_ratio=ratios[i] if ratios else 0.0,
-                backend=spec.backend, policy=pol_name, gap_vs_lp=gap))
+                policy=pol_name, gap_vs_lp=gap))
             problems.append(pp)
         tag = f"+{failure}" if failure != "none" else ""
         say(f"{topo_name:10s} {pat_name:8s} min-{obj:10s} "
@@ -424,8 +401,8 @@ def _placement_records(records, problems, spec: SweepSpec, say,
     cfg = search.SearchConfig(
         generations=spec.placement_generations,
         population=spec.placement_population,
-        iters=spec.iters, tol=spec.tol, backend=spec.backend,
-        rho=spec.rho, path_slack=spec.path_slack,
+        iters=spec.iters, tol=spec.tol, rho=spec.rho,
+        path_slack=spec.path_slack,
         n_slots=spec.n_slots)
     if not spec.seeds:
         return
@@ -444,8 +421,7 @@ def _placement_records(records, problems, spec: SweepSpec, say,
                    for kind, c in res.baselines.items()]):
             rec = _record(topo_name, obj, pat_label, seed, cand.problem,
                           cand.result, wall,
-                          offered=cand.problem.coflow.total_gbits,
-                          backend=spec.backend)
+                          offered=cand.problem.coflow.total_gbits)
             rec.placement_search = method
             rec.placement_gain = float(gain)
             records.append(rec)
@@ -470,13 +446,12 @@ def _solve_arrival_cell(topo, pat, fam: str, internal_obj: str,
     res = arrivals.run_online(topo, trace, internal_obj,
                               epoch_s=spec.epoch_s, rho=spec.rho,
                               path_slack=spec.path_slack, iters=spec.iters,
-                              tol=spec.tol, backend=spec.backend)
+                              tol=spec.tol)
     return trace, res, time.perf_counter() - t0
 
 
 def _arrival_record(topo_name, obj, pat_name, seed, fam: str,
-                    trace: list, res, wall_s: float,
-                    backend: str) -> SweepRecord:
+                    trace: list, res, wall_s: float) -> SweepRecord:
     """One SweepRecord summarizing a whole rolling-horizon trace.  The
     E/M columns hold the trace totals (executed-prefix energy summed
     over epochs, last co-flow completion); per-epoch LP provenance
@@ -498,7 +473,7 @@ def _arrival_record(topo_name, obj, pat_name, seed, fam: str,
         remaining_gbits=res.backlog_gbits,
         solve_s=wall_s / max(res.n_epochs, 1),
         survivability=(offered - res.backlog_gbits) / max(offered, 1e-12),
-        backend=backend, arrivals=fam, epochs=res.n_epochs,
+        arrivals=fam, epochs=res.n_epochs,
         # NaN (no co-flow finished) passes through: a 0.0 here would
         # make the worst possible run read as instant completion
         mean_response_s=res.mean_response_s,
@@ -522,18 +497,17 @@ def _solve_chaos_cell(topo, pat, preset: str, internal_obj: str,
     res = arrivals.run_online(topo, trace, internal_obj,
                               epoch_s=spec.epoch_s, rho=spec.rho,
                               path_slack=spec.path_slack, iters=spec.iters,
-                              tol=spec.tol, backend=spec.backend,
-                              chaos=events, fallback_policy="scf")
+                              tol=spec.tol, chaos=events,
+                              fallback_policy="scf")
     return trace, res, time.perf_counter() - t0
 
 
 def _chaos_record(topo_name, obj, pat_name, seed, preset: str,
-                  trace: list, res, wall_s: float,
-                  backend: str) -> SweepRecord:
+                  trace: list, res, wall_s: float) -> SweepRecord:
     """One SweepRecord summarizing a chaos replay (an arrival row plus
     the robustness columns)."""
     rec = _arrival_record(topo_name, obj, pat_name, seed, "poisson",
-                          trace, res, wall_s, backend)
+                          trace, res, wall_s)
     rec.chaos = preset
     rec.availability = res.availability
     rec.stranded_gbits = res.stranded_gbits
@@ -551,8 +525,7 @@ def _chaos_record(topo_name, obj, pat_name, seed, preset: str,
 
 def _record(topo_name, obj, pat_name, seed, p, r, per_inst_s, *,
             offered: float, failure: str = "none",
-            degradation_ratio: float = 0.0,
-            backend: str = "xla", policy: str = "lp",
+            degradation_ratio: float = 0.0, policy: str = "lp",
             gap_vs_lp: float = 1.0) -> SweepRecord:
     """One SweepRecord from a solved instance.  `offered` is the healthy
     demand in Gbits (a degraded instance's own coflow excludes flows the
@@ -570,7 +543,7 @@ def _record(topo_name, obj, pat_name, seed, p, r, per_inst_s, *,
         remaining_gbits=r.remaining_gbits, solve_s=per_inst_s,
         failure=failure, degradation_ratio=degradation_ratio,
         survivability=float(m.served.sum()) / max(offered, 1e-12),
-        backend=backend, policy=policy, gap_vs_lp=gap_vs_lp)
+        policy=policy, gap_vs_lp=gap_vs_lp)
 
 
 def run_sweep(spec: SweepSpec, *, log: Callable[[str], None] | None = None
@@ -618,8 +591,7 @@ def _run_grid(spec: SweepSpec, say: Callable[[str], None]
                 for seed, p, r, off in zip(spec.seeds, probs, results,
                                            offered):
                     records.append(_record(topo_name, obj, pat_name, seed,
-                                           p, r, per_inst_s, offered=off,
-                                           backend=spec.backend))
+                                           p, r, per_inst_s, offered=off))
                     problems.append(p)
                 say(f"{topo_name:10s} {pat_name:8s} min-{obj:10s} "
                     f"{len(probs)} seeds  "
@@ -643,8 +615,7 @@ def _run_grid(spec: SweepSpec, say: Callable[[str], None]
                         ratio = failures.degradation_ratio(hp.topo, fp.topo)
                         rec = _record(topo_name, obj, pat_name, seed, fp, fr,
                                       f_s, offered=off, failure=fail_name,
-                                      degradation_ratio=ratio,
-                                      backend=spec.backend)
+                                      degradation_ratio=ratio)
                         ratios.append(ratio)
                         survs.append(rec.survivability)
                         records.append(rec)
@@ -670,8 +641,7 @@ def _run_grid(spec: SweepSpec, say: Callable[[str], None]
                         trace, res, wall = _solve_arrival_cell(
                             topo, pat, fam, OBJECTIVES[obj], spec, seed)
                         rec = _arrival_record(topo_name, obj, pat_name,
-                                              seed, fam, trace, res, wall,
-                                              spec.backend)
+                                              seed, fam, trace, res, wall)
                         fam_recs.append(rec)
                         records.append(rec)
                         # the hoisted placeholder keeps records/problems
@@ -696,7 +666,7 @@ def _run_grid(spec: SweepSpec, say: Callable[[str], None]
                             topo, pat, preset, OBJECTIVES[obj], spec, seed)
                         rec = _chaos_record(topo_name, obj, pat_name,
                                             seed, preset, trace, res,
-                                            wall, spec.backend)
+                                            wall)
                         cz_recs.append(rec)
                         records.append(rec)
                         problems.append(placeholder)

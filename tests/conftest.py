@@ -26,3 +26,14 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip)
+
+
+@pytest.fixture(params=["coo", "tiles"])
+def operator(request, monkeypatch):
+    """The operator PDHG applies K with: `coo` as a CPU runs it, `tiles`
+    as a TPU runs it (solver._tiled_spmv forced true, so the CPU runs
+    the chip's dense-tile operator)."""
+    if request.param == "tiles":
+        from repro.core import solver
+        monkeypatch.setattr(solver, "_tiled_spmv", lambda: True)
+    return request.param

@@ -1,8 +1,8 @@
 """Structure-cache equivalence suite for the vectorized build path.
 
 PR 5 rewrote `build_routing_lp` as vectorized index arithmetic with a
-cross-solve ProblemStructure cache, a blocked-ELL plan cache, and
-shape-bucketed PDHG dispatches.  These tests pin the three invariants
+cross-solve ProblemStructure cache and shape-bucketed PDHG dispatches;
+the tile operator added a tile-plan cache.  These tests pin the three invariants
 that make that refactor safe:
 
   1. the vectorized assembly reproduces the historical loop builder
@@ -10,10 +10,10 @@ that make that refactor safe:
      numbering, COO entry order, and row-identity keys — on every
      topology, both objectives, including degraded, epoch-merged and
      zero-flow instances;
-  2. cache hits are invisible: solving with a hot structure/ELL cache
-     returns bit-identical metrics to a cold build, on both backends,
-     and an arrival-trace re-solve with unchanged structure performs
-     zero LP rebuilds and zero ELL re-packs (the counters in
+  2. cache hits are invisible: solving with a hot structure/tile-plan
+     cache returns bit-identical metrics to a cold build, under both
+     operators, and an arrival-trace re-solve with unchanged structure
+     performs zero LP rebuilds (the counters in
      `solver.build_cache_stats()` assert it);
   3. shape bucketing is value-neutral: bucketed solves match unbucketed
      within the golden 1e-4 envelope (on CPU they are bitwise equal).
@@ -166,35 +166,28 @@ def test_structure_cache_keying():
     assert stats.structure_misses == 3
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
 @pytest.mark.parametrize("topo_name", sorted(topology.BUILDERS))
-def test_solve_fast_cached_equals_uncached(topo_name, backend):
+def test_solve_fast_cached_equals_uncached(topo_name, operator):
     p = _problem(topo_name)
     solver.reset_build_caches()
-    cold = solver.solve_fast(p, "energy", iters=200, tol=5e-3,
-                             backend=backend)
+    cold = solver.solve_fast(p, "energy", iters=200, tol=5e-3)
     assert solver.build_cache_stats().structure_hits == 0
-    hot = solver.solve_fast(p, "energy", iters=200, tol=5e-3,
-                            backend=backend)
+    hot = solver.solve_fast(p, "energy", iters=200, tol=5e-3)
     assert solver.build_cache_stats().structure_hits >= 1
     assert _metrics_tuple(cold) == _metrics_tuple(hot)
     np.testing.assert_array_equal(cold.schedule, hot.schedule)
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_solve_fast_cached_degraded_and_merged(backend):
+def test_solve_fast_cached_degraded_and_merged(operator):
     for p in (_degraded("spine-leaf"), _merged("spine-leaf")):
         solver.reset_build_caches()
-        cold = solver.solve_fast(p, "time", iters=200, tol=5e-3,
-                                 backend=backend)
-        hot = solver.solve_fast(p, "time", iters=200, tol=5e-3,
-                                backend=backend)
+        cold = solver.solve_fast(p, "time", iters=200, tol=5e-3)
+        hot = solver.solve_fast(p, "time", iters=200, tol=5e-3)
         assert _metrics_tuple(cold) == _metrics_tuple(hot)
         np.testing.assert_array_equal(cold.schedule, hot.schedule)
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_solve_fast_warm_cached_equals_uncached(backend):
+def test_solve_fast_warm_cached_equals_uncached(operator):
     """The epoch re-solve primitive: warm-started, epoch-merged flow
     indexing (flow_map), identical with cold and hot build caches."""
     p1 = _problem("spine-leaf", seed=0)
@@ -204,11 +197,10 @@ def test_solve_fast_warm_cached_equals_uncached(backend):
                                        - p1.coflow.n_flows, -1)])
 
     def run():
-        r1 = solver.solve_fast(p1, "energy", iters=200, tol=5e-3,
-                               backend=backend)
+        r1 = solver.solve_fast(p1, "energy", iters=200, tol=5e-3)
         return solver.solve_fast_warm(p2, "energy", warm=r1,
                                       flow_map=flow_map, iters=200,
-                                      tol=5e-3, backend=backend)
+                                      tol=5e-3)
 
     solver.reset_build_caches()
     cold = run()
@@ -220,7 +212,7 @@ def test_solve_fast_warm_cached_equals_uncached(backend):
 
 def test_arrival_resolve_is_zero_rebuild():
     """Re-solving an unchanged arrival trace performs zero LP rebuilds:
-    every epoch's structure (and, on pallas, its ELL plan) is already
+    every epoch's structure (and, on a TPU, its tile plan) is already
     cached, so only value refreshes run."""
     topo = topology.build("spine-leaf")
     pat = traffic.pattern("uniform", **SMALL)
@@ -236,26 +228,27 @@ def test_arrival_resolve_is_zero_rebuild():
     stats = solver.build_cache_stats()
     assert stats.structure_misses == snap.structure_misses, \
         "re-solving an unchanged trace must not rebuild any LP structure"
-    assert stats.ell_misses == snap.ell_misses, \
-        "re-solving an unchanged trace must not re-pack any ELL operator"
+    assert stats.tile_misses == snap.tile_misses, \
+        "re-solving an unchanged trace must not re-plan any tile layout"
     assert stats.structure_hits > snap.structure_hits
     assert second.total_energy_j == first.total_energy_j
     assert second.makespan_s == first.makespan_s
 
 
-def test_ell_plan_cache_zero_repack_pallas():
-    """The pallas dispatch re-packs only on the first solve of a
-    structure; the second solve refreshes values through the cached
-    plan (zero ELL re-packs)."""
+def test_tile_plan_cache_zero_replan(monkeypatch):
+    """With the tile operator, a dispatch plans the tiles only on the
+    first solve of a structure; the second solve refills the cached
+    plan (zero re-plans)."""
+    monkeypatch.setattr(solver, "_tiled_spmv", lambda: True)
     p = _problem("spine-leaf")
     solver.reset_build_caches()
-    solver.solve_fast(p, "energy", iters=200, tol=5e-3, backend="pallas")
+    solver.solve_fast(p, "energy", iters=200, tol=5e-3)
     snap = solver.build_cache_stats().snapshot()
-    assert snap.ell_misses > 0
-    solver.solve_fast(p, "energy", iters=200, tol=5e-3, backend="pallas")
+    assert snap.tile_misses > 0
+    solver.solve_fast(p, "energy", iters=200, tol=5e-3)
     stats = solver.build_cache_stats()
-    assert stats.ell_misses == snap.ell_misses
-    assert stats.ell_hits > snap.ell_hits
+    assert stats.tile_misses == snap.tile_misses
+    assert stats.tile_hits > snap.tile_hits
 
 
 # ---------------------------------------------------------------------------

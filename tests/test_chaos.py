@@ -21,8 +21,8 @@ PON = topology.build("pon3")
 
 @pytest.fixture(scope="module", autouse=True)
 def _release_compiled_executables():
-    """The chaos grid compiles many one-off degraded-fabric LP shapes on
-    both backends.  On a single-core runner those executables stay live
+    """The chaos grid compiles many one-off degraded-fabric LP shapes
+    under both operators.  On a single-core runner those executables stay live
     in jax's jit caches for the rest of the session and push the
     process over the native JIT code-arena limit hundreds of tests
     later (XLA backend_compile segfaults, reproducibly).  Dropping them
@@ -182,8 +182,7 @@ def test_zero_length_outage_resolves_repair_first():
 # run_online: no-op chaos takes byte-identical decisions to healthy
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_online_noop_storm_matches_healthy(backend):
+def test_online_noop_storm_matches_healthy(operator):
     trace = small_trace()
     scen = failures.sample(TOPO, "switch", 0)
     # both events land before the first epoch boundary: applied there,
@@ -191,7 +190,7 @@ def test_online_noop_storm_matches_healthy(backend):
     # the healthy run's decisions exactly
     evs = [chaosmod.ChaosEvent(1e-12, "fail", 0, scen),
            chaosmod.ChaosEvent(2e-12, "repair", 0, scen)]
-    kw = dict(iters=1500, tol=5e-3, backend=backend)
+    kw = dict(iters=1500, tol=5e-3)
     healthy = arrivals.run_online(TOPO, trace, "energy", **kw)
     chaotic = arrivals.run_online(TOPO, trace, "energy", chaos=evs,
                                   fallback_policy="scf", **kw)
@@ -209,14 +208,12 @@ def test_online_noop_storm_matches_healthy(backend):
 
 
 # ---------------------------------------------------------------------------
-# run_online: storm replay is deterministic per seed on every backend
+# run_online: storm replay is deterministic per seed under every operator
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_online_storm_replay_deterministic(backend):
+def test_online_storm_replay_deterministic(operator):
     trace = small_trace(n_coflows=3)
-    kw = dict(iters=1500, tol=5e-3, backend=backend,
-              fallback_policy="scf")
+    kw = dict(iters=1500, tol=5e-3, fallback_policy="scf")
     r1 = arrivals.run_online(TOPO, trace, "energy",
                              chaos=storm_events(), **kw)
     r2 = arrivals.run_online(TOPO, trace, "energy",
@@ -237,22 +234,21 @@ def test_online_storm_replay_deterministic(backend):
         shipped + r1.backlog_gbits + r1.deferred_failure_gbits, abs=1e-6)
 
 
-def test_online_event_application_backend_independent():
+def test_online_event_application_backend_independent(monkeypatch):
     """The trace and its application times are solver-independent: both
-    backends apply the same events at the same boundaries."""
+    operators (COO, dense tiles) apply the same events at the same
+    boundaries."""
     trace = small_trace(n_coflows=3)
     logs = {}
-    for backend in solver.BACKENDS:
+    for tiles in (False, True):
+        monkeypatch.setattr(solver, "_tiled_spmv", lambda t=tiles: t)
         r = arrivals.run_online(TOPO, trace, "energy", iters=1500,
-                                tol=5e-3, backend=backend,
-                                chaos=storm_events(),
+                                tol=5e-3, chaos=storm_events(),
                                 fallback_policy="scf")
-        logs[backend] = [l for l in r.chaos_log
-                         if " fail " in l or " repair " in l]
-    ref = logs[solver.BACKENDS[0]]
-    assert ref
-    for backend, lines in logs.items():
-        assert lines == ref, backend
+        logs[tiles] = [l for l in r.chaos_log
+                       if " fail " in l or " repair " in l]
+    assert logs[False]
+    assert logs[True] == logs[False]
 
 
 # ---------------------------------------------------------------------------
@@ -300,10 +296,9 @@ def service_tenants(n=2):
             for k in range(n)]
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_service_chaos_replay_byte_identical(backend):
-    cfg = service.ServiceConfig(iters=1500, tol=5e-3, backend=backend,
-                                chaos=("storm",), chaos_seed=1)
+def test_service_chaos_replay_byte_identical(operator):
+    cfg = service.ServiceConfig(iters=1500, tol=5e-3, chaos=("storm",),
+                                chaos_seed=1)
     r1 = service.run_service(service_tenants(), cfg)
     r2 = service.run_service(service_tenants(), cfg)
     assert r1.event_log() == r2.event_log()

@@ -166,8 +166,7 @@ def test_pon_multicell_single_cell_matches_pon3_shape():
     ("pon-multicell", dict(n_cells=2, n_racks=2, servers_per_rack=2)),
     ("pon-cascaded", dict(n_cells=2, n_racks=2, servers_per_rack=2)),
 ])
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_new_families_solve_and_certify(name, kw, backend):
+def test_new_families_solve_and_certify(name, kw, operator):
     from repro.core import solver, timeslot, traffic, verify
 
     topo = topology.BUILDERS[name](**kw)
@@ -175,7 +174,7 @@ def test_new_families_solve_and_certify(name, kw, backend):
     cf = traffic.generate(topo, pat, seed=0)
     p = timeslot.ScheduleProblem(topo, cf,
                                  n_slots=timeslot.suggest_n_slots(topo, cf))
-    r = solver.solve_fast(p, "energy", backend=backend)
+    r = solver.solve_fast(p, "energy")
     cert = verify.check_schedule(p, r.schedule)
     assert cert.ok, cert
     assert r.metrics.feasible

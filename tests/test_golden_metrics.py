@@ -2,12 +2,13 @@
 
 A tiny pinned grid (2 topologies x 2 objectives x 1 seed) with expected
 exact paper-model Metrics committed under tests/golden/metrics.json.
-Every cell is solved with solve_fast on BOTH backends and compared to
-the committed numbers at 1e-4 relative — solver refactors (LP assembly,
-PDHG schedule, packing) cannot silently drift the reproduced paper
-numbers.  The committed values come from the "xla" backend; the pallas
-backend is held to the same envelope (the backends agree to ~1e-7,
-docs/SOLVER.md §7).
+Every cell is solved with solve_fast under BOTH operators (`operator`
+fixture: COO as a CPU runs it, dense tiles as a TPU runs it) and
+compared to the committed numbers at 1e-4 relative — solver refactors
+(LP assembly, PDHG schedule, packing) cannot silently drift the
+reproduced paper numbers.  The committed values come from the COO
+operator; the tiles are held to the same envelope (they differ only in
+float32 summation order, docs/SOLVER.md §7).
 
 Regenerate after an *intentional* numbers change:
 
@@ -29,8 +30,8 @@ from repro.core import (arrivals, policies, solver, timeslot, topology,
 
 GOLDEN_PATH = pathlib.Path(__file__).parent / "golden" / "metrics.json"
 RTOL = 1e-4
-# policy gaps divide by the per-backend LP solve, whose packed cost
-# wiggles ~1e-3 between lowerings — looser envelope than the metrics
+# policy gaps divide by the per-operator LP solve, whose packed cost
+# wiggles ~1e-3 between operators — looser envelope than the metrics
 GAP_RTOL = 5e-3
 
 # the pinned grid — small enough to solve tightly in seconds, spanning
@@ -57,6 +58,8 @@ SERVICE_KEY = "service/spine-leaf+pon3/seed0"
 # results/placement run uses the real budget.
 SEARCH_CFG = dict(method="sa", seed=0, generations=2, population=6,
                   iters=1500)
+# the search cell whose pinned optimum ties a baseline to the last ulp
+TIED_SEARCH = {("spine-leaf", "time")}
 
 
 def _problem(topo_name: str) -> timeslot.ScheduleProblem:
@@ -66,14 +69,14 @@ def _problem(topo_name: str) -> timeslot.ScheduleProblem:
         topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf), path_slack=2)
 
 
-def _solve(topo_name: str, objective: str, backend: str) -> dict:
+def _solve(topo_name: str, objective: str) -> dict:
     p = _problem(topo_name)
-    r = solver.solve_fast(p, objective, backend=backend)
+    r = solver.solve_fast(p, objective)
     # every golden schedule carries a zero-violation feasibility
     # certificate (capacity / conservation / wavelength / demand
     # residuals, core.verify) — not just the evaluate() bit
     verify.check_schedule(p, r.schedule).assert_ok(
-        f"{topo_name}/min-{objective}[{backend}]")
+        f"{topo_name}/min-{objective}")
     m = r.metrics
     return {"energy_j": float(m.energy_j),
             "completion_s": float(m.completion_s),
@@ -83,16 +86,18 @@ def _solve(topo_name: str, objective: str, backend: str) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _lp_for_gap(topo_name: str, objective: str, backend: str):
+def _lp_for_gap(topo_name: str, objective: str, operator: str):
+    """The LP solve a gap divides by, cached per operator (the name is
+    the cache key; the operator fixture has set the solver up)."""
     p = _problem(topo_name)
-    return p, solver.solve_fast(p, objective, backend=backend)
+    return p, solver.solve_fast(p, objective)
 
 
 def _policy_gap(topo_name: str, objective: str, pol_name: str,
-                backend: str) -> dict:
-    p_lp, lp = _lp_for_gap(topo_name, objective, backend)
+                operator: str) -> dict:
+    p_lp, lp = _lp_for_gap(topo_name, objective, operator)
     p = _problem(topo_name)
-    r = policies.get(pol_name).solve(p, objective, backend=backend)
+    r = policies.get(pol_name).solve(p, objective)
     r.certificate.assert_ok(f"{pol_name}/{topo_name}/min-{objective}")
     m = r.metrics
     return {"gap_vs_lp": float(policies.gap_vs_lp(objective, p,
@@ -102,7 +107,7 @@ def _policy_gap(topo_name: str, objective: str, pol_name: str,
             "feasible": bool(m.feasible)}
 
 
-def _service_run(backend: str) -> dict:
+def _service_run() -> dict:
     spec = arrivals.ArrivalSpec(n_coflows=2, mean_interarrival_s=2.0)
     pat = traffic.pattern("uniform", **PATTERN)
     tenants = [
@@ -113,7 +118,6 @@ def _service_run(backend: str) -> dict:
     ]
     res = service.run_service(
         tenants, service.ServiceConfig(iters=3000, tol=2e-3,
-                                       backend=backend,
                                        verify_schedules=True))
     assert res.backlog_gbits == 0.0
     return {"total_energy_j": float(res.total_energy_j),
@@ -128,14 +132,13 @@ def _service_run(backend: str) -> dict:
             "admitted": res.counters.admitted}
 
 
-def _search_run(topo_name: str, objective: str, backend: str) -> dict:
+def _search_run(topo_name: str, objective: str) -> dict:
     topo = topology.build(topo_name)
     pat = traffic.pattern("uniform", **PATTERN)
     obj = "time" if objective == "time" else "energy"
-    res = search.optimize_placement(topo, pat, obj, backend=backend,
-                                    **SEARCH_CFG)
+    res = search.optimize_placement(topo, pat, obj, **SEARCH_CFG)
     res.best.result.certificate.assert_ok(
-        f"search/{topo_name}/min-{objective}[{backend}]")
+        f"search/{topo_name}/min-{objective}")
     return {"best_score": float(res.best.score),
             "gain": float(res.gain),
             "baseline_best": res.baseline_best,
@@ -152,28 +155,26 @@ def _golden() -> dict:
         return json.load(fh)
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
 @pytest.mark.parametrize("topo_name,objective", GRID)
-def test_golden_metrics(topo_name, objective, backend):
+def test_golden_metrics(topo_name, objective, operator):
     want = _golden()[f"{topo_name}/min-{objective}/seed{SEED}"]
-    got = _solve(topo_name, objective, backend)
+    got = _solve(topo_name, objective)
     assert got["feasible"] and want["feasible"]
     for key in ("energy_j", "completion_s", "fairness_term",
                 "served_gbits"):
         np.testing.assert_allclose(
             got[key], want[key], rtol=RTOL, atol=1e-9,
-            err_msg=f"{topo_name}/min-{objective}[{backend}] {key} drifted "
+            err_msg=f"{topo_name}/min-{objective}[{operator}] {key} drifted "
                     f"from tests/golden/metrics.json (regen only if the "
                     f"change is intentional)")
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_golden_service_metrics(backend):
+def test_golden_service_metrics(operator):
     """The two-tenant service pin: schedule quality of the coalescing
     loop (per-tenant energies, shipped volumes, completion times) must
-    match the committed numbers on both backends."""
+    match the committed numbers under both operators."""
     want = _golden()[SERVICE_KEY]
-    got = _service_run(backend)
+    got = _service_run()
     # admission accounting is solver-independent: exact equality
     for key in ("n_done", "arrived", "admitted"):
         assert got[key] == want[key], key
@@ -181,53 +182,54 @@ def test_golden_service_metrics(backend):
                 "tenant_shipped_gbits", "tenant_makespan_s"):
         np.testing.assert_allclose(
             got[key], want[key], rtol=RTOL, atol=1e-9,
-            err_msg=f"{SERVICE_KEY}[{backend}] {key} drifted from "
+            err_msg=f"{SERVICE_KEY}[{operator}] {key} drifted from "
                     f"tests/golden/metrics.json (regen only if the "
                     f"change is intentional)")
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
 @pytest.mark.parametrize("topo_name,objective,pol_name", POLICY_GRID)
-def test_golden_policy_gaps(topo_name, objective, pol_name, backend):
+def test_golden_policy_gaps(topo_name, objective, pol_name, operator):
     """The pinned optimal-vs-practical grid: each baseline policy's
-    certified schedule and its gap over the LP cannot silently drift on
-    either backend.  Gaps get the looser GAP_RTOL envelope (the LP
-    denominator is backend-dependent); the policy's own metrics are
+    certified schedule and its gap over the LP cannot silently drift
+    under either operator.  Gaps get the looser GAP_RTOL envelope (the
+    LP denominator is operator-dependent); the policy's own metrics are
     pure numpy and held to the solver RTOL."""
     want = _golden()[f"policy/{topo_name}/min-{objective}/{pol_name}/"
                      f"seed{SEED}"]
-    got = _policy_gap(topo_name, objective, pol_name, backend)
+    got = _policy_gap(topo_name, objective, pol_name, operator)
     assert got["feasible"] and want["feasible"]
     assert got["gap_vs_lp"] >= 1.0 - 1e-4
     np.testing.assert_allclose(
         got["gap_vs_lp"], want["gap_vs_lp"], rtol=GAP_RTOL,
         err_msg=f"policy/{topo_name}/min-{objective}/{pol_name}"
-                f"[{backend}] gap drifted (regen only if intentional)")
+                f"[{operator}] gap drifted (regen only if intentional)")
     for key in ("energy_j", "completion_s"):
         np.testing.assert_allclose(
             got[key], want[key], rtol=RTOL, atol=1e-9,
             err_msg=f"policy/{topo_name}/min-{objective}/{pol_name}"
-                    f"[{backend}] {key} drifted")
+                    f"[{operator}] {key} drifted")
 
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
 @pytest.mark.parametrize("topo_name,objective", GRID)
-def test_golden_search_runs(topo_name, objective, backend):
+def test_golden_search_runs(topo_name, objective, operator):
     """The pinned SA placement-search runs: optimized placement, score,
-    gain, and per-baseline scores must match the committed numbers on
-    both backends.  The accept/reject trajectory depends on exact score
-    comparisons, so the placement ids are pinned too — if the backends
-    ever diverge on a comparison, this catches it loudly rather than
-    letting search results drift quietly."""
+    gain, and per-baseline scores must match the committed numbers under
+    both operators.  The accept/reject trajectory depends on exact score
+    comparisons, so the placement ids are pinned too, but for the one
+    cell in TIED_SEARCH: there the optimum ties the `local` baseline,
+    and which of the tied placements the search keeps is decided by a
+    last-ulp difference of completion time (docs/PLACEMENT.md §6), so
+    that cell pins score and gain, not ids."""
     want = _golden()[f"search/{topo_name}/min-{objective}/sa/seed{SEED}"]
-    got = _search_run(topo_name, objective, backend)
+    got = _search_run(topo_name, objective)
+    tag = f"search/{topo_name}/min-{objective}[{operator}]"
     assert got["baseline_best"] == want["baseline_best"]
     assert got["evaluations"] == want["evaluations"]
     assert got["dispatches"] == want["dispatches"]
-    assert got["best_mappers"] == want["best_mappers"], \
-        f"search/{topo_name}/min-{objective}[{backend}] optimized " \
-        f"placement drifted (regen only if intentional)"
-    assert got["best_reducers"] == want["best_reducers"]
+    if (topo_name, objective) not in TIED_SEARCH:
+        assert got["best_mappers"] == want["best_mappers"], \
+            f"{tag} optimized placement drifted (regen only if intentional)"
+        assert got["best_reducers"] == want["best_reducers"]
     np.testing.assert_allclose(got["best_score"], want["best_score"],
                                rtol=RTOL)
     np.testing.assert_allclose(got["gain"], want["gain"], rtol=RTOL)
@@ -235,18 +237,17 @@ def test_golden_search_runs(topo_name, objective, backend):
     for k in search.BASELINES:
         np.testing.assert_allclose(
             got["baselines"][k], want["baselines"][k], rtol=RTOL,
-            err_msg=f"search/{topo_name}/min-{objective}[{backend}] "
-                    f"baseline {k} drifted")
+            err_msg=f"{tag} baseline {k} drifted")
 
 
 def _regen() -> None:
-    doc = {f"{t}/min-{o}/seed{SEED}": _solve(t, o, "xla") for t, o in GRID}
+    doc = {f"{t}/min-{o}/seed{SEED}": _solve(t, o) for t, o in GRID}
     doc.update({f"policy/{t}/min-{o}/{pol}/seed{SEED}":
-                _policy_gap(t, o, pol, "xla")
+                _policy_gap(t, o, pol, "coo")
                 for t, o, pol in POLICY_GRID})
-    doc.update({f"search/{t}/min-{o}/sa/seed{SEED}": _search_run(t, o, "xla")
+    doc.update({f"search/{t}/min-{o}/sa/seed{SEED}": _search_run(t, o)
                 for t, o in GRID})
-    doc[SERVICE_KEY] = _service_run("xla")
+    doc[SERVICE_KEY] = _service_run()
     GOLDEN_PATH.parent.mkdir(parents=True, exist_ok=True)
     GOLDEN_PATH.write_text(json.dumps(doc, indent=1, sort_keys=True) + "\n")
     print(f"wrote {GOLDEN_PATH}")
@@ -267,7 +268,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--regen", action="store_true",
                     help="rewrite tests/golden/metrics.json from the "
-                         "current xla-backend solver")
+                         "current solver (COO operator, as on a CPU)")
     if ap.parse_args().regen:
         _regen()
     else:

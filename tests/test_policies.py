@@ -50,8 +50,7 @@ def _problem(topo_name: str) -> timeslot.ScheduleProblem:
 
 @functools.lru_cache(maxsize=None)
 def _lp(topo_name: str, objective: str) -> solver.FastPathResult:
-    return solver.solve_fast(_problem(topo_name), objective, iters=3000,
-                             backend="xla")
+    return solver.solve_fast(_problem(topo_name), objective, iters=3000)
 
 
 # ---------------------------------------------------------------------------

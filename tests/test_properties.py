@@ -10,15 +10,15 @@ Each property is a plain checker over an RNG so it runs in two modes:
 
 Invariants pinned here:
 
-  1. ell_pack -> ell_spmv equals the dense matvec (both gather
-     directions) on random sparsity patterns;
+  1. the blocked-ELL pack (one shard) and its operator pair equal the
+     dense matvec (both gather directions) on random sparsity patterns;
   2. path_decompose conserves per-flow volume exactly — decomposed
      path volumes per flow sum to the flow's demand;
   3. evaluate's aggregate metrics are invariant under a flow-order
      permutation of the CoflowSet (and `served` permutes with it);
   4. a zero-flow CoflowSet (an empty arrival epoch) flows through
      build_routing_lp / solve_fast / evaluate as empty-but-valid
-     results instead of raising, on both backends;
+     results instead of raising, under both operators;
   5. metamorphic policy/LP relations: scaling demands by k scales the
      min-time functional exactly k and leaves ECMP routing invariant;
      zeroing one flow never pushes the others' finishes later under
@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 from repro.core import policies, solver, timeslot, topology, traffic
-from repro.kernels import pdhg_spmv, ref
+from repro.kernels import pdhg_spmv
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -56,13 +56,15 @@ def check_ell_spmv_matches_dense(seed: int) -> None:
     flat = rng.choice(m * n, size=nnz, replace=False)
     row, col = flat // n, flat % n
     val = rng.normal(size=nnz)
-    op = pdhg_spmv.ell_pack(row, col, val, m, n)
+    op = pdhg_spmv.ell_pack_sharded(row, col, val, m, n, shards=1)
+    Kx, KTy = pdhg_spmv.ell_pair(op.row_idx, op.row_val, op.col_idx,
+                                 op.col_val, op.row_meta, op.col_meta)
     K = np.zeros((m, n), np.float32)
     np.add.at(K, (row, col), val.astype(np.float32))
     x = rng.normal(size=n).astype(np.float32)
     y = rng.normal(size=m).astype(np.float32)
-    kx = np.asarray(ref.ell_spmv(np.pad(x, (0, op.n_pad - n)), op.rows))
-    kty = np.asarray(ref.ell_spmv(np.pad(y, (0, op.m_pad - m)), op.cols))
+    kx = np.asarray(Kx(np.pad(x, (0, op.n_pad - n))))
+    kty = np.asarray(KTy(np.pad(y, (0, op.m_pad - m))))
     np.testing.assert_allclose(kx[:m], K @ x, atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(kty[:n], K.T @ y, atol=1e-4, rtol=1e-4)
     assert np.all(kx[m:] == 0.0) and np.all(kty[n:] == 0.0)
@@ -294,15 +296,14 @@ if HAVE_HYPOTHESIS:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("objective", ["energy", "time"])
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_zero_flow_coflow_solves(objective, backend):
+def test_zero_flow_coflow_solves(objective, operator):
     topo = topology.build("spine-leaf")
     cf = traffic.empty_coflow(topo.n_vertices)
     p = timeslot.ScheduleProblem(
         topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf))
     lp, idx = solver.build_routing_lp(p, objective)
     assert len(idx.kf) == 0 and lp.m == 0
-    r = solver.solve_fast(p, objective, backend=backend)
+    r = solver.solve_fast(p, objective)
     assert r.schedule.shape == p.shape_x
     assert r.schedule.size == 0 and r.remaining_gbits == 0.0
     m = r.metrics

@@ -1,18 +1,19 @@
-"""Differential harness: sharded PDHG vs the single-device pallas path.
+"""Differential harness: sharded PDHG vs the plain single-device solve.
 
 Each test spawns a subprocess with 4 fake CPU devices
 (XLA_FLAGS=--xla_force_host_platform_device_count=4) and solves one of
 the six paper topologies through the fast path at shards in {1, 2, 4}.
 The subprocess prints the paper metrics plus a SHA-256 over the packed
-schedule's raw psi bytes; the parent compares against a single-device
-pallas reference solved in THIS process:
+schedule's raw psi bytes; the parent compares against the plain
+single-device solve in THIS process:
 
   * shards=1 must be BITWISE identical (same psi digest) — the shards=1
     route never enters shard_map, so adding devices to the process must
-    not perturb a single bit of the existing pallas path;
+    not perturb a single bit of the plain solve;
   * shards=2 and shards=4 must agree on every metric to rtol 1e-4 —
-    the row-block partition + psum(K^T y) reduction reorders float
-    additions, so exact equality is not guaranteed, closeness is.
+    the row-sharded blocked-ELL operator and its psum(K^T y) sum in
+    another float order than the COO operator, so exact equality is not
+    guaranteed, closeness is.
 
 Subprocesses are required because device count is fixed at jax import
 time and the main pytest process must keep its real 1-device view.
@@ -45,8 +46,7 @@ _WORKER = """
     p = timeslot.ScheduleProblem(
         topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf))
     for shards in (1, 2, 4):
-        r = solver.solve_fast(p, "energy", iters={iters},
-                              backend="pallas", shards=shards)
+        r = solver.solve_fast(p, "energy", iters={iters}, shards=shards)
         psi = np.ascontiguousarray(r.metrics.psi, dtype=np.float64)
         digest = hashlib.sha256(psi.tobytes()).hexdigest()
         print(f"RESULT shards={{shards}} "
@@ -78,13 +78,13 @@ def run_worker(topo_name: str, devices: int = 4) -> dict[int, dict]:
 
 
 def _reference(topo_name: str):
-    """Single-device pallas solve in the main (1-device) process."""
+    """The plain single-device solve in the main (1-device) process."""
     topo = topology.build(topo_name)
     pat = traffic.pattern("uniform", n_map=4, n_reduce=3)
     cf = traffic.generate(topo, pat, seed=0)
     p = timeslot.ScheduleProblem(
         topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf))
-    r = solver.solve_fast(p, "energy", iters=ITERS, backend="pallas")
+    r = solver.solve_fast(p, "energy", iters=ITERS)
     psi = np.ascontiguousarray(r.metrics.psi, dtype=np.float64)
     return r, hashlib.sha256(psi.tobytes()).hexdigest()
 
@@ -94,9 +94,9 @@ def test_sharded_matches_single_device(topo_name):
     ref, ref_digest = _reference(topo_name)
     got = run_worker(topo_name)
 
-    # mesh=1 in a multi-device process is the plain pallas path — bitwise
+    # mesh=1 in a multi-device process is the plain solve — bitwise
     assert got[1]["psi"] == ref_digest, \
-        f"{topo_name}: shards=1 schedule diverged from single-device pallas"
+        f"{topo_name}: shards=1 schedule diverged from the plain solve"
     assert got[1]["energy"] == ref.metrics.energy_j
     assert got[1]["completion"] == ref.metrics.completion_s
 
@@ -128,8 +128,8 @@ def test_sharded_lp_iterates_close_to_single_device():
         p = timeslot.ScheduleProblem(
             topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf))
         lp, _ = solver.build_routing_lp(p, "energy")
-        xs = [solver.solve_lp(lp, iters=600, backend="pallas",
-                              shards=s).x for s in (1, 2, 4)]
+        xs = [solver.solve_lp(lp, iters=600, shards=s).x
+              for s in (1, 2, 4)]
         print("MAXDIFF", max(float(np.abs(x - xs[0]).max())
                              for x in xs[1:]))
     """)
@@ -138,24 +138,75 @@ def test_sharded_lp_iterates_close_to_single_device():
     assert r.returncode == 0, r.stderr[-4000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("MAXDIFF")][0]
     scale = max(1.0, float(np.max(np.abs(
-        solver.solve_lp(lp, iters=600, backend="pallas").x))))
+        solver.solve_lp(lp, iters=600).x))))
     assert float(line.split()[1]) <= 1e-4 * scale
 
 
 # -------- in-process coverage of the sharded machinery (1 device is a
 # -------- valid mesh: psum over a 1-device axis is the exact identity)
-def test_sharded_driver_on_one_device_mesh_matches_plain_pallas():
+def test_sharded_driver_on_one_device_mesh_matches_plain_ell():
+    """The sharded driver on a one-device mesh is the shared burst over
+    the blocked-ELL pair, bit for bit."""
+    import jax.numpy as jnp
+
+    from repro.core import pdhg
+    from repro.kernels import pdhg_spmv
+
     topo = topology.build("spine-leaf")
     pat = traffic.pattern("uniform", n_map=4, n_reduce=3)
     cf = traffic.generate(topo, pat, seed=0)
     p = timeslot.ScheduleProblem(
         topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf))
     lp, _ = solver.build_routing_lp(p, "energy")
-    plain = solver._solve_lp_pallas(lp, 400, 1e-6, 0, None, None)
-    shard = solver._solve_lp_pallas_sharded(lp, 400, 1e-6, 0, None, None,
-                                            shards=1)
-    np.testing.assert_array_equal(shard.x, plain.x)
-    np.testing.assert_array_equal(shard.y, plain.y)
+    g = solver.block_stack([lp]).lp
+    st = solver._stage_sharded(g, 1, 1)
+    shard_x, shard_y = st.unpad(*st.run(*st.place(np.zeros(lp.n),
+                                                  np.zeros(lp.m)),
+                                        400)[:2])
+    op, vecs, ell = solver._pack_sharded(g, 1)
+    c, tau, xmax, q, sig, ub = vecs
+    Kx, KTy = pdhg_spmv.ell_pair(*ell, op.row_meta, op.col_meta)
+    x, y = pdhg.burst(Kx, KTy, c, tau, sig, q, xmax, ub,
+                      jnp.zeros(op.n_pad), jnp.zeros(op.m_pad), 400)
+    np.testing.assert_array_equal(shard_x, np.asarray(x)[:lp.n])
+    np.testing.assert_array_equal(shard_y, np.asarray(y)[:lp.m])
+
+
+@pytest.mark.parametrize("objective", ["energy", "time"])
+def test_pack_sharded_vectors_match_float64_formulas(objective):
+    """The row-sharded pack's vectors come from core.pdhg (float32 sums
+    on the device); on the fat-tree-k16 LP they equal the float64 host
+    formulas the pack used before, rounded once to float32.  K's entries
+    are small integers, so every row and column sum is exact in float32
+    and 1/sum rounds the same from either width."""
+    topo = topology.build("fat-tree", k=16)
+    pat = traffic.pattern("uniform", n_map=20, n_reduce=12,
+                          total_gbits=120.0)
+    cf = traffic.generate(topo, pat, seed=0)
+    p = timeslot.ScheduleProblem(
+        topo, cf, n_slots=timeslot.suggest_n_slots(topo, cf), path_slack=0)
+    lp, _ = solver.build_routing_lp(p, objective)
+    assert len(lp.val) > 100_000
+    op, vecs, _ = solver._pack_sharded(solver.block_stack([lp]).lp, 4)
+    n, m = lp.n, lp.m
+    abs_val = np.abs(lp.val)
+    col_sum, row_sum = np.zeros(n), np.zeros(m)
+    np.add.at(col_sum, lp.col, abs_val)
+    np.add.at(row_sum, lp.row, abs_val)
+    cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
+    want = (lp.c / cscale, 1.0 / np.maximum(col_sum, 1e-12),
+            np.where(np.isfinite(lp.xmax), lp.xmax, 1e12),
+            np.concatenate([lp.b, lp.h]), 1.0 / np.maximum(row_sum, 1e-12))
+    pads = (op.n_pad - n,) * 3 + (op.m_pad - m,) * 2
+    for name, got, w, pad in zip(("c", "tau", "xmax", "q", "sig"), vecs,
+                                 want, pads):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.pad(w.astype(np.float32), (0, pad)),
+            err_msg=name)
+    np.testing.assert_array_equal(
+        np.asarray(vecs[5]),
+        np.pad(np.arange(m) >= lp.m_eq, (0, op.m_pad - m),
+               constant_values=True))
 
 
 def _dense_from_sharded(op):

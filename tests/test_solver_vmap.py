@@ -22,10 +22,11 @@ def test_vmap_over_demand_vectors():
     def solve_one(scale):
         b = jnp.asarray(lp.b).at[-F:].set(jnp.asarray(demand_rows) * scale)
         xmax = jnp.asarray(np.where(np.isfinite(lp.xmax), lp.xmax, 1e12))
-        x, primal, gap = solver._pdhg_run(
+        x, _, primal, _ = solver._pdhg_resume(
             jnp.asarray(lp.c / max(abs(lp.c).max(), 1e-12)),
             jnp.asarray(lp.row), jnp.asarray(lp.col), jnp.asarray(lp.val),
-            b, jnp.asarray(lp.h), xmax, lp.m, lp.n, lp.m_eq, 3000, 3000)
+            b, jnp.asarray(lp.h), xmax, jnp.zeros(lp.n), jnp.zeros(lp.m),
+            lp.m, lp.n, lp.m_eq, 3000)
         return x[-1], primal                     # theta, residual
 
     thetas, primals = jax.vmap(solve_one)(scales)

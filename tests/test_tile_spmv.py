@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import solver, tile_spmv, timeslot, topology, traffic
+from repro.core import pdhg, solver, tile_spmv, timeslot, topology, traffic
 
 
 def _routing_lps(name, n, objective="time", pattern="uniform", **kw):
@@ -76,7 +76,7 @@ def test_tile_pair_matches_coo(case):
     x = rng.normal(size=gp.n).astype(np.float32)
     y = rng.normal(size=gp.m).astype(np.float32)
     row, col, val = (jnp.asarray(a) for a in (gp.row, gp.col, gp.val))
-    coo = solver._coo_pair(row, col, val, gp.m, gp.n)
+    coo = pdhg.coo_pair(row, col, val, gp.m, gp.n)
     tiles = tile_spmv.operator_pair(
         tuple(jnp.asarray(a) for a in st.arrays()), val, gp.m, gp.n)
     K = np.zeros((gp.m, gp.n))
@@ -113,13 +113,16 @@ def test_instance_tiles_same_alone_and_stacked():
         _, alone = solver._tile_layout([lp], solver.block_stack([lp]).lp,
                                        False)
         a_kx, a_kty = _table(alone.kx, lp.val), _table(alone.kty, lp.val)
-        e = plans[i].kx_eq
+        e, t_kx, t_kty = (plans[i].kx_eq, plans[i].kx.tiles,
+                          plans[i].kty.tiles)
+        # tiles past the plan's are the capacity's padding: empty
+        assert not a_kx[t_kx:].any() and not a_kty[t_kty:].any()
         parts = [(st.kx, kx, slice(te[i], te[i] + e), alone.kx, a_kx,
                   slice(0, e)),
                  (st.kx, kx, slice(tu[i], tu[i + 1]), alone.kx, a_kx,
-                  slice(e, None)),
+                  slice(e, t_kx)),
                  (st.kty, kty, slice(tt[i], tt[i + 1]), alone.kty, a_kty,
-                  slice(None))]
+                  slice(0, t_kty))]
         for sd, stab, s, ad, atab, a in parts:
             np.testing.assert_array_equal(stab[s], atab[a])
             # one offset a field, but K^T.y's segments are rows: one
@@ -194,7 +197,7 @@ def test_adaptive_tile_trajectory_matches_coo(monkeypatch):
 
 
 def test_cpu_keeps_coo_operator():
-    """On a CPU the XLA backend applies K as COO scatters."""
+    """On a CPU every dispatch applies K as COO scatters."""
     assert not solver._tiled_spmv()
     solver.reset_dispatch_stats()
     solver.solve_lp_batch(_routing_lps("pon3", 2), iters=1000,
@@ -202,6 +205,41 @@ def test_cpu_keeps_coo_operator():
     stats = solver.dispatch_stats()
     assert stats.dispatches > 0
     assert stats.tiled_dispatches == stats.tile_slots_run == 0
+
+
+@pytest.mark.parametrize("kind", ["solve_lp", "batch-adaptive",
+                                  "batch-fixed"])
+def test_every_dispatch_kind_uses_tiles(kind, monkeypatch):
+    """With _tiled_spmv true, every kind of dispatch applies K as tiles
+    (one _tile_layout a dispatch, counted tiled) and lands where the
+    COO operator does, to float32 summation order."""
+    lps = _routing_lps("pon3", 2, pattern="skew")
+
+    def solve():
+        if kind == "solve_lp":
+            return [solver.solve_lp(lp, iters=800, max_restarts=1)
+                    for lp in lps]
+        return solver.solve_lp_batch(lps, iters=800, max_restarts=1,
+                                     adaptive=kind == "batch-adaptive")
+
+    coo = solve()
+    monkeypatch.setattr(solver, "_tiled_spmv", lambda: True)
+    layouts = []
+    orig = solver._tile_layout
+
+    def spy(*a, **kw):
+        layouts.append(len(a[0]))
+        return orig(*a, **kw)
+    monkeypatch.setattr(solver, "_tile_layout", spy)
+    solver.reset_dispatch_stats()
+    tiled = solve()
+    assert layouts and sum(layouts) >= len(lps)
+    stats = solver.dispatch_stats()
+    if kind != "solve_lp":
+        assert stats.tiled_dispatches == stats.dispatches == len(layouts)
+    for a, b in zip(coo, tiled):
+        assert b.iterations == a.iterations
+        np.testing.assert_allclose(b.x, a.x, rtol=1e-5, atol=1e-5)
 
 
 def test_tile_plan_cached_per_pattern():

@@ -13,7 +13,6 @@ this one file.
 The LP is the fat-tree-k16 instance of benchmarks/scale_bench.py:
 1,024 servers, 20 mappers x 12 reducers, 120 Gbit, seed 0.
 """
-import functools
 import re
 
 import jax
@@ -23,9 +22,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.core import solver, timeslot, topology, traffic
-from repro.kernels import ops as kops
-from repro.kernels import pdhg_spmv
+from repro.core import pdhg, solver, timeslot, topology, traffic
 
 V5E_HBM_BYTES = 16e9
 ITERS = 1500
@@ -87,12 +84,6 @@ def _lp_specs(lp, sharding):
             _spec((lp.n,), f32, sharding)]
 
 
-def _normalized(lp):
-    """(c / max|c|, xmax with infinities clamped) as solve_lp packs them."""
-    cscale = max(float(np.abs(lp.c).max(initial=0.0)), 1e-12)
-    return lp.c / cscale, np.where(np.isfinite(lp.xmax), lp.xmax, 1e12)
-
-
 def _fits_one_chip(compiled):
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -100,15 +91,34 @@ def _fits_one_chip(compiled):
     assert 0 < used < V5E_HBM_BYTES, ma
 
 
+def _compile_resume(gp, one_chip, tiles=None):
+    """_pdhg_resume (solve_lp's rungs) for `gp`, `tiles` the tile plan's
+    arrays or None for the COO operator."""
+    f32, i32 = jnp.float32, jnp.int32
+    tile_specs = None if tiles is None else tuple(
+        _spec(a.shape, i32, one_chip) for a in tiles)
+    return solver._pdhg_resume.lower(
+        *_lp_specs(gp, one_chip), _spec((gp.n,), f32, one_chip),
+        _spec((gp.m,), f32, one_chip), gp.m, gp.n, gp.m_eq, ITERS,
+        tile_specs).compile()
+
+
 def test_pdhg_resume_compiles_for_one_v5e(one_chip, lp_k16):
-    """solve_fast's default path: the resumable COO-scatter PDHG."""
-    lp = lp_k16
-    f32 = jnp.float32
-    compiled = solver._pdhg_resume.lower(
-        *_lp_specs(lp, one_chip), _spec((lp.n,), f32, one_chip),
-        _spec((lp.m,), f32, one_chip), lp.m, lp.n, lp.m_eq,
-        ITERS).compile()
+    """The resumable PDHG of solve_lp's rungs with the COO operator."""
+    _fits_one_chip(_compile_resume(lp_k16, one_chip))
+
+
+def test_tiled_pdhg_resume_compiles_for_one_v5e(one_chip, lp_k16):
+    """solve_fast's path on a TPU: the resumable PDHG with the tile
+    operator, unbucketed as solve_lp stages it; no scatter inside the
+    loop moves one update per nonzero."""
+    g = solver.block_stack([lp_k16]).lp
+    gp, st = solver._tile_layout([lp_k16], g, False)
+    compiled = _compile_resume(gp, one_chip, st.arrays())
     _fits_one_chip(compiled)
+    updates = _loop_scatter_updates(compiled)
+    assert st.kx.tiles in updates and st.kty.tiles in updates
+    assert len(gp.val) not in updates
 
 
 def _loop_scatter_updates(compiled) -> list[int]:
@@ -167,41 +177,18 @@ def test_tiled_pdhg_run_adaptive_compiles_for_one_v5e(one_chip, lp_k16):
 def test_sharded_burst_compiles_for_v5e_2x2(tpu_topology, lp_k16):
     """The row-sharded burst (`--mesh 4`, solve_fast(shards=4)) on a
     four-chip mesh: one all-reduce of K^T.y per iteration."""
-    lp = lp_k16
-    c, xmax = _normalized(lp)
-    op, vecs, ell = solver._pack_pallas_sharded(
-        c, lp.row, lp.col, lp.val, lp.b, lp.h, xmax, lp.m_eq, shards=4)
+    op, vecs, ell = solver._pack_sharded(solver.block_stack([lp_k16]).lp, 4)
     mesh = Mesh(np.asarray(tpu_topology.devices[:4]), ("shard",))
-    args = [*vecs, jnp.zeros(op.n_pad, bool), jnp.zeros(op.m_pad, bool),
-            *ell, jnp.zeros(op.n_pad), jnp.zeros(op.m_pad)]
-    # x-side arrays (c, tau, xmax, keep_n, x0) are replicated, the rest
-    # split by rows — kernels.ops._sharded_burst_fn's in_specs
-    replicated = {0, 1, 2, 6, 12}
+    args = [*vecs, *ell, jnp.zeros(op.n_pad), jnp.zeros(op.m_pad)]
+    # x-side arrays (c, tau, xmax, x0) are replicated, the rest split by
+    # rows — core.pdhg._sharded_burst_fn's in_specs
+    replicated = {0, 1, 2, 10}
     specs = [_spec(a.shape, a.dtype,
                    NamedSharding(mesh, P() if i in replicated
                                  else P("shard")))
              for i, a in enumerate(args)]
-    fn = kops._sharded_burst_fn(mesh, "shard", op.row_meta, op.col_meta,
-                                ITERS, "fp32")
+    fn = pdhg._sharded_burst_fn(mesh, "shard", op.row_meta, op.col_meta,
+                                ITERS)
     compiled = fn.lower(*specs).compile()
     assert "all-reduce" in compiled.as_text()
     _fits_one_chip(compiled)
-
-
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="Mosaic refuses the blocked-ELL gather in "
-           "kernels/pdhg_spmv.spmv_blocks (jnp.take over a flat vector): "
-           "'NotImplementedError: Only 2D gather is supported'")
-def test_single_device_pallas_burst_compiles_for_one_v5e(one_chip, lp_k16):
-    """`backend="pallas"` on one chip: the fused whole-array burst."""
-    lp = lp_k16
-    c, xmax = _normalized(lp)
-    op, vecs, ell = solver._pack_pallas(c, lp.row, lp.col, lp.val, lp.b,
-                                        lp.h, xmax, lp.m_eq)
-    args = [*vecs, jnp.zeros(op.n_pad, bool), jnp.zeros(op.m_pad, bool),
-            *ell, jnp.zeros(op.n_pad), jnp.zeros(op.m_pad)]
-    burst = jax.jit(functools.partial(
-        pdhg_spmv.pdhg_burst, row_meta=op.rows.meta, col_meta=op.cols.meta,
-        iters=ITERS, interpret=False))
-    burst.lower(*[_spec(a.shape, a.dtype, one_chip) for a in args]).compile()

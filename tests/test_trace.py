@@ -130,15 +130,13 @@ def test_batched_calls_share_one_id_and_nest_into_the_outer():
     assert ids["z3"] is None
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_solve_fast_batch_spans(backend):
+def test_solve_fast_batch_spans(operator):
     probs = _problems(2)
     d0 = solver.dispatch_stats().snapshot()
     with trace.recording() as rec:
-        solver.solve_fast_batch(probs, "time", iters=500, backend=backend)
+        solver.solve_fast_batch(probs, "time", iters=500)
         n_first = len(rec.records)
-        solver.solve_fast_batch(probs[:1], "time", iters=500,
-                                backend=backend)
+        solver.solve_fast_batch(probs[:1], "time", iters=500)
     levels = solver.dispatch_stats().dispatches - d0.dispatches
     s = rec.seconds()
     for name in ("lp.build",) + PACK:
@@ -174,13 +172,12 @@ def test_one_instance_on_its_own_shape_wastes_nothing(how):
     assert useful == run == res[0].iterations * len(lp.val) > 0
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_a_frozen_instance_makes_useful_work_lower(backend):
+def test_a_frozen_instance_makes_useful_work_lower(operator):
     a, b = _lp(seed=0), _lp(seed=1)
-    solo = solver.solve_lp_batch([a], iters=1000, backend=backend)[0]
+    solo = solver.solve_lp_batch([a], iters=1000)[0]
     d0 = solver.dispatch_stats().snapshot()
     res = solver.solve_lp_batch(
-        [a, b], iters=1000, backend=backend,
+        [a, b], iters=1000,
         warm_starts=[(solo.x, solo.y), (np.zeros(b.n), np.zeros(b.m))])
     d = solver.dispatch_stats()
     run = d.nnz_iters_run - d0.nnz_iters_run

@@ -8,8 +8,8 @@ Raw wall-clock numbers are only comparable on the same machine with the
 same benchmark arguments, so the gate adapts:
 
   * **absolute mode** — when the candidate's ``sweep_bench`` args match
-    the baseline's exactly, the per-backend ``sweep/aggregate/*``
-    wall_ms values are compared directly;
+    the baseline's exactly, the ``sweep/aggregate`` wall_ms values are
+    compared directly;
   * **normalized mode** (the CI case: different seeds/topos, different
     runner hardware) — each file's aggregate *batch/loop ratio* is
     compared instead.  The per-instance loop runs the same PDHG work
@@ -77,7 +77,7 @@ def check_sweep(base_doc: dict, cand_doc: dict, max_regress: float) -> int:
     if base_args == cand_args:
         failed = 0
         for name, rec in base.items():
-            if "/aggregate/" not in name or name not in cand:
+            if "/aggregate" not in name or name not in cand:
                 continue
             old, new = rec["wall_ms"], cand[name]["wall_ms"]
             regress = new / max(old, 1e-9) - 1.0
