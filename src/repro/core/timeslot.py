@@ -8,6 +8,11 @@ candidate schedule with the paper's exact equations:
   * completion time M:         eqs. (39)-(45)
   * feasibility:               eqs. (25)-(30), (46), (47)
 
+Each accounting call reads the schedule's nonzero entries once
+(`schedule_coo`) and sums by bincount over them; the feasibility
+residuals are written once (`residuals`) for `evaluate` and
+`core.verify.check_schedule`.
+
 A schedule is a pair of tensors
     x[f, e, w, t]  - Gbits of flow f carried on directed edge e,
                      wavelength w, during slot t
@@ -221,16 +226,149 @@ def suggest_n_slots(topo: Topology, coflow: CoflowSet, *, rho: float = 8.0,
     return max(int(np.ceil(slack * t_lb / topo.slot_duration)) + extra, 2)
 
 
-def _delta_from_x(p: ScheduleProblem, x: np.ndarray) -> np.ndarray:
+@dataclasses.dataclass
+class AccountingStats:
+    """Counters for the exact accounting (`evaluate` and
+    `verify.check_schedule`): `calls`, the schedule `entries` those
+    calls read (each tensor's nonzeros) and the dense tensor `cells`
+    they cover; entries / cells is the schedules' nonzero share.  Read
+    via `accounting_stats()`; `python -m repro.sweep --profile` prints
+    per-cell deltas."""
+
+    calls: int = 0
+    entries: int = 0
+    cells: int = 0
+
+    def snapshot(self) -> "AccountingStats":
+        return dataclasses.replace(self)
+
+
+ACCOUNTING_STATS = AccountingStats()
+
+
+def accounting_stats() -> AccountingStats:
+    """The live accounting counters (see AccountingStats)."""
+    return ACCOUNTING_STATS
+
+
+def _bincount(keys: np.ndarray, weights: np.ndarray, n: int) -> np.ndarray:
+    """(n,) float64 sums of `weights` by key (np.bincount returns int64
+    where `keys` is empty)."""
+    return np.bincount(keys, weights=weights, minlength=n).astype(
+        np.float64, copy=False)
+
+
+def _sum_by(index: np.ndarray, rows: np.ndarray, n: int) -> np.ndarray:
+    """(n, *rows.shape[1:]): out[j] sums the rows k of `rows` with
+    index[k] == j (np.add.at's result, as one bincount)."""
+    tail = rows.shape[1:]
+    width = int(np.prod(tail, dtype=np.int64))
+    keys = (index[:, None] * width + np.arange(width)).reshape(-1)
+    return _bincount(keys, rows.reshape(-1), n * width).reshape((n,) + tail)
+
+
+@dataclasses.dataclass(frozen=True)
+class ScheduleCoo:
+    """A schedule tensor read once: the flow, edge and slot of each
+    nonzero entry and its Gbits `v`, with the two sums every accounting
+    family starts from, both bincounts over those entries: `psi`
+    (E, W, T), eq. (29), and `net` (F, V, W, T), each flow's outflow
+    less inflow at every vertex."""
+
+    f: np.ndarray
+    e: np.ndarray
+    t: np.ndarray
+    v: np.ndarray
+    psi: np.ndarray
+    net: np.ndarray
+
+
+def schedule_coo(p: ScheduleProblem, x: np.ndarray) -> ScheduleCoo:
+    """Read the nonzero entries of `x` (F, E, W, T).  A zero adds
+    nothing to a sum and every residual is a max from 0, so each reads
+    over the entries as over the dense tensor, NaN and inf included.
+    One vectorised scan finds the entries; the sums after it work on
+    them alone."""
+    assert x.shape == p.shape_x, (x.shape, p.shape_x)
+    F, E, W, T = x.shape
+    V = p.topo.n_vertices
+    flat = x.reshape(-1)
+    i = np.flatnonzero(flat != 0)
+    v = flat[i].astype(np.float64, copy=False)
+    f, e, w, t = np.unravel_index(i, x.shape)
+    stats = ACCOUNTING_STATS
+    stats.calls += 1
+    stats.entries += int(i.size)
+    stats.cells += int(x.size)
+    psi = _bincount((e * W + w) * T + t, v, E * W * T)
+    ends = np.concatenate([p.e_src[e], p.e_dst[e]])
+    key = ((np.concatenate([f, f]) * V + ends) * W
+           + np.concatenate([w, w])) * T + np.concatenate([t, t])
+    net = _bincount(key, np.concatenate([v, -v]), F * V * W * T)
+    return ScheduleCoo(f, e, t, v, psi.reshape(E, W, T),
+                       net.reshape(F, V, W, T))
+
+
+def _peak(values) -> float:
+    """The largest of `values`, at least 0; NaN if any is NaN."""
+    return float(np.max(values, initial=0.0))
+
+
+def _delta(p: ScheduleProblem, c: ScheduleCoo) -> np.ndarray:
     """delta[f, t] = net injection at the source of flow f in slot t."""
-    F, E, W, T = p.shape_x
-    out_src = np.zeros((F, T))
-    in_src = np.zeros((F, T))
-    for f in range(F):
-        s = p.coflow.src[f]
-        out_src[f] = x[f, p.e_src == s].sum(axis=(0, 1))
-        in_src[f] = x[f, p.e_dst == s].sum(axis=(0, 1))
-    return out_src - in_src
+    return c.net[np.arange(c.net.shape[0]), p.coflow.src].sum(axis=1)
+
+
+FAMILIES = ("capacity", "egress", "ingress", "mask", "conservation",
+            "demand", "release", "wavelength")
+
+
+def residuals(p: ScheduleProblem, c: ScheduleCoo) -> dict[str, float]:
+    """Worst residual of each constraint family (FAMILIES), in Gbits;
+    the single copy that both `evaluate` (their max) and
+    `core.verify.check_schedule` (each one) report."""
+    F, V, W, T = c.net.shape
+    D = p.topo.slot_duration
+    flows = np.arange(F)
+    res: dict[str, float] = {}
+    # eq. (28): psi <= C*D   (W entries with zero capacity must carry nothing)
+    res["capacity"] = _peak(c.psi - p.slot_cap_gbits[:, :, None])
+    # eq. (26): server egress <= rho*D
+    load = c.psi.sum(axis=1)                                   # (E, T)
+    egress = _sum_by(p.e_src, load, V)
+    res["egress"] = _peak(egress[p.is_server] - p.rho * D)
+    # eq. (27): switch ingress <= sigma*D
+    ingress = _sum_by(p.e_dst, load, V)
+    sw = p.is_switch & np.isfinite(p.sigma)
+    res["ingress"] = _peak(ingress[sw] - p.sigma[sw, None] * D)
+    # flow-edge mask (eq. 46 et al.): entries on inadmissible (f, e)
+    res["mask"] = _peak(c.v[~p.flow_edge_mask[c.f, c.e]])
+    # eq. (25): conservation at intermediate vertices.  Passive vertices
+    # (AWGR ports) conserve per wavelength (no O/E conversion); electronic
+    # vertices (switches/OLT/backplanes/relay servers) may convert, so they
+    # conserve the wavelength-summed flow.
+    inter = np.ones((F, V), dtype=bool)
+    inter[flows, p.coflow.src] = inter[flows, p.coflow.dst] = False
+    passive = ~(p.is_server | p.is_switch)
+    res["conservation"] = _peak(
+        [_peak(np.abs(c.net[:, passive][inter[:, passive]])),
+         _peak(np.abs(np.where(inter[:, :, None], c.net.sum(axis=2), 0.0)))])
+    # eq. (30): demand satisfaction (report shortfall as violation)
+    served = _delta(p, c).sum(axis=1)
+    res["demand"] = _peak(np.abs(served - p.coflow.size))
+    # release times (extension): no traffic before a flow's release slot
+    res["release"] = 0.0
+    if p.release_slot is not None:
+        release = np.asarray(p.release_slot).astype(np.int64)
+        res["release"] = _peak(c.v[c.t < release[c.f]])
+    # eq. (47): one TX wavelength per server per slot (PON3)
+    res["wavelength"] = 0.0
+    if p.topo.one_wavelength_tx and p.topo.awgr_in_ports:
+        tx = np.flatnonzero(p.is_server[p.e_src]
+                            & np.isin(p.e_dst, p.topo.awgr_in_ports))
+        n_w_used = (_sum_by(p.e_src[tx], c.psi[tx], V) > TOL).sum(axis=1)
+        res["wavelength"] = _peak(n_w_used - 1)               # (V, T)
+    return res
 
 
 def _activity_energy(p: ScheduleProblem, psi: np.ndarray
@@ -240,9 +378,8 @@ def _activity_energy(p: ScheduleProblem, psi: np.ndarray
     Joules.  Single source of truth for evaluate (T' = full horizon)
     and prefix_energy (T' = an executed epoch prefix)."""
     D = p.topo.slot_duration
-    beta = np.zeros((p.topo.n_vertices,) + psi.shape[1:])
-    np.add.at(beta, p.e_src, psi)
-    np.add.at(beta, p.e_dst, psi)
+    V = p.topo.n_vertices
+    beta = _sum_by(p.e_src, psi, V) + _sum_by(p.e_dst, psi, V)
     active = beta > TOL
     energy = D * float((active * p.p_max[:, None, None]).sum())
     energy += D * float((p.eps[:, None, None] * beta
@@ -264,63 +401,15 @@ def evaluate(p: ScheduleProblem, x: np.ndarray) -> Metrics:
 
     `x` has shape (F, E, W, T) in Gbits; returns energy in Joules
     (eqs. 19-22), completion time in seconds (eqs. 39-45), and the worst
-    constraint violation in Gbits (feasible iff <= 1e-4).  Pure numpy,
-    deterministic, and platform-independent — this is the single
-    source of truth both solvers and all sweeps report through."""
-    F, E, W, T = p.shape_x
-    assert x.shape == (F, E, W, T), (x.shape, p.shape_x)
+    constraint violation in Gbits (feasible iff <= 1e-4; NaN, and so
+    infeasible, if any entry is NaN).  Pure numpy, deterministic, and
+    platform-independent — this is the single source of truth both
+    solvers and all sweeps report through."""
+    c = schedule_coo(p, x)
+    viol = _peak(list(residuals(p, c).values()))
+    T = p.n_slots
     D = p.topo.slot_duration
-    psi = x.sum(axis=0)                              # (E, W, T), eq. (29)
-
-    viol = 0.0
-    # eq. (28): psi <= C*D   (W entries with zero capacity must carry nothing)
-    viol = max(viol, float((psi - p.slot_cap_gbits[:, :, None]).max(initial=0.0)))
-    # eq. (26): server egress <= rho*D
-    egress = np.zeros((p.topo.n_vertices, T))
-    np.add.at(egress, p.e_src, psi.sum(axis=1))
-    viol = max(viol, float((egress[p.is_server] - p.rho * D).max(initial=0.0)))
-    # eq. (27): switch ingress <= sigma*D
-    ingress = np.zeros((p.topo.n_vertices, T))
-    np.add.at(ingress, p.e_dst, psi.sum(axis=1))
-    sw = p.is_switch & np.isfinite(p.sigma)
-    viol = max(viol, float((ingress[sw] - p.sigma[sw, None] * D).max(initial=0.0)))
-    # flow-edge mask (eq. 46 et al.)
-    viol = max(viol, float((x * ~p.flow_edge_mask[:, :, None, None]).max(initial=0.0)))
-
-    # eq. (25): conservation at intermediate vertices.  Passive vertices
-    # (AWGR ports) conserve per wavelength (no O/E conversion); electronic
-    # vertices (switches/OLT/backplanes/relay servers) may convert, so they
-    # conserve the wavelength-summed flow.
-    passive = ~(p.is_server | p.is_switch)
-    for f in range(F):
-        net = np.zeros((p.topo.n_vertices, W, T))
-        np.add.at(net, p.e_src, x[f])
-        np.subtract.at(net, p.e_dst, x[f])
-        inter = np.ones(p.topo.n_vertices, dtype=bool)
-        inter[p.coflow.src[f]] = inter[p.coflow.dst[f]] = False
-        viol = max(viol, float(np.abs(net[inter & passive]).max(initial=0.0)))
-        viol = max(viol, float(np.abs(net.sum(axis=1)[inter]).max(initial=0.0)))
-
-    # eq. (30): demand satisfaction (report shortfall as violation)
-    delta = _delta_from_x(p, x)
-    served = delta.sum(axis=1)
-    viol = max(viol, float(np.abs(served - p.coflow.size).max(initial=0.0)))
-
-    # release times (extension): no traffic before a flow's release slot
-    if p.release_slot is not None:
-        for f in range(F):
-            r = int(p.release_slot[f])
-            if r > 0:
-                viol = max(viol, float(x[f, :, :, :r].max(initial=0.0)))
-
-    # eq. (47): one TX wavelength per server per slot (PON3)
-    if p.topo.one_wavelength_tx and p.topo.awgr_in_ports:
-        awgr_in = np.isin(p.e_dst, p.topo.awgr_in_ports)
-        for i in np.flatnonzero(p.is_server):
-            sel = (p.e_src == i) & awgr_in
-            if sel.any():
-                n_w_used = (psi[sel].sum(axis=0) > TOL).sum(axis=0)  # (T,)
-                viol = max(viol, float(n_w_used.max(initial=0) - 1))
+    psi = c.psi
 
     # device activity (eqs. 31-38) and power (eqs. 19-21)
     beta, active, energy = _activity_energy(p, psi)       # eq. (22)
@@ -333,8 +422,9 @@ def evaluate(p: ScheduleProblem, x: np.ndarray) -> Metrics:
     omega = np.where(psi > TOL, D * (t_idx - 1) + tx_time, 0.0)   # eq. (39)
     completion = float(omega.max(initial=0.0))                    # eqs. (43-45)
 
+    delta = _delta(p, c)                                  # eq. (30)
     fairness = p.q_weight * float((delta * t_idx[0, 0][None, :]).sum())
     return Metrics(energy_j=energy, completion_s=completion,
                    fairness_term=fairness, feasible=viol <= 1e-4,
                    max_violation=viol, psi=psi,
-                   active_devices=active, served=served)
+                   active_devices=active, served=delta.sum(axis=1))

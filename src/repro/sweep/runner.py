@@ -212,30 +212,35 @@ class SweepRecord:
 
 def _profile_mark() -> tuple:
     """Where a cell starts, for its --profile line: the number of spans
-    recorded so far, the build-cache, dispatch and decompose counters."""
+    recorded so far, the build-cache, dispatch, decompose and accounting
+    counters."""
     rec = trace.active()
     return (len(rec.records) if rec else 0,
             solver.build_cache_stats().snapshot(),
             solver.dispatch_stats().snapshot(),
-            solver.decompose_stats().snapshot())
+            solver.decompose_stats().snapshot(),
+            timeslot.accounting_stats().snapshot())
 
 
 def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
     """One --profile line for a finished cell, from the spans recorded
     since `mark` (_profile_mark): LP build (`lp.build`), PDHG
     (`pdhg.*`: stack, run, unstack), pack (`pack.*`: decompose, slots,
-    evaluate) and the rest of the cell's wall time, with the build
-    caches' hits, how many PDHG dispatches applied K as tiles, and the
-    paths the decomposition peeled and the DFS states it expanded."""
-    start, snap, disp, dec = mark
+    evaluate), certify (`verify.check`) and the rest of the cell's wall
+    time, with the build caches' hits, how many PDHG dispatches applied
+    K as tiles, the paths the decomposition peeled and the DFS states it
+    expanded, and the schedule entries the accounting read out of the
+    dense cells its calls cover."""
+    start, snap, disp, dec, acc = mark
     rec = trace.active()
 
     def ms(prefix: str) -> float:
         return rec.total(prefix, start) * 1e3
 
     d, t = solver.build_cache_stats(), solver.dispatch_stats()
-    c = solver.decompose_stats()
+    c, a = solver.decompose_stats(), timeslot.accounting_stats()
     build, pdhg, pack = ms("lp.build"), ms("pdhg."), ms("pack.")
+    certify = ms("verify.check")
     say(f"    profile {label}: build {build:7.1f} ms "
         f"(structure {d.structure_hits - snap.structure_hits} hit"
         f"/{d.structure_misses - snap.structure_misses} miss, "
@@ -249,7 +254,10 @@ def _profile_line(say, label: str, mark: tuple, wall_s: float) -> None:
         f"paths {c.paths - dec.paths}, states {c.states - dec.states}, "
         f"slots {ms('pack.slots'):.1f}, "
         f"evaluate {ms('pack.evaluate'):.1f}) | "
-        f"other {wall_s * 1e3 - build - pdhg - pack:8.1f} ms | "
+        f"certify {certify:7.1f} ms | "
+        f"accounting {a.calls - acc.calls} calls, "
+        f"{a.entries - acc.entries}/{a.cells - acc.cells} entries | "
+        f"other {wall_s * 1e3 - build - pdhg - pack - certify:8.1f} ms | "
         f"total {wall_s * 1e3:8.1f} ms")
 
 

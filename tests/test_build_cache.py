@@ -317,10 +317,10 @@ def test_sweep_profile_prints_build_solve_split():
     cell = [ln for ln in prof if "spine-leaf/uniform/min-energy:" in ln]
     assert len(cell) == 1
     # the split comes from the program's spans (repro.trace): build,
-    # PDHG host and device work, and the pack split three ways
+    # PDHG host and device work, the pack split three ways, and certify
     ms = {k: float(v) for k, v in re.findall(r"(\w+) +([\d.]+) ms",
                                             cell[0])}
-    assert set(ms) == {"build", "pdhg", "pack", "other", "total"}
+    assert set(ms) == {"build", "pdhg", "pack", "certify", "other", "total"}
     parts = dict(re.findall(r"(\w+) ([\d.]+)[,)]", cell[0]))
     assert set(parts) >= {"stack", "run", "unstack", "decompose", "slots",
                           "evaluate", "paths", "states"}
@@ -329,7 +329,13 @@ def test_sweep_profile_prints_build_solve_split():
     assert int(parts["paths"]) >= 24
     assert int(parts["states"]) >= int(parts["paths"])
     assert float(parts["run"]) > 0 and float(parts["slots"]) > 0
-    assert ms["build"] + ms["pdhg"] + ms["pack"] <= ms["total"] + 0.1
+    assert ms["build"] + ms["pdhg"] + ms["pack"] + ms["certify"] \
+        <= ms["total"] + 0.1
+    # every schedule is evaluated: one accounting read each, of its
+    # nonzero entries out of its dense cells
+    calls, entries, cells = map(int, re.search(
+        r"accounting (\d+) calls, (\d+)/(\d+) entries", cell[0]).groups())
+    assert calls >= 2 and 0 < entries < cells
     assert "structure" in cell[0]
     # the recording ends with the sweep
     from repro import trace
