@@ -31,6 +31,7 @@ import zlib
 
 import numpy as np
 
+from .. import trace
 from .timeslot import ScheduleProblem, suggest_n_slots
 from .topology import KIND_PASSIVE, KIND_SERVER, KIND_SWITCH, Topology
 from .traffic import CoflowSet
@@ -297,6 +298,7 @@ def routable_flows(p: ScheduleProblem) -> np.ndarray:
     return ok
 
 
+@trace.spanned("failure.degrade")
 def degrade_problem(p: ScheduleProblem, scen: FailureScenario, *,
                     n_slots: int | None = None) -> ScheduleProblem:
     """Build the degraded ScheduleProblem for a healthy one.

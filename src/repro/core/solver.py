@@ -1804,6 +1804,44 @@ def solve_fast_batch(problems: list[ScheduleProblem],
 # Incremental re-solves (degraded topologies, core.failures)
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass
+class EnsembleStats:
+    """Counters for warm re-solves.
+
+    `members` counts the instances `solve_fast_ensemble` solved,
+    `warm_members` those it started from a projected warm state, and
+    `warm_iters` the PDHG iterations of those warm members, summed;
+    `warm_iters / warm_members` is the iterations a warm re-plan costs.
+    `kept_gbits` is the healthy path volume every `project_warm_start`
+    call kept on paths whose hops all survive, `rerouted_gbits` the
+    volume it moved from dead paths onto a surviving route.  Read via
+    `ensemble_stats()`, clear via `reset_ensemble_stats()`."""
+
+    members: int = 0
+    warm_members: int = 0
+    warm_iters: int = 0
+    kept_gbits: float = 0.0
+    rerouted_gbits: float = 0.0
+
+    def snapshot(self) -> "EnsembleStats":
+        return dataclasses.replace(self)
+
+
+ENSEMBLE_STATS = EnsembleStats()
+
+
+def ensemble_stats() -> EnsembleStats:
+    """The live warm re-solve counters (see EnsembleStats)."""
+    return ENSEMBLE_STATS
+
+
+def reset_ensemble_stats() -> None:
+    """Zero the warm re-solve counters."""
+    for f in dataclasses.fields(EnsembleStats):
+        setattr(ENSEMBLE_STATS, f.name, f.default)
+
+
+@trace.spanned("warm.project")
 def project_warm_start(warm: FastPathResult, p_dst: ScheduleProblem,
                        lp_dst: StructuredLP, idx_dst: RoutingIndex, *,
                        flow_map: np.ndarray | None = None
@@ -1886,6 +1924,7 @@ def project_warm_start(warm: FastPathResult, p_dst: ScheduleProblem,
             shipped[f] += vol
         else:
             lost[f] += vol
+    ENSEMBLE_STATS.kept_gbits += float(shipped.sum())
 
     # re-route volume stranded by failed hops onto any surviving route
     out_edges = _out_edges(p_dst)
@@ -1905,6 +1944,7 @@ def project_warm_start(warm: FastPathResult, p_dst: ScheduleProblem,
             x0[dst_pos(f, e, w)] += vol
         x0[K_dst + f * W + trail[0][1]] += vol
         shipped[f] += vol
+        ENSEMBLE_STATS.rerouted_gbits += float(vol)
 
     if idx_dst.n_theta:
         # theta couples every capacity row (sum x <= limit * theta); the
@@ -2077,6 +2117,10 @@ def solve_fast_ensemble(problems: list[ScheduleProblem],
     results = solve_lp_batch(lps, iters=iters, tol=tol, adaptive=adaptive,
                              chunk=chunk, warm_starts=warm_starts,
                              bucket=bucket, shards=shards)
+    ENSEMBLE_STATS.members += len(problems)
+    if warm_starts is not None:
+        ENSEMBLE_STATS.warm_members += len(problems)
+        ENSEMBLE_STATS.warm_iters += sum(r.iterations for r in results)
     return [_assemble_fast_result(p, lp, idx, res)
             for p, (lp, idx), res in zip(problems, built, results)]
 

@@ -110,12 +110,15 @@ class ScheduleProblem:
             mask &= ~(v_is_server[None, :] & (self.e_dst[None, :] != dst[:, None]))
         if self.path_slack is not None:
             # edge (u, v) stays admissible for flow f iff it lies on some
-            # src->dst walk within path_slack hops of the shortest one
+            # src->dst walk within path_slack hops of the shortest one; a
+            # flow with no route left (a failure stranded it) admits none,
+            # since inf <= inf would admit every edge
             from_src = hop_rows(t, src)                       # (F, V)
             to_dst = hop_rows(t, dst, to=True)                # (F, V)
             through = from_src[:, self.e_src] + 1 + to_dst[:, self.e_dst]
             shortest = from_src[np.arange(F), dst]
             mask &= through <= (shortest + self.path_slack)[:, None]
+            mask &= np.isfinite(shortest)[:, None]
         return mask
 
     # -- convenience sizes --------------------------------------------------

@@ -22,8 +22,10 @@ id.
 Span names are dotted by layer: `problem.mask` (a ScheduleProblem's
 flow-edge mask) with `problem.hops` inside it (hop-count rows found on
 a cache miss); `lp.build`; `pdhg.stack`, `pdhg.run`, `pdhg.unstack`;
-`pack.decompose`, `pack.slots`, `pack.evaluate`; `verify.check`.
-Counters stay with the code they count (`solver.dispatch_stats()`,
+`pack.decompose`, `pack.slots`, `pack.evaluate`; `verify.check`;
+`failure.degrade` (`failures.degrade_problem`) and `warm.project`
+(`solver.project_warm_start`).  Counters stay with the code they count
+(`solver.dispatch_stats()`, `solver.ensemble_stats()`,
 `timeslot.accounting_stats()`).
 """
 from __future__ import annotations

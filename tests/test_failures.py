@@ -247,3 +247,137 @@ def test_compose_matches_sequential_application_pattern():
     rows = failures.affected_rows(topo, a) | failures.affected_rows(topo, b)
     assert np.all(both.cap[rows] == 0.0)
     assert np.array_equal(both.cap[~rows], topo.cap[~rows])
+
+
+# ---------------------------------------------------------------------------
+# the failure path's spans and counters, and stranded flows
+# ---------------------------------------------------------------------------
+
+def _fat_tree_problem(k=4, seed=0, n_map=4, n_reduce=2, total=8.0):
+    t = topology.build("fat-tree", k=k)
+    cf = traffic.shuffle_traffic(t, total, n_map=n_map, n_reduce=n_reduce,
+                                 seed=seed)
+    return timeslot.ScheduleProblem(t, cf, n_slots=timeslot.suggest_n_slots(
+        t, cf), path_slack=0)
+
+
+def _agg_failures(p):
+    """One scenario per aggregation switch: none strands a flow."""
+    return [failures.fail_device(p.topo, i)
+            for i, d in enumerate(p.topo.devices) if d.name.startswith("agg")]
+
+
+def test_failure_spans_once_per_member():
+    from repro import trace
+
+    p = _fat_tree_problem()
+    healthy = solver.solve_fast_batch([p], "energy", iters=4000)[0]
+    scens = _agg_failures(p)[:3]
+    with trace.recording() as rec:
+        dprobs = [failures.degrade_problem(p, s, n_slots=p.n_slots)
+                  for s in scens]
+        solver.solve_fast_ensemble(dprobs, "energy", warm=[healthy] * 3,
+                                   iters=4000)
+    s = rec.seconds()
+    assert len(s["failure.degrade"]) == len(s["warm.project"]) == 3
+    assert all(v >= 0.0 for v in s["failure.degrade"] + s["warm.project"])
+
+
+def test_ensemble_stats_count_members_and_iterations():
+    p = _fat_tree_problem()
+    healthy = solver.solve_fast_batch([p], "energy", iters=4000)[0]
+    dprobs = [failures.degrade_problem(p, s, n_slots=p.n_slots)
+              for s in _agg_failures(p)[:3]]
+    solver.reset_ensemble_stats()
+    warm = solver.solve_fast_ensemble(dprobs, "energy", warm=[healthy] * 3,
+                                      iters=4000)
+    cold = solver.solve_fast_ensemble(dprobs[:2], "energy", iters=4000)
+    st = solver.ensemble_stats().snapshot()
+    assert (st.members, st.warm_members) == (5, 3)
+    assert st.warm_iters == sum(r.iterations for r in warm) > 0
+    assert sum(r.iterations for r in cold) > 0
+    # the projection places every Gbit of the healthy paths: kept on a
+    # surviving path or re-routed, never more than the demand
+    shipped = st.kept_gbits + st.rerouted_gbits
+    assert 0.0 < shipped <= 3 * p.coflow.total_gbits + 1e-9
+    solver.reset_ensemble_stats()
+    assert solver.ensemble_stats() == solver.EnsembleStats()
+
+
+def test_warm_members_run_fewer_iterations_than_cold():
+    """Fat-tree k=4: re-plans after each aggregation switch's failure,
+    warm-started from the healthy plan, converge in fewer PDHG
+    iterations than the same members started from zero."""
+    p = _fat_tree_problem()
+    healthy = solver.solve_fast_batch([p], "energy", iters=16000,
+                                      tol=2e-3)[0]
+    dprobs = [failures.degrade_problem(p, s, n_slots=p.n_slots)
+              for s in _agg_failures(p)]
+    warm = solver.solve_fast_ensemble(dprobs, "energy", warm=[healthy] *
+                                      len(dprobs), iters=16000, tol=2e-3)
+    cold = solver.solve_fast_ensemble(dprobs, "energy", iters=16000,
+                                      tol=2e-3)
+    for w, c in zip(warm, cold):
+        assert w.metrics.feasible and c.metrics.feasible
+    assert sum(r.iterations for r in warm) < sum(r.iterations for r in cold)
+
+
+def _old_mask(p):
+    """The flow-edge mask as it was before stranded flows admitted no
+    edge (`shortest + path_slack` compared with an infinite `shortest`)."""
+    src, dst = p.coflow.src, p.coflow.dst
+    mask = ~(p.e_dst[None, :] == src[:, None])
+    mask &= ~(p.e_src[None, :] == dst[:, None])
+    hs = timeslot.hop_rows(p.topo, src)
+    hd = timeslot.hop_rows(p.topo, dst, to=True)
+    through = hs[:, p.e_src] + 1 + hd[:, p.e_dst]
+    shortest = hs[np.arange(p.coflow.n_flows), dst]
+    return mask & (through <= (shortest + p.path_slack)[:, None])
+
+
+@pytest.mark.parametrize("k", [8, 16])
+def test_stranded_flows_admit_no_edge(k):
+    """One edge switch hosting tasks fails: its flows are stranded and
+    admit no edge, so the degraded LP is no larger than the healthy one,
+    and every routable flow keeps its mask row."""
+    p = _fat_tree_problem(k=k, seed=1, n_map=10, n_reduce=6, total=30.0)
+    src = int(p.coflow.src[0])
+    edge = int(p.topo.edges[p.topo.edges[:, 0] == src, 1][0])
+    dp = failures.degrade_problem(p, failures.fail_device(p.topo, edge),
+                                  n_slots=p.n_slots)
+    stranded = dp.coflow.size == 0.0
+    assert stranded.any() and not stranded.all()
+    assert not dp.flow_edge_mask[stranded].any()
+    old = _old_mask(dp)
+    # the defect: nearly every edge, inf <= inf
+    assert (old[stranded].sum(axis=1) > 0.9 * p.topo.n_edges).all()
+    np.testing.assert_array_equal(dp.flow_edge_mask[~stranded],
+                                  old[~stranded])
+    lp_h, _ = solver.build_routing_lp(p, "energy")
+    lp_d, _ = solver.build_routing_lp(dp, "energy")
+    assert lp_d.n <= lp_h.n
+
+
+def test_stranded_flows_keep_the_solve_finite():
+    """A stranded flow's rows hold only its injections, fixed at 0: the
+    solve stays finite, its demand row's dual stays 0, and every
+    routable flow is served in full."""
+    p = _fat_tree_problem(k=4, seed=1)
+    src = int(p.coflow.src[0])
+    edge = int(p.topo.edges[p.topo.edges[:, 0] == src, 1][0])
+    dp = failures.degrade_problem(p, failures.fail_device(p.topo, edge),
+                                  n_slots=p.n_slots)
+    stranded = dp.coflow.size == 0.0
+    assert stranded.any()
+    lp, idx = solver.build_routing_lp(dp, "energy")
+    res = solver.solve_lp_batch([lp], iters=4000)[0]
+    assert np.isfinite(res.x).all() and np.isfinite(res.y).all()
+    assert np.isfinite(res.primal_residual)
+    assert np.isfinite(res.duality_gap_rel)
+    demand_row = {k: i for i, k in enumerate(idx.eq_keys)}
+    for f in np.flatnonzero(stranded):
+        assert res.y[demand_row[("d", int(f))]] == 0.0
+    r = solver.solve_fast_batch([dp], "energy", iters=4000)[0]
+    assert r.metrics.feasible
+    np.testing.assert_allclose(r.metrics.served[~stranded],
+                               dp.coflow.size[~stranded], atol=1e-6)

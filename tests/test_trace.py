@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro import trace
-from repro.core import solver, timeslot, topology, traffic
+from repro.core import failures, solver, timeslot, topology, traffic
 
 PACK = ("pack.decompose", "pack.slots", "pack.evaluate")
 PDHG = ("pdhg.stack", "pdhg.run", "pdhg.unstack")
@@ -40,7 +40,7 @@ def notes(monkeypatch):
     return made
 
 
-@pytest.mark.parametrize("what", ["span", "decorators", "solve"])
+@pytest.mark.parametrize("what", ["span", "decorators", "solve", "replan"])
 def test_off_stores_nothing_and_builds_no_annotation(notes, what):
     assert trace.active() is None
     if what == "span":
@@ -49,8 +49,15 @@ def test_off_stores_nothing_and_builds_no_annotation(notes, what):
                 pass
     elif what == "decorators":
         trace.batched(trace.spanned("f")(lambda: None))()
-    else:
+    elif what == "solve":
         solver.solve_fast_batch(_problems(1), "time", iters=500)
+    else:
+        # failures.degrade_problem and solver.project_warm_start
+        p = _problems(1)[0]
+        healthy = solver.solve_fast_batch([p], "time", iters=500)[0]
+        dp = failures.degrade_problem(p, failures.sample(p.topo, "link1", 0),
+                                      n_slots=p.n_slots)
+        solver.solve_fast_ensemble([dp], "time", warm=[healthy], iters=500)
     assert notes == []
     with trace.recording() as rec:
         pass
